@@ -41,6 +41,16 @@ def main(argv: list[str] | None = None) -> int:
         import jax
         jax.config.update("jax_platforms", "cpu")
 
+    # the first log line names the device this node will serve from: a
+    # node that was meant for a TPU and found none says so here
+    from elasticsearch_tpu.common.device import describe_devices
+    dev = describe_devices()
+    print(f"[estpu] device: platform={dev['platform']} "
+          f"kind={dev['kind']} count={dev['count']}"
+          + ("" if dev["platform"] == "tpu" or args.cpu else
+             " — NO TPU FOUND, serving from the CPU backend "
+             "(pass --cpu to choose this on purpose)"), flush=True)
+
     overrides = {}
     for kv in args.E:
         k, _, v = kv.partition("=")

@@ -58,6 +58,12 @@ class Node:
     SHARD_FAILED_ACTION = "internal:cluster/shard/failure"
 
     def start(self) -> "Node":
+        # compiled programs persist across processes from the first one
+        # on; a device without roofline peaks fails here, not per request
+        from elasticsearch_tpu.common.device import ensure_compile_cache
+        from elasticsearch_tpu.observability import costs
+        ensure_compile_cache()
+        costs.machine_constants()
         # transport selection (ref: `transport.type` setting resolved by
         # NetworkModule — NettyTransport by default, LocalTransport for
         # embedded/test use; core/node/Node.java:230-275 wiring order).

@@ -73,16 +73,35 @@ HOT_DISPATCHES = 32
 #: √2-spaced dispatch-time histogram bounds in µs: 1 µs → ~64 s
 BOUNDS_US = tuple(1.0 * (2 ** (i / 2.0)) for i in range(33))
 
-#: nominal roofline constants per platform — (HBM bytes/s, flop/s).
-#: TPU numbers are single-chip v5e (819 GB/s HBM, ~9.8e13 f32 flop/s);
-#: CPU numbers are a laptop-class core (the CPU backend is a
-#: correctness rig — its predictions are honest about being nominal).
+#: roofline peaks keyed by ``device_kind`` — (HBM bytes/s, flop/s).
+#: "TPU v5 lite" is what JAX reports for one v5e chip: 819 GB/s HBM and
+#: 197 TFLOP/s bf16 (Google Cloud documentation, "TPU v5e"). No f32 peak
+#: is published, so f32 programs — every lane today — are held to the
+#: same bf16 MXU figure: an upper bound on compute, which leaves the
+#: bandwidth wall deciding the prediction for the memory-bound lanes.
+#: "cpu" is a laptop-class core (the CPU backend is a correctness rig —
+#: its predictions are honest about being nominal). A device that is
+#: not in the table is an error, not a default.
 #: Override with ESTPU_ROOFLINE_BW_GBS / ESTPU_ROOFLINE_GFLOPS.
-ROOFLINE = {
-    "tpu": (819.0e9, 9.8e13),
+PEAKS = {
+    "TPU v5 lite": (819.0e9, 197.0e12),
     "cpu": (25.0e9, 5.0e10),
-    "gpu": (900.0e9, 1.0e13),
 }
+
+
+def peaks_for(platform: str, device_kind: str) -> "tuple[float, float]":
+    """(bytes/s, flop/s) of one device. Every CPU kind shares the
+    nominal "cpu" row; any other device must be in :data:`PEAKS`."""
+    if platform == "cpu":
+        return PEAKS["cpu"]
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device_kind {device_kind!r} "
+            f"(platform {platform!r}); known: {sorted(PEAKS)} — add its "
+            f"published peaks to observability/costs.py PEAKS, or set "
+            f"ESTPU_ROOFLINE_BW_GBS and ESTPU_ROOFLINE_GFLOPS") from None
 
 _machine_lock = threading.Lock()
 _machine: "tuple[float, float] | None" = None
@@ -111,12 +130,9 @@ def machine_constants() -> "tuple[float, float]":
             except ValueError:
                 flops = None
         if bw is None or flops is None:
-            try:
-                import jax
-                platform = jax.devices()[0].platform
-            except Exception:            # noqa: BLE001 — no backend yet
-                platform = "cpu"
-            d_bw, d_fl = ROOFLINE.get(platform, ROOFLINE["cpu"])
+            import jax
+            dev = jax.devices()[0]
+            d_bw, d_fl = peaks_for(dev.platform, dev.device_kind)
             bw = bw if bw is not None else d_bw
             flops = flops if flops is not None else d_fl
         _machine = (bw, flops)

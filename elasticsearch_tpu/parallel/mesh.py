@@ -16,18 +16,10 @@ from jax.sharding import Mesh
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """`jax.shard_map` across the versions this repo meets: new jax
-    exports it top-level with `check_vma`; 0.4.x ships it under
-    jax.experimental with `check_rep`. Replication checking stays off
-    either way (the programs return per-shard lanes on purpose)."""
-    try:
-        from jax import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    """`jax.shard_map` with replication checking off (the programs
+    return per-shard lanes on purpose)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def valid_geometries(n: int) -> list:

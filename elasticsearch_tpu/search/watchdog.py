@@ -10,8 +10,15 @@ monotonic start) — and a monitor thread compares each wait's age
 against its **predicted envelope**: ``costs.estimate(lane, shape_key)``
 (the PR 15 cost observatory) × ``stall_multiplier``, bounded to
 [``floor_s``, ``ceiling_s``]; a shape the cost table has never seen
-gets the larger ``cold_floor_s`` (a cold shape legitimately includes a
-trace+compile).
+gets the larger ``cold_floor_s`` (a cold shape's first wait includes
+host planning, uploads and first-dispatch set-up).
+
+A trace+compile is host work, not a device wait, and at real widths it
+takes tens of seconds (20.7 s for one 2^20-row ``segment`` program on a
+v5e host, PR 24): the compile seam brackets it with :meth:`DispatchWatchdog.
+compiling`, which stops the clock of the waits the compiling thread
+registered. A compile that itself outlasts ``ceiling_s`` — the longest
+envelope any wait can get — is still a stall.
 
 The escalation ladder, per overdue wait:
 
@@ -41,6 +48,7 @@ from __future__ import annotations
 
 import threading
 import time
+from contextlib import contextmanager
 
 from elasticsearch_tpu.observability.context import current_node_id
 
@@ -51,7 +59,8 @@ class WaitEntry:
 
     __slots__ = ("site", "lane", "shape_key", "n_real", "node_id",
                  "trace_id", "task_id", "started", "budget_s",
-                 "on_stall", "stalled", "done")
+                 "on_stall", "stalled", "done", "thread_id",
+                 "compile_since")
 
     def __init__(self, site, lane, shape_key, n_real, node_id,
                  trace_id, task_id, started, budget_s, on_stall):
@@ -67,6 +76,8 @@ class WaitEntry:
         self.on_stall = on_stall
         self.stalled = False
         self.done = False
+        self.thread_id = threading.get_ident()
+        self.compile_since: float | None = None   # clock stopped since
 
 
 def _context_ids() -> tuple:
@@ -156,8 +167,9 @@ class DispatchWatchdog:
     def budget_s(self, lane: str | None, shape_key=None) -> float:
         """The stall envelope for one wait: the cost observatory's
         estimate × the multiplier, floor/ceiling-bounded; a shape with
-        no estimate gets the cold floor (its first wait legitimately
-        includes a trace+compile)."""
+        no estimate gets the cold floor (its first wait includes
+        uploads and first-dispatch set-up; the trace+compile itself is
+        excluded by :meth:`compiling`)."""
         est_us = None
         if lane is not None:
             try:
@@ -209,6 +221,30 @@ class DispatchWatchdog:
             self._consecutive_stalls = 0
             return True
 
+    @contextmanager
+    def compiling(self):
+        """Bracket one trace+compile on the calling thread: host work,
+        not a device wait. The waits this thread registered stop their
+        clock for the duration (``started`` moves forward by the time
+        spent compiling), so a long cold compile is never counted as a
+        device stall; while compiling, a wait is overdue only when the
+        compile alone outlasts ``ceiling_s`` (a runaway compile)."""
+        tid = threading.get_ident()
+        t0 = time.perf_counter()
+        with self._lock:
+            mine = [e for e in self._entries
+                    if e.thread_id == tid and e.compile_since is None]
+            for entry in mine:
+                entry.compile_since = t0
+        try:
+            yield
+        finally:
+            spent = time.perf_counter() - t0
+            with self._lock:
+                for entry in mine:
+                    entry.compile_since = None
+                    entry.started += spent
+
     # ---- monitor -----------------------------------------------------------
 
     def _ensure_monitor_locked(self) -> None:
@@ -235,7 +271,9 @@ class DispatchWatchdog:
             for entry in self._entries:
                 if entry.stalled or entry.done:
                     continue
-                if now - entry.started > entry.budget_s:
+                if (now - entry.compile_since > self.ceiling_s
+                        if entry.compile_since is not None
+                        else now - entry.started > entry.budget_s):
                     entry.stalled = True
                     overdue.append(entry)
             if overdue:

@@ -84,6 +84,43 @@ def _lanes_seen():
 
 
 # ---------------------------------------------------------------------------
+# roofline peaks: keyed by device_kind, an unknown device is an error
+# ---------------------------------------------------------------------------
+
+def test_v5e_peaks_are_the_published_ones():
+    bw, flops = costs.peaks_for("tpu", "TPU v5 lite")
+    assert bw == 819.0e9 and flops == 197.0e12
+    # every CPU kind shares the nominal row
+    assert costs.peaks_for("cpu", "cpu") == \
+        costs.peaks_for("cpu", "some-host-cpu") == costs.PEAKS["cpu"]
+
+
+@pytest.mark.parametrize("platform,kind", [
+    ("tpu", "TPU v9 imaginary"), ("gpu", "NVIDIA H100")])
+def test_unknown_device_kind_raises(platform, kind):
+    with pytest.raises(ValueError, match="no roofline peaks"):
+        costs.peaks_for(platform, kind)
+
+
+def test_machine_constants_raise_on_an_unknown_device(monkeypatch):
+    """No silent CPU default: a backend whose device_kind has no peaks
+    fails machine_constants() — unless both env overrides are given."""
+    import jax
+
+    class Dev:
+        platform, device_kind = "tpu", "TPU v9 imaginary"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
+    monkeypatch.setattr(costs, "_machine", None)
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        costs.machine_constants()
+    monkeypatch.setenv("ESTPU_ROOFLINE_BW_GBS", "100")
+    monkeypatch.setenv("ESTPU_ROOFLINE_GFLOPS", "1000")
+    assert costs.machine_constants() == (100e9, 1000e9)
+    monkeypatch.setattr(costs, "_machine", None)   # next reader re-resolves
+
+
+# ---------------------------------------------------------------------------
 # static cost analysis: present and positive for every serving lane
 # ---------------------------------------------------------------------------
 

@@ -179,6 +179,64 @@ def test_abandoned_wait_escalates_and_complete_returns_false():
         jit_exec.plane_breaker.reset()
 
 
+def test_compile_time_is_not_device_wait():
+    """A trace+compile is host work: the compile seam's bracket stops
+    the clock of the waits its thread registered, so a compile longer
+    than the envelope is no stall — and the envelope still applies to
+    the time around it."""
+    wd = DispatchWatchdog(stall_multiplier=1.0, floor_s=0.15,
+                          cold_floor_s=0.15, ceiling_s=5.0,
+                          quarantine_stalls=99, tick_s=0.02)
+    stalls: list = []
+    try:
+        entry = wd.register(site="dispatch", lane=None,
+                            on_stall=stalls.append)
+        with wd.compiling():
+            time.sleep(0.5)                 # > 3 envelopes of compile
+        assert not entry.stalled and not stalls
+        assert entry.compile_since is None
+        assert wd.complete(entry) is True
+        assert wd.stats()["stalls"] == 0
+        # the clock restarts after the compile: a wedged dispatch that
+        # follows it is still abandoned inside its envelope
+        entry = wd.register(site="dispatch", lane=None,
+                            on_stall=stalls.append)
+        with wd.compiling():
+            time.sleep(0.2)
+        assert wait_until(lambda: len(stalls) == 1, timeout=5.0)
+        assert wd.complete(entry) is False
+        # another thread's compile never touches this thread's wait
+        other = wd.register(site="dispatch", lane=None)
+        t = threading.Thread(target=lambda: wd.compiling().__enter__())
+        t.start()
+        t.join()
+        assert other.compile_since is None
+        assert wd.complete(other) is True
+    finally:
+        wd.reset()
+        jit_exec.plane_breaker.reset()
+
+
+def test_runaway_compile_is_still_a_stall():
+    """While compiling, a wait is overdue once the compile alone
+    outlasts ceiling_s — the longest envelope any wait can get."""
+    wd = DispatchWatchdog(stall_multiplier=1.0, floor_s=0.1,
+                          cold_floor_s=0.1, ceiling_s=0.3,
+                          quarantine_stalls=99, tick_s=0.02)
+    stalls: list = []
+    try:
+        entry = wd.register(site="dispatch", lane=None,
+                            on_stall=stalls.append)
+        with wd.compiling():
+            time.sleep(0.2)                 # past the envelope: fine
+            assert not stalls
+            assert wait_until(lambda: len(stalls) == 1, timeout=5.0)
+        assert wd.complete(entry) is False
+    finally:
+        wd.reset()
+        jit_exec.plane_breaker.reset()
+
+
 def test_disabled_watchdog_registers_nothing():
     wd = DispatchWatchdog(enabled=False)
     assert wd.register(site="dispatch") is None

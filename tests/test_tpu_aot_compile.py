@@ -1,0 +1,300 @@
+"""Ahead-of-time compiles of the served path's programs for a DESCRIBED
+TPU v5e (no chip attached): the TPU compiler refuses here — for free —
+what it would refuse on the chip: programs whose intermediates do not
+fit 16 GB of HBM at [B=64, 2^20 rows], top-k at k=1000 over 2^20 lanes,
+the block-max sweep's gathers, and the mesh lanes' collectives.
+
+Shapes are chip_smoke.py's: one 2^20-row bucketed segment at the 1M-doc
+corpus's unique-term width, B=64; the opt-in lanes' index at 2^15 rows
+with 768-dim vectors.
+
+This must stay ONE file with the topology fixture inside it: only one
+process may load the TPU library, pytest workers each import every test
+file, and only the worker that is handed this file may describe the
+topology — from inside a fixture, after a test of the file has started
+(never at import, in a ``skipif`` or in a ``parametrize`` argument).
+
+A compile that passes here is not a chip run and is never reported as
+one.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from elasticsearch_tpu.index.device_reader import device_reader_for
+from elasticsearch_tpu.index.engine import Engine
+from elasticsearch_tpu.index.segment import Segment, VectorFieldColumn
+from elasticsearch_tpu.mapping import MapperService
+from elasticsearch_tpu.search import jit_exec
+from elasticsearch_tpu.search.phase import (ShardSearcher,
+                                            parse_search_request)
+
+ROWS = 1 << 20            # chip_smoke's bucketed 1M-doc segment
+UNIQUE = 36               # its unique-term width (seed 20240924)
+VOCAB = 30_000
+BATCH = 64
+LANE_ROWS = 1 << 15       # the opt-in lanes' index
+LANE_VOCAB = 2_000
+VEC_DIMS = 768
+HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                # noqa: BLE001 — skip, don't fail
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip can be written to the persistent
+    cache but never read back; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+class _Captured(BaseException):
+    """Carries the chip's ``Compiled`` out of the lane: it cannot run
+    here, and a BaseException passes the lanes' ``except Exception →
+    eager rescue`` seams untouched."""
+
+    def __init__(self, compiled):
+        super().__init__("compiled for the described chip")
+        self.compiled = compiled
+
+
+@contextlib.contextmanager
+def steered_to(sharding_of, mesh=None):
+    """Steer the lane code from the test: inside, every program
+    ``jit_exec`` lowers is lowered for the described chip — its operands
+    become ``ShapeDtypeStruct``s carrying ``sharding_of(operand)`` — and
+    the compile result leaves through :class:`_Captured`. With ``mesh``,
+    shard_map programs are built over it instead of the CPU mesh their
+    operands were placed on. The program gets no option for this."""
+    from elasticsearch_tpu.parallel import mesh as mesh_mod
+
+    class Lowered:
+        def __init__(self, lowered):
+            self.lowered = lowered
+
+        def compile(self):
+            raise _Captured(self.lowered.compile())
+
+    class Jitted:
+        def __init__(self, fn, **kw):
+            self.jitted = jax.jit(fn, **kw)
+
+        def lower(self, *args):
+            shapes = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=sharding_of(a)),
+                args)
+            return Lowered(self.jitted.lower(*shapes))
+
+    proxy = types.SimpleNamespace(
+        **{n: getattr(jax, n) for n in dir(jax) if not n.startswith("__")})
+    proxy.jit = Jitted
+    real_jax, real_sm = jit_exec.jax, mesh_mod.shard_map_compat
+    jit_exec.jax = proxy
+    if mesh is not None:
+        described = mesh
+
+        def on_described_mesh(f, mesh, in_specs, out_specs):
+            return real_sm(f, mesh=described, in_specs=in_specs,
+                           out_specs=out_specs)
+        mesh_mod.shard_map_compat = on_described_mesh
+    try:
+        yield
+    finally:
+        jit_exec.jax = real_jax
+        mesh_mod.shard_map_compat = real_sm
+
+
+def captured(fn, *args, **kwargs):
+    """Run ``fn`` until its program is compiled → that ``Compiled``."""
+    with pytest.raises(_Captured) as exc:
+        fn(*args, **kwargs)
+    return exc.value.compiled
+
+
+def fits_hbm(compiled, label: str):
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    print(f"\n[aot] {label}: args {ma.argument_size_in_bytes / 2**20:.1f} "
+          f"MiB, out {ma.output_size_in_bytes / 2**20:.1f} MiB, temp "
+          f"{ma.temp_size_in_bytes / 2**20:.1f} MiB per device")
+    assert need < HBM_BYTES, f"{label} needs {need} bytes of 16 GB HBM"
+    return ma
+
+
+def _engine(tmp_path, rows: int, vocab: int, unique: int, *,
+            vec_dims: int = 0):
+    """An engine holding one packed segment of the given shape (the
+    values only have to be well-formed: nothing runs)."""
+    rng = np.random.default_rng(7)
+    ms = MapperService()
+    props = {"body": {"type": "text", "analyzer": "whitespace"}}
+    if vec_dims:
+        props["vec"] = {"type": "dense_vector", "dims": vec_dims}
+    ms.merge("_doc", {"properties": props})
+    uterms = rng.integers(0, vocab, (rows, unique)).astype(np.int32)
+    uterms.sort(axis=1)
+    df = np.bincount(uterms.ravel(), minlength=vocab)
+    w = len(str(vocab - 1))
+    seg = Segment.from_packed_text(
+        0, "body", terms=[f"t{i:0{w}d}" for i in range(vocab)],
+        tokens=None, uterms=uterms,
+        utf=np.ones((rows, unique), np.float32),
+        doc_len=np.full(rows, unique, np.int32), df=df, num_docs=rows,
+        ids=[""] * rows)
+    if vec_dims:
+        seg.vector_fields["vec"] = VectorFieldColumn(
+            vecs=np.zeros((rows, vec_dims), np.float32),
+            exists=np.ones(rows, bool), dims=vec_dims)
+    eng = Engine(tmp_path, ms)
+    eng.install_segment(seg, track_versions=False)
+    return eng, ms
+
+
+@pytest.fixture(scope="module")
+def big(tmp_path_factory):
+    eng, ms = _engine(tmp_path_factory.mktemp("aot_big"), ROWS, VOCAB,
+                      UNIQUE)
+    yield eng, ms
+    eng.close()
+    jit_exec.clear_cache()
+
+
+@pytest.fixture(scope="module")
+def lanes(tmp_path_factory):
+    eng, ms = _engine(tmp_path_factory.mktemp("aot_lanes"), LANE_ROWS,
+                      LANE_VOCAB, UNIQUE, vec_dims=VEC_DIMS)
+    yield eng, ms
+    eng.close()
+    jit_exec.clear_cache()
+
+
+def _match_reqs(vocab: int, size: int, **extra) -> list:
+    w = len(str(vocab - 1))
+    return [parse_search_request({
+        "query": {"match": {"body": " ".join(
+            f"t{(17 * i + 5 * j) % vocab:0{w}d}" for j in range(4))}},
+        "size": size, **extra}) for i in range(BATCH)]
+
+
+@pytest.mark.parametrize("k", [10, 1000])
+def test_flagship_bm25_topk_batch(topo, no_persistent_cache, k):
+    """models/bm25.bm25_topk_batch — what __graft_entry__.entry()
+    returns — at 2^20 rows × U, B=64."""
+    from jax.sharding import SingleDeviceSharding
+    from elasticsearch_tpu.models.bm25 import bm25_topk_batch
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    compiled = bm25_topk_batch.lower(
+        spec((ROWS, UNIQUE), jnp.int32), spec((ROWS, UNIQUE), jnp.float32),
+        spec((ROWS,), jnp.int32), spec((ROWS,), jnp.bool_),
+        spec((BATCH, 4), jnp.int32), spec((BATCH, 4), jnp.float32),
+        spec((), jnp.float32), k=k).compile()
+    fits_hbm(compiled, f"bm25_topk_batch k={k}")
+
+
+@pytest.mark.parametrize("k", [10, 1000])
+def test_reader_batch_program(topo, no_persistent_cache, big, k):
+    """The program the served BM25 path dispatches (`reader-batch`),
+    reached through ShardSearcher.query_phase_batch as the scheduler
+    reaches it."""
+    from jax.sharding import SingleDeviceSharding
+    eng, ms = big
+    chip = SingleDeviceSharding(topo.devices[0])
+    searcher = ShardSearcher(0, device_reader_for(eng), ms)
+    with steered_to(lambda a: chip):
+        compiled = captured(searcher.query_phase_batch,
+                            _match_reqs(VOCAB, k))
+    fits_hbm(compiled, f"reader-batch k={k}")
+
+
+def test_impact_pruned_sweep(topo, no_persistent_cache, big):
+    """The block-max pruned sweep (ops/blockmax.py) over the 2^20-row
+    impact columns and their [512, 30000] block-max table."""
+    from jax.sharding import SingleDeviceSharding
+    eng, ms = big
+    chip = SingleDeviceSharding(topo.devices[0])
+    jit_exec.configure_impact_plane(
+        "aot", {"index.search.impact_plane": "true"})
+    try:
+        searcher = ShardSearcher(0, device_reader_for(eng), ms,
+                                 index_name="aot")
+        with steered_to(lambda a: chip):
+            compiled = captured(
+                searcher.query_phase_batch,
+                _match_reqs(VOCAB, 10, track_total_hits=False))
+    finally:
+        jit_exec.configure_impact_plane("aot", {})
+    fits_hbm(compiled, "impact-pruned k=10")
+    assert "while" in compiled.as_text()      # the block sweep's loop
+
+
+def test_knn_program_768(topo, no_persistent_cache, lanes):
+    from jax.sharding import SingleDeviceSharding
+    eng, ms = lanes
+    chip = SingleDeviceSharding(topo.devices[0])
+    searcher = ShardSearcher(0, device_reader_for(eng), ms)
+    reqs = [parse_search_request({
+        "knn": {"field": "vec", "k": 10, "num_candidates": 100,
+                "query_vector": [float(i + 1)] * VEC_DIMS},
+        "size": 10}) for i in range(4)]
+    with steered_to(lambda a: chip):
+        compiled = captured(searcher.query_phase_batch, reqs)
+    fits_hbm(compiled, "knn 768 dims")
+
+
+def test_impact_mesh_program_1x4(topo, no_persistent_cache, big):
+    """One mesh program on four described chips, geometry (1,4): the
+    per-shard sweeps must merge through cross-chip collectives."""
+    from jax.sharding import NamedSharding
+    from elasticsearch_tpu.parallel.mesh import make_mesh
+    eng, ms = big
+    cpu_mesh = make_mesh(dp=1, shard=4, devices=jax.devices()[:4])
+    tpu_mesh = make_mesh(dp=1, shard=4, devices=topo.devices)
+    jit_exec.configure_impact_plane(
+        "aot", {"index.search.impact_plane": "true"})
+    jit_exec.set_serving_mesh(cpu_mesh)
+    try:
+        searcher = ShardSearcher(0, device_reader_for(eng), ms,
+                                 index_name="aot")
+        with steered_to(lambda a: NamedSharding(tpu_mesh, a.sharding.spec),
+                        mesh=tpu_mesh):
+            compiled = captured(
+                searcher.query_phase_batch,
+                _match_reqs(VOCAB, 10, track_total_hits=False))
+    finally:
+        jit_exec.set_serving_mesh(None)
+        jit_exec.configure_impact_plane("aot", {})
+    ma = fits_hbm(compiled, "impact-mesh (1,4) k=10")
+    # the doc axis really is split: a quarter of the columns per chip
+    assert ma.argument_size_in_bytes < ROWS * UNIQUE * 5 // 2
+    text = compiled.as_text()
+    assert "all-gather" in text, "no cross-chip candidate merge"
+    assert "all-reduce" in text, "no cross-chip count/theta reduction"
