@@ -710,13 +710,14 @@ class SearchActions:
 
     def _shard_traced(self, phase: str, name: str, shard: int, fn):
         """Run one shard-phase callable under a per-shard attribution
-        record (slow-log plane fields) and — when a trace is active — a
-        ``shard`` span whose finished subtree is attached to the payload
+        record (slow-log plane fields) and the shard phase's span — whose
+        finished subtree, when a trace is active, is attached to the payload
         as ``_profile`` (the coordinator pops it into the response's
         profile section). The payload is shallow-copied before the
         attach so request-cache entries never carry spans."""
         if not obs_trace.active():
-            with attribution.collect(admission="fanout"):
+            with attribution.collect(admission="fanout"), \
+                    obs_trace.span(phase):
                 return fn()
         from elasticsearch_tpu.observability import costs as obs_costs
         with attribution.collect(admission="fanout"), \
@@ -756,7 +757,7 @@ class SearchActions:
                              pin: dict, budget_ms=None) -> dict:
         t0 = time.perf_counter()
         return self._attach_ars(self._shard_traced(
-            "shard-query", name, shard,
+            "action.shard_query", name, shard,
             lambda: self._execute_shard_query_inner(
                 name, shard, body, doc_slot, dfs, pin, budget_ms)), t0)
 
@@ -819,7 +820,7 @@ class SearchActions:
 
     def _handle_shard_fetch(self, request: dict, source) -> dict:
         return self._shard_traced(
-            "shard-fetch", request["index"], request["shard"],
+            "action.shard_fetch", request["index"], request["shard"],
             lambda: self._handle_shard_fetch_inner(request))
 
     def _handle_shard_fetch_inner(self, request: dict) -> dict:
@@ -870,6 +871,10 @@ class SearchActions:
                 pass
 
     def _handle_shard_msearch(self, request: dict, source) -> dict:
+        with obs_trace.span("action.shard_msearch"):
+            return self._handle_shard_msearch_inner(request)
+
+    def _handle_shard_msearch_inner(self, request: dict) -> dict:
         """Shard-side _msearch: B request bodies against one shard in ONE
         batched device program when they share a plan
         (ShardSearcher.query_phase_batch — the TPU-native multi-search),
@@ -952,7 +957,7 @@ class SearchActions:
                        budget_ms=None) -> dict:
         t0 = time.perf_counter()
         return self._attach_ars(self._shard_traced(
-            "shard", name, shard,
+            "action.shard", name, shard,
             lambda: self._execute_shard_inner(
                 name, shard, body, doc_slot=doc_slot, dfs=dfs,
                 scroll_pin=scroll_pin, budget_ms=budget_ms)), t0)
@@ -1560,7 +1565,7 @@ class SearchActions:
                         obs_trace.profile_sink() as shard_profiles, \
                         obs_costs.collect_programs() as coord_progs, \
                         obs_trace.collect_spans() as coord_spans, \
-                        obs_trace.span("search", index=index_expr):
+                        obs_trace.span("action.search", index=index_expr):
                     resp = self._search(index_expr, body, scroll=scroll,
                                         search_type=search_type,
                                         routing=routing,
@@ -1579,10 +1584,11 @@ class SearchActions:
                         "programs": obs_costs.render_rows(coord_progs),
                     }
             else:
-                resp = self._search(index_expr, body, scroll=scroll,
-                                    search_type=search_type,
-                                    routing=routing,
-                                    preference=preference)
+                with obs_trace.span("action.search"):
+                    resp = self._search(index_expr, body, scroll=scroll,
+                                        search_type=search_type,
+                                        routing=routing,
+                                        preference=preference)
             if task is not None and task.cancelled:
                 resp["cancelled"] = True
             return resp
@@ -2213,7 +2219,7 @@ class SearchActions:
                      routing: str | None = None,
                      preference: str | None = None,
                      allow_partial=None) -> dict:
-        with obs_trace.span("parse"):
+        with obs_trace.span("action.parse"):
             names = self.node.indices_service.resolve_open(index_expr)
             body = rewrite_mlt_likes(self.node, body,
                                      names[0] if names else "_all")
@@ -2239,7 +2245,7 @@ class SearchActions:
             # does not provide.
             from elasticsearch_tpu.search import jit_exec
             with attribution.collect(admission="plane"), \
-                    obs_trace.span("plane") as psp:
+                    obs_trace.span("action.plane") as psp:
                 mesh_resp = self._try_collective_plane(
                     names, [body], [req], t0, search_type=search_type)
                 psp.set(served=mesh_resp is not None,
@@ -2292,7 +2298,7 @@ class SearchActions:
                                           allow_hedge=allow_hedge)
         q_t0 = time.perf_counter()
         payloads, failures = [], []
-        with obs_trace.span("query", shards=len(groups)):
+        with obs_trace.span("action.query", shards=len(groups)):
             futures = [self._submit(self._try_shard, state, n, s, copies,
                                     body, slot_of[(n, s)], dfs,
                                     scroll_pin, None, deadline_at,
@@ -2309,7 +2315,7 @@ class SearchActions:
                     failures.append(payload)
         q_ms = (time.perf_counter() - q_t0) * 1e3
         r_t0 = time.perf_counter()
-        with obs_trace.span("reduce"):
+        with obs_trace.span("action.reduce"):
             resp = merge_shard_payloads(
                 req, payloads, (time.perf_counter() - t0) * 1e3,
                 total_shards=len(groups), failures=failures)
@@ -2339,7 +2345,7 @@ class SearchActions:
         pin = {"uid": _uuid.uuid4().hex, "keep_s": 30.0}
         q_t0 = time.perf_counter()
         qpayloads, failures = [], []   # (payload, node_id, name, sid, slot)
-        with obs_trace.span("query", shards=len(groups)):
+        with obs_trace.span("action.query", shards=len(groups)):
             futures = [self._submit(self._try_shard, state, n, s, copies,
                                     body, slot_of[(n, s)], dfs,
                                     None, pin, budget_deadline,
@@ -2377,7 +2383,7 @@ class SearchActions:
             f_t0 = time.perf_counter()
             fetched: dict[tuple[int, int], dict] = {}
             fetch_failed: set[int] = set()
-            with obs_trace.span("fetch", shards=len(by_shard)):
+            with obs_trace.span("action.fetch", shards=len(by_shard)):
                 fetch_futs = {}
                 for si, positions in by_shard.items():
                     p, node_id, name, sid, slot = qpayloads[si]
@@ -2438,7 +2444,7 @@ class SearchActions:
             assemble_response, attach_phase_took)
         r_t0 = time.perf_counter()
         payloads = [p for p, *_ in qpayloads]
-        with obs_trace.span("reduce"):
+        with obs_trace.span("action.reduce"):
             resp = assemble_response(
                 req, payloads, hits_out,
                 (time.perf_counter() - t0) * 1e3,
@@ -2487,9 +2493,10 @@ class SearchActions:
                 groups[-1][2].append(i)
             else:
                 groups.append((index_expr, stype, [i]))
-        with self._coordinating_task(
-                "indices:data/read/msearch",
-                f"requests[{len(items)}]"):
+        with obs_trace.span("action.msearch"), \
+                self._coordinating_task(
+                    "indices:data/read/msearch",
+                    f"requests[{len(items)}]"):
             futures = [self._msearch_pool.submit(
                 tasks.bind_current(self._msearch_group), expr,
                 [items[i][1] for i in idxs],
@@ -2529,6 +2536,12 @@ class SearchActions:
 
     def _msearch_group(self, index_expr: str, bodies: list[dict],
                        search_type: str | None = None) -> list[dict]:
+        with obs_trace.span("action.msearch_group"):
+            return self._msearch_group_inner(index_expr, bodies,
+                                             search_type)
+
+    def _msearch_group_inner(self, index_expr: str, bodies: list[dict],
+                             search_type: str | None) -> list[dict]:
         """One shard fan-out for a group of bodies on one index expr.
         Bodies are parsed ONCE here — invalid items answer immediately and
         never ship; per-item SHARD errors surface as that item's shard
@@ -2558,7 +2571,7 @@ class SearchActions:
             # the same single dispatch; fallback runs the items through
             # the ordinary paths
             with attribution.collect(admission="plane"), \
-                    obs_trace.span("plane", batch=len(send_bodies)):
+                    obs_trace.span("action.plane", batch=len(send_bodies)):
                 mesh_outs = self._try_collective_plane(
                     names, send_bodies, [parsed[i] for i in valid], t0,
                     search_type=search_type)

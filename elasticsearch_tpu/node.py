@@ -960,7 +960,13 @@ class Node:
             # per-lane latency distributions (fixed-bucket histograms,
             # always on) + this node's span-store accounting
             "latency": _hist.summaries(self.node_id),
-            "tracing": _tracing.store_stats(self.node_id),
+            "tracing": {**_tracing.store_stats(self.node_id),
+                        # the always-on span ring (process-wide)
+                        "ring": _tracing.ring_stats()},
+            # the in-flight book: how long the host left the device
+            # with no launch in flight (``starved_pct``), process-wide
+            # and cumulative — two reads give a window
+            "device": _tracing.book_stats(),
             "process": ps,
             "os": osx,
             # process-level memory reported under the reference's jvm

@@ -10,10 +10,11 @@ cheap enough to stay on even when the span tracer is off.
 
 Lanes: ``plane`` (collective-plane searches, per body), ``fanout``
 (RPC fan-out searches), ``percolate`` (batched percolation runs),
-``bulk`` (bulk requests), ``queue_wait`` (threadpool queue time),
-``device_rtt`` (device dispatch round trips — fed by the tracing
-module's :func:`~elasticsearch_tpu.observability.tracing.device_span`
-at dispatch-class seam sites).
+``bulk`` (bulk requests), ``queue_wait`` (every queue's time on the
+node: the thread pools' and the scheduler's — the scheduler keeps a
+histogram of its own beside it), ``device_rtt`` (device round trips:
+a launch's enqueue → the end of the drain that fetched its result,
+fed by the tracing module's in-flight book when the launch closes).
 
 Registries key on node id (see context.py) so multi-node in-process
 clusters report per-node numbers in ``_nodes/stats``.

@@ -10,9 +10,11 @@ maps straight onto the VPU with zero scatter/gather, exact BM25 scores
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("bm25_score")
 def bm25_match(uterms, utf, doc_len, qtids, qidf, qweight, k1, b, avgdl):
     """Score a (multi-term, OR-semantics) match query against one segment.
 

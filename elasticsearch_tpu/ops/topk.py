@@ -24,6 +24,7 @@ import jax.numpy as jnp
 NEG_INF = jnp.float32(-jnp.inf)
 
 
+@jax.named_scope("topk_select")
 def top_k(scores, mask, k: int, doc_base: int = 0):
     """Per-segment/shard top-k.
 
@@ -75,6 +76,7 @@ def merge_top_k_batch(scores_list, docs_list, k: int, bases):
                               tuple(int(b) for b in bases))
 
 
+@jax.named_scope("topk_merge")
 def merge_top_k_batch_body(scores_list, docs_list, k: int, bases):
     """Traceable body shared by the standalone jitted entry below and the
     fused reader program (jit_exec.run_reader_batch) — ONE copy of the
@@ -110,6 +112,7 @@ def pack_batch_result(top_scores, top_docs, counts):
     return _pack_batch_result(top_scores, top_docs, counts)
 
 
+@jax.named_scope("pack_result")
 def pack_batch_result_body(top_scores, top_docs, counts):
     """Traceable body (shared with the fused reader program)."""
     return jnp.concatenate(

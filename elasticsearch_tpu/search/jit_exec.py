@@ -41,7 +41,7 @@ import numpy as np
 from elasticsearch_tpu.index.device_reader import DeviceSegment
 from elasticsearch_tpu.observability import attribution as _attribution
 from elasticsearch_tpu.observability.context import current_node_id
-from elasticsearch_tpu.observability.tracing import device_span
+from elasticsearch_tpu.observability.tracing import device_span, span
 from elasticsearch_tpu.ops import blockmax as blockmax_ops
 from elasticsearch_tpu.ops import topk as topk_ops
 from elasticsearch_tpu.search import lanes
@@ -962,19 +962,21 @@ def run_reader_batch(segments: list, ctx: ExecutionContext, queries: list,
     if not queries or not segments:
         return None
     k_static = int(k)
-    plans = []
-    for seg in segments:
-        plan = _plan_segment_batch(seg, ctx, queries, k_static)
-        if plan is None:
-            return None
-        plans.append(plan)
-    b = len(queries)
-    b_pad = plans[0]["b_pad"]
-    bases = tuple(int(seg.doc_base) for seg in segments)
-    key = ("reader", bases, bool(pack)) + tuple(p["key"] for p in plans)
-    flats = [p["flat"] for p in plans]
-    packeds = [{dt: jnp.asarray(buf) for dt, buf in p["packed"].items()}
-               for p in plans]
+    with span("jit.pack"):
+        # plans, flats and the packed operands: all host work
+        plans = []
+        for seg in segments:
+            plan = _plan_segment_batch(seg, ctx, queries, k_static)
+            if plan is None:
+                return None
+            plans.append(plan)
+        b = len(queries)
+        b_pad = plans[0]["b_pad"]
+        bases = tuple(int(seg.doc_base) for seg in segments)
+        key = ("reader", bases, bool(pack)) + tuple(p["key"] for p in plans)
+        flats = [p["flat"] for p in plans]
+        packeds = [{dt: jnp.asarray(buf) for dt, buf in p["packed"].items()}
+                   for p in plans]
     if os.environ.get("JIT_DEBUG"):
         total = sum(int(a.size) * a.dtype.itemsize
                     for flat in flats for a in flat)
@@ -987,11 +989,12 @@ def run_reader_batch(segments: list, ctx: ExecutionContext, queries: list,
         def run(flats_in, packeds_in):
             ts_list, td_list = [], []
             counts = None
-            for plan, flat_in, packed_in in zip(plans, flats_in,
-                                                packeds_in):
+            for i, (plan, flat_in, packed_in) in enumerate(
+                    zip(plans, flats_in, packeds_in)):
                 view = seg_rebuild(plan["seg"], flat_in,
                                    plan["pos"], plan["vecs"])
-                outs = jax.vmap(_lane_fn(plan, view))(packed_in)
+                with jax.named_scope(f"segment_{i}"):
+                    outs = jax.vmap(_lane_fn(plan, view))(packed_in)
                 ts_list.append(outs["top_scores"])
                 td_list.append(outs["top_docs"])
                 counts = outs["count"] if counts is None \

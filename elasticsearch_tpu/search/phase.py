@@ -28,6 +28,7 @@ import numpy as np
 from elasticsearch_tpu.common.errors import (QueryParsingError,
                                              TaskCancelledError)
 from elasticsearch_tpu.index.device_reader import DeviceReader
+from elasticsearch_tpu.observability import tracing as obs_trace
 from elasticsearch_tpu.ops import topk as topk_ops
 from elasticsearch_tpu.search import query_dsl as q
 from elasticsearch_tpu.search.aggregations import (
@@ -569,7 +570,14 @@ class ShardSearcher:
         plan = planner.plan_batch(self, reqs, n_real=n_real)
         if plan is None:
             return None
-        return planner.launch_plan(plan)
+        # what the arm enqueues stays in flight (the in-flight book)
+        # until the end of this handle's drain
+        with obs_trace.launch_scope() as launches:
+            handle = planner.launch_plan(plan)
+        if handle is None:
+            obs_trace.close_launches(launches, drained=False)
+            return None
+        return handle + (launches,)
 
     def _exact_batch_launch(self, reqs: list, n_real: int | None = None):
         """The exact batched arm (the planner's tier-3 catch-all):
@@ -1055,39 +1063,60 @@ class ShardSearcher:
             # span on profiled responses; mispriced warm plans land on
             # the flight recorder)
             from elasticsearch_tpu.search import planner
-            _, node, plan, t0, inner = handle
-            results = self.query_phase_batch_drain(inner)
+            _, node, plan, t0, inner = handle[:5]
+            results = self._drain_arm(inner, handle[5] if len(handle) > 5
+                                      else ())
             planner.finish_plan(node, plan, t0)
             return results
+        return self._drain_arm(handle, ())
+
+    def _drain_arm(self, handle, launches) -> list[ShardQueryResult]:
+        """One arm's handle → results: ``jit.drain`` blocks until the
+        launched arrays are on the host — its end closes the handle's
+        launches in the in-flight book, on every exit — and
+        ``jit.unpack`` builds the per-request results from them."""
         tag, reqs = handle[0], handle[1]
         if tag == "empty":
             return [ShardQueryResult(self.shard_id, 0, None,
                                      np.zeros(0, np.int32),
                                      np.zeros(0, np.float32), None, {},
                                      self.reader) for _ in reqs]
-        if tag == "knn":
-            _, _, _k, out = handle
-            ms = np.asarray(out["top_scores"])
-            md = np.asarray(out["top_docs"])
-            totals = np.asarray(out["count"])
-            results = []
-            for bi, req in enumerate(reqs):
-                kq = self._knn_limit(req)
-                valid = md[bi] >= 0
-                s_, d_ = ms[bi][valid][:kq], md[bi][valid][:kq]
-                results.append(ShardQueryResult(
-                    self.shard_id, int(totals[bi]),
-                    float(s_[0]) if s_.size else None,
-                    d_.astype(np.int32), s_.astype(np.float32), None,
-                    {}, self.reader))
-            return results
+        try:
+            with obs_trace.span("jit.drain"):
+                if tag == "host":
+                    host = handle[3]
+                elif tag == "device" and handle[3]:
+                    # single-fetch fast path: scoring, merge AND result
+                    # packing ran as one program — one dispatch + one
+                    # device→host fetch per batch
+                    host = np.asarray(handle[4])
+                else:
+                    out = handle[4] if tag == "device" else handle[3]
+                    names = ("top_scores", "top_docs", "count")
+                    if tag == "impact" and handle[4]:       # pruned
+                        names += ("blocks_scored", "blocks_skipped")
+                    host = {name: np.asarray(out[name]) for name in names}
+        finally:
+            obs_trace.close_launches(launches)
+        with obs_trace.span("jit.unpack"):
+            return self._unpack_arm(handle, host)
+
+    def _unpack_arm(self, handle, host) -> list[ShardQueryResult]:
+        tag, reqs, k = handle[0], handle[1], handle[2]
+        if tag == "host":
+            ms, md, totals = host
+        elif tag == "device" and handle[3]:
+            ms, md, totals = topk_ops.unpack_batch_result(host, k)
+        else:
+            ms, md, totals = (host["top_scores"], host["top_docs"],
+                              host["count"])
         if tag == "impact":
             from elasticsearch_tpu.observability import attribution
             from elasticsearch_tpu.search import jit_exec
-            _, _, k, out, pruned, total_blocks, n_real = handle
+            _, _, _, _, pruned, total_blocks, n_real = handle
             if pruned:
-                scored = int(np.asarray(out["blocks_scored"]).sum())
-                skipped = int(np.asarray(out["blocks_skipped"]).sum())
+                scored = int(host["blocks_scored"].sum())
+                skipped = int(host["blocks_skipped"].sum())
                 attribution.label(
                     "pruned", f"{skipped}/{scored + skipped} blocks")
             else:
@@ -1095,40 +1124,21 @@ class ShardSearcher:
                 # effective-work accounting for the skip-ratio surfaces
                 # (real rows only: pad replicas are not admissions)
                 scored, skipped = total_blocks * n_real, 0
-            ms = np.asarray(out["top_scores"])
-            md = np.asarray(out["top_docs"])
-            totals = np.asarray(out["count"])
             jit_exec.note_impact_served(self.ctx.index_name, n_real,
                                         scored, skipped)
         elif tag == "rescore":
             from elasticsearch_tpu.search import jit_exec
-            _, _, k, out, total_blocks, n_real = handle
-            ms = np.asarray(out["top_scores"])
-            md = np.asarray(out["top_docs"])
-            totals = np.asarray(out["count"])
+            _, _, _, _, total_blocks, n_real = handle
             # the composed plan's candidate stage is eager — every
             # block scored — and the whole rescore rode the one
             # dispatch (the counter the fusion bench reconciles)
             jit_exec.note_impact_served(self.ctx.index_name, n_real,
                                         total_blocks * n_real, 0)
             jit_exec.note_rescore_fused(n_real)
-        elif tag == "host":
-            _, _, k, (ms, md, totals) = handle
-        else:
-            _, _, k, pack, out = handle
-            if pack:
-                # single-fetch fast path: scoring, merge AND result
-                # packing ran as one program — one dispatch + one
-                # device→host fetch per batch
-                ms, md, totals = topk_ops.unpack_batch_result(
-                    np.asarray(out), k)
-            else:
-                ms = np.asarray(out["top_scores"])
-                md = np.asarray(out["top_docs"])
-                totals = np.asarray(out["count"])
         results = []
         for bi, req in enumerate(reqs):
-            kq = max(req.from_ + req.size, 1)
+            kq = self._knn_limit(req) if tag == "knn" \
+                else max(req.from_ + req.size, 1)
             valid = md[bi] >= 0
             s_, d_ = ms[bi][valid][:kq], md[bi][valid][:kq]
             results.append(ShardQueryResult(
@@ -1527,6 +1537,12 @@ class ShardSearcher:
     # -- fetch phase ---------------------------------------------------------
 
     def fetch_phase(self, req: ParsedSearchRequest, result: ShardQueryResult,
+                    index_name: str, positions: list[int]) -> list[dict]:
+        # one span per call, never per hit
+        with obs_trace.span("fetch.hits"):
+            return self._fetch_hits(req, result, index_name, positions)
+
+    def _fetch_hits(self, req: ParsedSearchRequest, result: ShardQueryResult,
                     index_name: str, positions: list[int]) -> list[dict]:
         from elasticsearch_tpu.index.engine import _segment_meta
         meta_wanted = [f for f in req.stored_fields

@@ -90,10 +90,13 @@ DEFAULT_PRIORITIES = {"plane": 3, "impact": 2, "knn": 2, "percolate": 1}
 SHED_MIN_SAMPLES = 16
 
 
-def _invoke(fn, *args, **kwargs):
-    """Trivial invoker ``bind_context`` wraps — identity when the
-    submitting thread carried no observability context."""
-    return fn(*args, **kwargs)
+def _invoke(span_name, fn, *args, **kwargs):
+    """The invoker ``bind_context`` wraps: runs a batch's launch or
+    drain under its ``scheduler.*`` span — and, for a single-waiter
+    batch, under the submitting thread's observability context."""
+    from elasticsearch_tpu.observability import tracing as obs_trace
+    with obs_trace.span(span_name):
+        return fn(*args, **kwargs)
 
 
 def query_shape(q_node) -> tuple:
@@ -322,6 +325,11 @@ class ContinuousBatchScheduler:
         self._qw_good = 0
         self._qw_bad = 0
         self._qw_target_ms = 50.0       # refreshed from slo config
+        # enqueue → pick-up of THIS scheduler's waiters, and no other
+        # queue's (``stats()["queue_wait_ms"]``)
+        from elasticsearch_tpu.observability.histograms import \
+            LatencyHistogram
+        self._queue_wait = LatencyHistogram()
 
     # ---- admission ---------------------------------------------------------
 
@@ -403,10 +411,9 @@ class ContinuousBatchScheduler:
         :class:`SchedulerRejectedError` when shed at admission."""
         from elasticsearch_tpu.observability import tracing as obs_trace
         w = self.submit(lane, key, req, launch, drain)
-        if obs_trace.active():
-            with obs_trace.span("scheduler.queue", lane=lane) as sp:
-                w.picked.wait(EXECUTE_BACKSTOP_S)
-                sp.set(queue_ms=round(w.queue_ms, 3))
+        with obs_trace.span("scheduler.queue", lane=lane) as sp:
+            w.picked.wait(EXECUTE_BACKSTOP_S)
+            sp.set(queue_ms=round(w.queue_ms, 3))
         try:
             out = w.future.result(timeout=EXECUTE_BACKSTOP_S)
         except FutTimeout:
@@ -450,6 +457,11 @@ class ContinuousBatchScheduler:
             # queueing — the next batch forms larger for free (the
             # continuous-batching win)
             self._inflight_sem.acquire()
+            if self._closed:
+                # closed while this thread waited for the window: form
+                # no batch; the flush declines what is queued
+                self._inflight_sem.release()
+                return
             try:
                 with self._lock:
                     q, batch = self._next_batch_locked()
@@ -560,6 +572,9 @@ class ContinuousBatchScheduler:
         bad = 0
         for w in live:
             w.queue_ms = (t_pick - w.enq_t) * 1e3
+            self._queue_wait.observe(w.queue_ms)
+            # the node's queue-time lane (and its SLO book) sees every
+            # queue on the node, this one and the thread pools'
             obs_hist.observe_lane("queue_wait", w.queue_ms,
                                   self.node_id or "")
             bad += w.queue_ms > self._qw_target_ms
@@ -634,8 +649,8 @@ class ContinuousBatchScheduler:
             on_stall=lambda err: self._abandon_batch(state))
         try:
             reqs = [w.req for w in live]
-            results = runner(q.launch, reqs) if runner is not None \
-                else q.launch(reqs)
+            results = (runner or _invoke)("scheduler.launch", q.launch,
+                                          reqs)
         except Exception:                # noqa: BLE001 — serial retry owns it
             results = None
         wd.dispatch_watchdog.complete(entry)
@@ -655,14 +670,12 @@ class ContinuousBatchScheduler:
             n_real=len(live),
             on_stall=lambda err: self._abandon_batch(state))
         results = None
+        run = runner or _invoke
         try:
-            if runner is not None:
-                handle = runner(q.launch, reqs, n_real=len(live))
-            else:
-                handle = q.launch(reqs, n_real=len(live))
+            handle = run("scheduler.launch", q.launch, reqs,
+                         n_real=len(live))
             if handle is not None:
-                results = runner(q.drain, handle) if runner is not None \
-                    else q.drain(handle)
+                results = run("scheduler.drain", q.drain, handle)
         except Exception:                # noqa: BLE001 — serial retry owns it
             results = None
         wd.dispatch_watchdog.complete(entry)
@@ -805,6 +818,10 @@ class ContinuousBatchScheduler:
                 "batches_abandoned": self._batches_abandoned,
                 "in_flight_high_water": self._inflight_hw,
                 "pad_rows": self._pad_rows,
+                "queue_wait_ms": {
+                    "count": self._queue_wait.count,
+                    "p50": round(self._queue_wait.percentile(0.50), 4),
+                    "p95": round(self._queue_wait.percentile(0.95), 4)},
                 "reconciled": (
                     self._submitted == self._queued + self._inflight_reqs
                     + self._delivered + self._declined + self._shed
