@@ -3,9 +3,20 @@
 The TPU replacement for Lucene's TermScorer/BooleanScorer postings iteration
 (the hot loop behind core/search/query/QueryPhase.java:314): instead of
 walking per-term postings lists, every doc row's unique-term array is
-compared against the query terms — a dense [N, U]×[T] compare/reduce that
-maps straight onto the VPU with zero scatter/gather, exact BM25 scores
+compared against the query terms — a dense [N, U]×[T] compare that maps
+straight onto the VPU with zero scatter/gather, exact BM25 scores
 (BM25S-style eager scoring, PAPERS.md).
+
+``bm25_match`` is ONE pass over a segment's columns: every slot is compared
+with each of the T query terms and earns the sum of the weights (idf ·
+boost) of the terms it holds, elementwise; then one reduction along U sums
+``tf_norm · weight`` over the slots that earned any. XLA emits one fusion a
+segment that reads ``uterms``, ``utf`` and ``doc_len`` once and writes [N];
+no [N, U] temporary reaches HBM, and a further query term costs a compare,
+a select and an add a slot, not another read of the columns. On a v5e the
+fusion takes 2.47 ms for a [2^20, 224] segment (1.88 GB) at 2 to 12 terms
+(PERF.md section 6, PR 27). ``classic_match`` and ``lm_dirichlet_match``
+still make one pass per term.
 """
 
 from __future__ import annotations
@@ -30,22 +41,26 @@ def bm25_match(uterms, utf, doc_len, qtids, qidf, qweight, k1, b, avgdl):
 
     Returns:
       scores:  [N] f32  Σ_t idf_t · tfNorm(tf_t,d)
-      nmatch:  [N] i32  number of distinct query terms matching each doc
-               (drives minimum_should_match / operator=and)
+      nmatch:  [N] i32  number of query terms matching each doc (a term
+               the query repeats counts twice; drives
+               minimum_should_match / operator=and)
     """
-    n = uterms.shape[0]
     norm = k1 * (1.0 - b + b * doc_len.astype(jnp.float32) / avgdl)   # [N]
     tf_norm = utf * (k1 + 1.0) / (utf + norm[:, None])                # [N, U]
-    scores = jnp.zeros(n, dtype=jnp.float32)
-    nmatch = jnp.zeros(n, dtype=jnp.int32)
-    T = qtids.shape[0]
-    for t in range(T):  # T is static; unrolled and fused by XLA
-        tid = qtids[t]
-        hit = (uterms == tid) & (tid >= 0)                            # [N, U]
-        any_hit = hit.any(axis=1)
-        scores = scores + qidf[t] * qweight[t] * jnp.where(
-            any_hit, (tf_norm * hit).sum(axis=1), 0.0)
-        nmatch = nmatch + any_hit.astype(jnp.int32)
+    # an absent term (any negative id) becomes -2, which no slot holds:
+    # a slot is a term id >= 0 or the -1 pad
+    tids = jnp.where(qtids >= 0, qtids, -2)
+    tweight = qidf * qweight
+    w = jnp.zeros(uterms.shape, jnp.float32)     # weight a slot earns
+    cnt = jnp.zeros(uterms.shape, jnp.int32)     # query terms hitting it
+    for t in range(qtids.shape[0]):  # T is static: T compares a slot
+        hit = uterms == tids[t]
+        w = w + jnp.where(hit, tweight[t], 0.0)
+        cnt = cnt + hit
+    # a select, not tf_norm * w alone: a slot no term hit has w == 0 and
+    # must add 0 even where tf_norm is 0/0 (a pad of a row whose norm is 0)
+    scores = jnp.where(w != 0.0, tf_norm * w, 0.0).sum(axis=1)
+    nmatch = cnt.sum(axis=1)
     return scores, nmatch
 
 

@@ -91,6 +91,79 @@ class TestBM25:
         assert s.shape == (len(docs),)
 
 
+def np_bm25_slots(uterms, utf, doc_len, qtids, qidf, qweight, k1, b, avgdl):
+    """float64 BM25 written from the formula over the forward columns:
+    score(d) = Σ_t idf_t · w_t · tf·(k1+1) / (tf + k1·(1 − b + b·dl/avgdl))
+    over the query terms present in d (a repeated term counts twice);
+    nmatch(d) = how many of the query's entries d holds."""
+    scores = np.zeros(len(uterms))
+    nmatch = np.zeros(len(uterms), np.int32)
+    for d in range(len(uterms)):
+        norm = k1 * (1.0 - b + b * float(doc_len[d]) / avgdl)
+        for t, tid in enumerate(qtids):
+            if tid < 0:
+                continue
+            for u in np.flatnonzero(uterms[d] == tid):
+                tf = float(utf[d, u])
+                scores[d] += (float(qidf[t]) * float(qweight[t])
+                              * tf * (k1 + 1.0) / (tf + norm))
+                nmatch[d] += 1
+    return scores, nmatch
+
+
+class TestBM25OnePass:
+    """bm25_match against the formula in float64, by number of query
+    terms and by how it is called."""
+
+    @pytest.mark.parametrize("how", ["plain", "jit", "vmap"])
+    @pytest.mark.parametrize("n_terms", [1, 2, 6, 12, 16])
+    def test_formula(self, rng, n_terms, how):
+        vocab, n_queries = 40, 3
+        _, _, uterms, utf, doc_len = make_corpus(rng, n_docs=192,
+                                                 vocab=vocab, max_len=24)
+        # rows 0-2 are all padding; row 3 keeps its terms at doc_len 0
+        uterms[:3], utf[:3], doc_len[:4] = -1, 0.0, 0
+        qtids = rng.integers(0, vocab, (n_queries, n_terms)).astype(np.int32)
+        if n_terms > 1:
+            qtids[:, 1] = -1                       # an absent term
+            qtids[1, :] = -1                       # a query with no term
+        if n_terms > 2:
+            qtids[:, 2] = qtids[:, 0]              # a repeated term
+        qidf = np.where(qtids >= 0, rng.uniform(
+            0.1, 9.0, qtids.shape), 0.0).astype(np.float32)
+        qweight = rng.uniform(0.5, 3.0, qtids.shape).astype(np.float32)
+        # b = 1 with doc_len 0 makes row 3's norm 0: its padded slots
+        # hold 0/0, which must not reach its (or any) score
+        k1, b, avgdl = 1.2, 1.0, 17.5
+
+        def f(qt, qi, qw):
+            return lexical.bm25_match(jnp.array(uterms), jnp.array(utf),
+                                      jnp.array(doc_len), qt, qi, qw,
+                                      k1, b, avgdl)
+        if how == "vmap":
+            scores, nmatch = jax.jit(jax.vmap(f))(
+                jnp.array(qtids), jnp.array(qidf), jnp.array(qweight))
+        else:
+            g = jax.jit(f) if how == "jit" else f
+            outs = [g(jnp.array(qtids[q]), jnp.array(qidf[q]),
+                      jnp.array(qweight[q])) for q in range(n_queries)]
+            scores = np.stack([np.asarray(s) for s, _ in outs])
+            nmatch = np.stack([np.asarray(m) for _, m in outs])
+        scores, nmatch = np.asarray(scores), np.asarray(nmatch)
+        assert scores.dtype == np.float32 and nmatch.dtype == np.int32
+        assert np.isfinite(scores).all()
+        for q in range(n_queries):
+            ref_s, ref_n = np_bm25_slots(uterms, utf, doc_len, qtids[q],
+                                         qidf[q], qweight[q], k1, b, avgdl)
+            np.testing.assert_allclose(scores[q], ref_s, rtol=1e-5, atol=0)
+            np.testing.assert_array_equal(nmatch[q], ref_n)
+        assert (scores[:, :3] == 0).all() and (nmatch[:, :3] == 0).all()
+        if n_terms > 1:
+            assert (scores[1] == 0).all() and (nmatch[1] == 0).all()
+        if n_terms > 2:
+            assert nmatch.max() >= 2 and scores.max() > 0
+
+
 class TestPhrase:
     def test_exact_phrase(self):
         # doc0: "a b c", doc1: "b a b c", doc2: "a c b"

@@ -45,6 +45,11 @@ LANE_ROWS = 1 << 15       # the opt-in lanes' index
 LANE_VOCAB = 2_000
 VEC_DIMS = 768
 HBM_BYTES = 16 * 1024 ** 3
+# temp_size_in_bytes of the B=64, 4-term `reader-batch` program by k, at
+# the parent of the PR that made bm25_match one pass (commit e62604a,
+# this file's test_reader_batch_program run there; a compile for the
+# described v5e, not a chip run)
+PARENT_B64_TEMP_BYTES = {10: 2_685_644_800, 1000: 1_511_626_752}
 
 
 @pytest.fixture(scope="module")
@@ -193,12 +198,13 @@ def lanes(tmp_path_factory):
     jit_exec.clear_cache()
 
 
-def _match_reqs(vocab: int, size: int, **extra) -> list:
+def _match_reqs(vocab: int, size: int, *, batch: int = BATCH,
+                terms: int = 4, **extra) -> list:
     w = len(str(vocab - 1))
     return [parse_search_request({
         "query": {"match": {"body": " ".join(
-            f"t{(17 * i + 5 * j) % vocab:0{w}d}" for j in range(4))}},
-        "size": size, **extra}) for i in range(BATCH)]
+            f"t{(17 * i + 5 * j) % vocab:0{w}d}" for j in range(terms))}},
+        "size": size, **extra}) for i in range(batch)]
 
 
 @pytest.mark.parametrize("k", [10, 1000])
@@ -232,7 +238,33 @@ def test_reader_batch_program(topo, no_persistent_cache, big, k):
     with steered_to(lambda a: chip):
         compiled = captured(searcher.query_phase_batch,
                             _match_reqs(VOCAB, k))
-    fits_hbm(compiled, f"reader-batch k={k}")
+    ma = fits_hbm(compiled, f"reader-batch k={k}")
+    # bm25_match keeps no [N, U] hit mask per query term
+    assert ma.temp_size_in_bytes <= PARENT_B64_TEMP_BYTES[k]
+
+
+@pytest.mark.parametrize("terms", [2, 12])
+def test_reader_batch_reads_columns_once(topo, no_persistent_cache, big,
+                                         terms):
+    """The B = 1, k = 1000 `reader-batch` program of a `match` query —
+    what a mixed `_msearch` dispatches per query — reads the segment's
+    forward columns once whatever the query's length: bm25_match is one
+    pass over [N, U], not one (and more) per query term."""
+    from jax.sharding import SingleDeviceSharding
+    eng, ms = big
+    chip = SingleDeviceSharding(topo.devices[0])
+    searcher = ShardSearcher(0, device_reader_for(eng), ms)
+    with steered_to(lambda a: chip):
+        compiled = captured(searcher.query_phase_batch,
+                            _match_reqs(VOCAB, 1000, batch=1, terms=terms))
+    columns = ROWS * UNIQUE * (4 + 4)             # uterms int32 + utf f32
+    accessed = compiled.cost_analysis()["bytes accessed"]
+    print(f"\n[aot] reader-batch B=1 k=1000 T={terms}: bytes accessed "
+          f"{accessed} = {accessed / columns:.3f} x the columns' {columns}")
+    # one read of the columns, and what the program moves per ROW beside
+    # them (doc_len, the live mask, the scores through top-k and packing),
+    # which does not grow with U: 127 bytes a row at both lengths here
+    assert accessed <= 1.25 * columns + 128 * ROWS
 
 
 def test_impact_pruned_sweep(topo, no_persistent_cache, big):
