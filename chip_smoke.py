@@ -150,7 +150,7 @@ class Corpus:
         import numpy as np
         from elasticsearch_tpu.index.segment import (
             KeywordFieldColumn, NumericFieldColumn, Segment,
-            VectorFieldColumn, doc_count_bucket)
+            doc_count_bucket)
         rows = hi - lo
         np_rows = doc_count_bucket(rows)
 
@@ -162,23 +162,23 @@ class Corpus:
         seg_df = np.zeros(self.vocab, np.int64)
         ut = self.uterms[lo:hi]
         np.add.at(seg_df, ut[ut >= 0], 1)
+        exists = np.zeros(np_rows, bool)
+        exists[:rows] = True
+        vectors = None
+        if vecs is not None:
+            padded = np.zeros((np_rows, vecs.shape[1]), np.float32)
+            padded[:rows] = vecs[lo:hi]
+            vectors = {"vec": (padded, exists.copy())}
         seg = Segment.from_packed_text(
             0, "body", terms=self.term_names, tokens=None,
             uterms=pad(self.uterms, -1), utf=pad(self.utf, 0.0),
             doc_len=pad(self.lens, 0), df=seg_df, num_docs=rows,
             ids=[str(lo + i) for i in range(rows)]
-            + [""] * (np_rows - rows))
-        exists = np.zeros(np_rows, bool)
-        exists[:rows] = True
+            + [""] * (np_rows - rows), vectors=vectors)
         seg.numeric_fields["rank"] = NumericFieldColumn(
             values=pad(self.rank, 0.0), exists=exists.copy())
         seg.keyword_fields["cat"] = KeywordFieldColumn(
             vocab=list(self.cat_names), ords=pad(self.cat[:, None], -1))
-        if vecs is not None:
-            padded = np.zeros((np_rows, vecs.shape[1]), np.float32)
-            padded[:rows] = vecs[lo:hi]
-            seg.vector_fields["vec"] = VectorFieldColumn(
-                vecs=padded, exists=exists.copy(), dims=vecs.shape[1])
         return seg
 
 

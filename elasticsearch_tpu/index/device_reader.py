@@ -159,12 +159,25 @@ class TextFieldStats:
         return self.total_tokens / max(self.docs_with_field, 1)
 
 
+def _lazy_vector_bytes(seg: Segment) -> int:
+    """Bytes of the columns the reader keeps host-side (``vecs`` of dense
+    and rank_vectors fields): the knn lane uploads them through the
+    per-segment block cache, which charges them itself (ledger component
+    ``vector``) — charged here too, a vector index would be booked twice
+    and trip the fielddata breaker at half its limit."""
+    total = sum(c.vecs.nbytes for c in seg.vector_fields.values())
+    total += sum(c.vecs.nbytes for c in seg.mvector_fields.values())
+    for blk in seg.nested_blocks.values():
+        total += _lazy_vector_bytes(blk.segment)
+    return total
+
+
 def resident_prefix_bytes(view: SearcherView,
                           hbm_budget_bytes: int | None) -> int:
-    """Column bytes of the segment prefix that stays HBM-resident under a
-    budget (mirrors DeviceReader's cutoff: the first segment whose
-    cumulative size exceeds the budget — and everything after it —
-    streams)."""
+    """Bytes the READER uploads for the segment prefix that stays
+    HBM-resident under a budget (mirrors DeviceReader's cutoff: the first
+    segment whose cumulative size — every column, the vectors too —
+    exceeds the budget, and everything after it, streams)."""
     total = 0
     used = 0
     for seg in view.segments:
@@ -173,7 +186,7 @@ def resident_prefix_bytes(view: SearcherView,
             used += b
             if used > hbm_budget_bytes:
                 break
-        total += b
+        total += b - _lazy_vector_bytes(seg)
     return total
 
 
@@ -232,21 +245,20 @@ class DeviceReader:
             hi, lo = dd_split(c.values)
             numeric[name] = DeviceNumericField(
                 hi=put(hi), lo=put(lo), exists=put(c.exists), column=c)
+        # the normalized host columns are the knn lane's, computed once
+        # per immutable Segment and shared (jit_exec._host_knn_column):
+        # dense rows unit length, rank_vectors per TOKEN (padding rows
+        # stay zero, so MaxSim's token dot is the token cosine)
+        from elasticsearch_tpu.search.jit_exec import _host_knn_column
         vector = {}
         for name, c in seg.vector_fields.items():
-            norms = np.linalg.norm(c.vecs, axis=1, keepdims=True)
-            normed = c.vecs / np.maximum(norms, 1e-12)
             vector[name] = DeviceVectorField(
-                vecs=np.ascontiguousarray(normed.astype(np.float32)),  # lazy
+                vecs=_host_knn_column(seg, name, "f32")[0]["vecs"],  # lazy
                 exists=put(c.exists), column=c)
         mvector = {}
         for name, c in seg.mvector_fields.items():
-            # per-TOKEN normalization (padding rows stay zero): MaxSim's
-            # token dot is then the token cosine, matching the dense lane
-            norms = np.linalg.norm(c.vecs, axis=2, keepdims=True)
-            normed = c.vecs / np.maximum(norms, 1e-12)
             mvector[name] = DeviceMultiVectorField(
-                vecs=np.ascontiguousarray(normed.astype(np.float32)),  # lazy
+                vecs=_host_knn_column(seg, name, "f32")[0]["vecs"],  # lazy
                 lens=put(c.lens), exists=put(c.exists), column=c)
         geo = {name: DeviceGeoField(lat=put(c.lat.astype(np.float32)),
                                     lon=put(c.lon.astype(np.float32)),

@@ -511,7 +511,8 @@ class Segment:
                          df: np.ndarray, num_docs: int,
                          total_tokens: int | None = None,
                          ids: list[str] | None = None,
-                         sources: list[dict] | None = None) -> "Segment":
+                         sources: list[dict] | None = None,
+                         vectors: dict | None = None) -> "Segment":
         """Construct an immutable single-text-field segment directly from
         pre-tokenized packed columns — the high-throughput bulk-load path,
         the analog of Lucene's ``IndexWriter.addIndexes(CodecReader...)``
@@ -524,6 +525,8 @@ class Segment:
         holes — or ``None`` to skip position indexing entirely (the
         reference's ``index_options: freqs``: ~40% less memory, positional
         queries rejected); rows at and beyond ``num_docs`` are padding.
+        ``vectors``: dense_vector columns beside the text, field →
+        ``(vecs [Np, D], exists [Np])`` (:meth:`packed_vector_column`).
         """
         np_docs = int(uterms.shape[0])
         has_positions = tokens is not None
@@ -544,6 +547,53 @@ class Segment:
             doc_len=np.ascontiguousarray(doc_len, dtype=np.int32),
             df=np.ascontiguousarray(df, dtype=np.int32),
             total_tokens=total_tokens, has_positions=has_positions)
+        return Segment._from_packed(
+            seg_id, num_docs, np_docs, ids, sources,
+            text_fields={field: col},
+            vector_fields={
+                name: Segment.packed_vector_column(v, ex, np_docs)
+                for name, (v, ex) in (vectors or {}).items()})
+
+    @staticmethod
+    def from_packed_vectors(seg_id: int, field: str, vecs: np.ndarray,
+                            exists: np.ndarray, num_docs: int,
+                            ids: list[str] | None = None,
+                            sources: list[dict] | None = None
+                            ) -> "Segment":
+        """Construct an immutable single-dense_vector-field segment from a
+        packed ``[Np, D]`` float32 column — the bulk-load path of a vector
+        index (3 GB a segment at 2^20 × 768 cannot go through ``_bulk``
+        JSON). ``vecs`` is taken AS IT IS, not copied (float32,
+        C-contiguous) and not normalized: the knn lane norms a column once
+        (jit_exec._host_knn_column) and keeps rows that are unit length
+        already in place. Rows at and beyond ``num_docs`` are padding and
+        must not exist. Search results equal those of the same documents
+        indexed through ``_bulk`` (tests/test_dense_knn_config.py)."""
+        np_docs = int(vecs.shape[0])
+        if num_docs > np_docs:
+            raise ValueError(f"num_docs {num_docs} > padded rows {np_docs}")
+        if np.asarray(exists[num_docs:]).any():
+            raise ValueError("a padding row is marked as existing")
+        return Segment._from_packed(
+            seg_id, num_docs, np_docs, ids, sources, text_fields={},
+            vector_fields={field: Segment.packed_vector_column(
+                vecs, exists, np_docs)})
+
+    @staticmethod
+    def packed_vector_column(vecs: np.ndarray, exists: np.ndarray,
+                             np_docs: int) -> VectorFieldColumn:
+        """THE way a packed dense_vector column enters a segment."""
+        if vecs.ndim != 2 or vecs.shape[0] != np_docs \
+                or exists.shape != (np_docs,):
+            raise ValueError("packed columns disagree on row count")
+        return VectorFieldColumn(
+            vecs=np.ascontiguousarray(vecs, dtype=np.float32),
+            exists=np.ascontiguousarray(exists, dtype=bool),
+            dims=int(vecs.shape[1]))
+
+    @staticmethod
+    def _from_packed(seg_id: int, num_docs: int, np_docs: int, ids, sources,
+                     *, text_fields: dict, vector_fields: dict) -> "Segment":
         if ids is None:
             ids = [str(i) for i in range(num_docs)] + \
                 [""] * (np_docs - num_docs)
@@ -551,9 +601,9 @@ class Segment:
         if sources is None:
             sources = [{}] * np_docs       # shared empty dict: read-only
         return Segment(seg_id=seg_id, num_docs=num_docs, padded_docs=np_docs,
-                       ids=ids, sources=sources, text_fields={field: col},
+                       ids=ids, sources=sources, text_fields=text_fields,
                        keyword_fields={}, numeric_fields={},
-                       vector_fields={}, geo_fields={},
+                       vector_fields=vector_fields, geo_fields={},
                        source_complete=source_complete)
 
     # ---- persistence ------------------------------------------------------

@@ -20,7 +20,7 @@ a query opens eight;
 ``rest/server.py``) or by the first span of an in-process caller. Both
 ride pool submits on the seam that carries the trace context
 (:func:`bind_context` ← ``tasks.bind_current``). The ring holds
-:data:`RING_CAP` records of 64 bytes (:data:`RING_BYTES` = 8 MiB,
+:data:`RING_CAP` records of 64 bytes (:data:`RING_BYTES` = 64 MiB,
 allocated once), counts what it overwrote, and answers a reader that
 asks for an interval it no longer holds with ``None``, never a short
 list. Spans sit at LAYER BOUNDARIES only — never inside a per-hit or
@@ -526,8 +526,13 @@ def device_span(site: str, cost: tuple | None = None):
 # the ring
 # ---------------------------------------------------------------------------
 
-#: records the ring holds; 64 bytes each, allocated once
-RING_CAP = 1 << 17
+#: records the ring holds; 64 bytes each, allocated once. Sized for a
+#: reader that asks about a slice of a minute ago: single searches
+#: through the scheduler leave 15 records a request, so 200 requests/s
+#: (the knn cell, PERF.md section 5) overwrote 2^17 records in 44 s and
+#: a benchmark window's span metrics fell silent; 2^20 keeps a 51 s
+#: window whole up to about 1,300 requests/s
+RING_CAP = 1 << 20
 _REC = struct.Struct("<8q")
 RING_BYTES = RING_CAP * _REC.size
 

@@ -11,7 +11,9 @@ one XLA program over ICI.
 Tie-breaking matches Lucene exactly because ``lax.top_k`` is stable (equal
 values → lower index first): within a segment, index order == doc id order;
 across shards, concatenating in shard order before re-top-k reproduces
-TopDocs.merge's (shard index, position) tie-break.
+TopDocs.merge's (shard index, position) tie-break. (Measured otherwise on
+the TPU v5e at k = 1000: PERF.md section 7, fault 1 — the benchmark counts
+such pairs as ``ties_not_by_id``, for the BM25 and the knn cells alike.)
 """
 
 from __future__ import annotations

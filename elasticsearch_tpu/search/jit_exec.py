@@ -2237,6 +2237,15 @@ def note_knn_served(index_name: str | None, n_requests: int,
             bucket["maxsim_dispatches"] += maxsim
 
 
+def note_knn_rows(real: int, padded: int) -> None:
+    """One knn-lane dispatch of ``real`` request rows and ``padded``
+    no-op rows (the power-of-two batch bucket's price: a padded row is
+    scored and selected like a real one)."""
+    with _cache_lock:
+        _bump("knn_rows_real", int(real))
+        _bump("knn_rows_padded", int(padded))
+
+
 def knn_index_stats(index_name: str) -> dict:
     """One index's knn-lane rollup (zeros when never admitted)."""
     with _cache_lock:
@@ -2253,14 +2262,54 @@ def note_data_blocks_vector(uploaded: int, reused: int) -> None:
         _data_layer["vector_bytes_reused"] += int(reused)
 
 
+#: a float32 row whose squared length lies this close to 1 is unit
+#: length to float32 rounding: dividing it by its own norm again moves
+#: components by an ulp and the cosine by less than 5e-7, so it is kept
+#: as it is (and a column of such rows is never copied)
+_UNIT_TOL = 1e-6
+
+
+def _unit_rows(vecs, exists):
+    """``vecs`` with every row that exists L2-normalized, float32,
+    C-contiguous — ``vecs`` ITSELF when it already is all of that (the
+    bulk-installed column of a packed segment: 3 GB a segment that no
+    second host copy has to hold). Rows are normed in blocks so the
+    float64 temporaries stay small. The last axis is the vector's."""
+    import numpy as _np
+    v = _np.ascontiguousarray(vecs, dtype=_np.float32)
+    flat = v.reshape(-1, v.shape[-1])
+    keep = _np.broadcast_to(
+        _np.asarray(exists, bool).reshape((-1,) + (1,) * (v.ndim - 2)),
+        v.shape[:-1]).reshape(-1)
+    out = None
+    step = 1 << 16
+    for lo in range(0, flat.shape[0], step):
+        blk = flat[lo:lo + step]
+        n2 = _np.einsum("ij,ij->i", blk, blk, dtype=_np.float64)
+        fix = keep[lo:lo + step] & (n2 > 0.0) \
+            & (_np.abs(n2 - 1.0) > _UNIT_TOL)
+        if not fix.any():
+            continue
+        if out is None:
+            out = flat.copy() if v is vecs or _np.shares_memory(v, vecs) \
+                else flat
+        rows = _np.flatnonzero(fix) + lo
+        out[rows] = (flat[rows] / _np.sqrt(n2[fix])[:, None]).astype(
+            _np.float32)
+    return v if out is None else out.reshape(v.shape)
+
+
 def _host_knn_column(host_seg, field: str, quant: str):
     """The host-side knn column for one segment — L2-normalized f32, or
     its int8 quantization — cached ON the immutable host Segment (the
     impact-column discipline: survives reader swaps, so unchanged
     segments never renormalize/requantize). Returns
     (arrays dict, multi: bool, dims) or None when the segment lacks the
-    field. Shared by the compiled pack builder and the eager fallback
-    lane so both lanes score the same bits."""
+    field. ONE normalization and at most one host copy per column: the
+    compiled pack builder, the eager fallback lane and the device
+    reader's lazy ``vecs`` (DeviceReader._pack_segment) all read this
+    entry, the int8 form quantizes the f32 entry's rows, and a column
+    whose rows are already unit length is used in place."""
     import numpy as _np
     from elasticsearch_tpu.index.segment import quantize_vectors
     col = host_seg.vector_fields.get(field)
@@ -2273,26 +2322,18 @@ def _host_knn_column(host_seg, field: str, quant: str):
     hit = cache.get(ckey)
     if hit is not None:
         return hit
-    if multi:
-        norms = _np.linalg.norm(mcol.vecs, axis=2, keepdims=True)
-        normed = (mcol.vecs / _np.maximum(norms, 1e-12)).astype(
-            _np.float32)
-        out = {"lens": _np.asarray(mcol.lens, _np.int32),
-               "exists": _np.asarray(mcol.exists, bool)}
-        dims = mcol.dims
-    else:
-        norms = _np.linalg.norm(col.vecs, axis=1, keepdims=True)
-        normed = (col.vecs / _np.maximum(norms, 1e-12)).astype(
-            _np.float32)
-        out = {"lens": None, "exists": _np.asarray(col.exists, bool)}
-        dims = col.dims
     if quant == "int8":
-        qcol = quantize_vectors(normed, dims)
-        out.update(vecs=qcol.qvecs, qcol=qcol,
-                   scale=qcol.scale, offset=qcol.offset)
+        base, _, dims = _host_knn_column(host_seg, field, "f32")
+        qcol = quantize_vectors(base["vecs"], dims)
+        out = {**base, "vecs": qcol.qvecs, "qcol": qcol,
+               "scale": qcol.scale, "offset": qcol.offset}
     else:
-        out.update(vecs=_np.ascontiguousarray(normed), qcol=None,
-                   scale=1.0, offset=0.0)
+        src = mcol if multi else col
+        exists = _np.asarray(src.exists, bool)
+        out = {"lens": _np.asarray(mcol.lens, _np.int32) if multi else None,
+               "exists": exists, "vecs": _unit_rows(src.vecs, exists),
+               "qcol": None, "scale": 1.0, "offset": 0.0}
+        dims = src.dims
     entry = (out, multi, dims)
     cache[ckey] = entry
     return entry
@@ -2357,34 +2398,37 @@ def vector_pack_for(reader, field: str,
     pack = _VectorPack(field, cfg.quantization)
     uploaded = reused = 0
     any_field = False
-    for dseg in reader.segments:
-        entry = _host_knn_column(dseg.seg, field, cfg.quantization)
-        if entry is None:
-            pack.segs.append(None)
-            continue
-        host, multi, dims = entry
-        any_field = True
-        pack.multi = multi
-        pack.dims = dims
-        arrs, up, re = fetch_vector_block(
-            engine_uuid, dseg.seg.block_uid, field,
-            (cfg.quantization, multi), lambda h=host: [
-                h["vecs"], h["exists"].astype(np.bool_),
-                h["lens"]], breaker_service)
-        uploaded += up
-        reused += re
-        dev_vecs, dev_exists = arrs[0], arrs[1]
-        dev_lens = arrs[2] if multi else None
-        pack.segs.append({
-            "vecs": dev_vecs, "exists": dev_exists, "lens": dev_lens,
-            "live": dseg.live, "qcol": host["qcol"],
-            "scale": float(host["scale"]),
-            "offset": float(host["offset"]),
-            "np_docs": int(dseg.padded_docs),
-            "t": int(host["vecs"].shape[1]) if multi else 0,
-            "doc_base": int(dseg.doc_base),
-            "block_uid": int(dseg.seg.block_uid),
-        })
+    # the whole build — host norms, the block cache's uploads — is one
+    # ``jit.upload`` stretch of the span ring (each segment's transfer
+    # is a ``jit.vector-upload`` child inside fetch_vector_block)
+    with device_span("upload"):
+        for dseg in reader.segments:
+            entry = _host_knn_column(dseg.seg, field, cfg.quantization)
+            if entry is None:
+                pack.segs.append(None)
+                continue
+            host, multi, dims = entry
+            any_field = True
+            pack.multi = multi
+            pack.dims = dims
+            arrs, up, re = fetch_vector_block(
+                engine_uuid, dseg.seg.block_uid, field,
+                (cfg.quantization, multi), lambda h=host: [
+                    h["vecs"], h["exists"], h["lens"]], breaker_service)
+            uploaded += up
+            reused += re
+            dev_vecs, dev_exists = arrs[0], arrs[1]
+            dev_lens = arrs[2] if multi else None
+            pack.segs.append({
+                "vecs": dev_vecs, "exists": dev_exists, "lens": dev_lens,
+                "live": dseg.live, "qcol": host["qcol"],
+                "scale": float(host["scale"]),
+                "offset": float(host["offset"]),
+                "np_docs": int(dseg.padded_docs),
+                "t": int(host["vecs"].shape[1]) if multi else 0,
+                "doc_base": int(dseg.doc_base),
+                "block_uid": int(dseg.seg.block_uid),
+            })
     if not any_field:
         return None
     note_data_blocks_vector(uploaded, reused)
@@ -2580,7 +2624,9 @@ def run_knn_hybrid_batch(reader, ctx, reqs, pack: _VectorPack,
             if plan is None:
                 return None
             plans.append(plan)
-    qv, qmask, boosts, b_pad = _knn_query_inputs(reqs, pack)
+    with span("jit.pack"):
+        # host work: B query vectors parsed, normed and stacked
+        qv, qmask, boosts, b_pad = _knn_query_inputs(reqs, pack)
     if need_seg:
         # const rows pad to the SAME bucket as the query vectors
         for plan in plans:
@@ -2657,38 +2703,43 @@ def run_knn_hybrid_batch(reader, ctx, reqs, pack: _VectorPack,
                     vecs, exists, live, lens = arrs
                 else:
                     vecs, exists, live = arrs
-                if pack.multi and pack.quant == "int8":
-                    scores = maxsim_ops.maxsim_scores_int8_batch_body(
-                        vecs, scales_in[vi], offsets_in[vi], lens,
-                        qv_in, qmask_in)
-                elif pack.multi:
-                    scores = maxsim_ops.maxsim_scores_batch_body(
-                        vecs, lens, qv_in, qmask_in)
-                elif pack.quant == "int8":
-                    scores = vector_ops.cosine_scores_int8_batch(
-                        vecs, scales_in[vi], offsets_in[vi], exists,
-                        qv_in)
-                else:
-                    scores = jnp.where(exists[None, :],
-                                       qv_in @ vecs.T, 0.0)
-                if not hybrid:
-                    # knn-only: the section boost scales the reported
-                    # scores (rank-preserving — boost > 0 validated)
-                    scores = scores * boosts_in[:, None]
-                elig = exists & live
-                masks = jnp.broadcast_to(elig[None, :],
-                                         (b_pad, elig.shape[0]))
-                if fmasks[i] is not None:
-                    masks = masks & fmasks[i]
-                ts, td = vector_ops.filtered_topk_batch(
-                    scores, masks, min(c_static, elig.shape[0]), 0)
+                with jax.named_scope("knn_score"):
+                    if pack.multi and pack.quant == "int8":
+                        scores = maxsim_ops.maxsim_scores_int8_batch_body(
+                            vecs, scales_in[vi], offsets_in[vi], lens,
+                            qv_in, qmask_in)
+                    elif pack.multi:
+                        scores = maxsim_ops.maxsim_scores_batch_body(
+                            vecs, lens, qv_in, qmask_in)
+                    elif pack.quant == "int8":
+                        scores = vector_ops.cosine_scores_int8_batch(
+                            vecs, scales_in[vi], offsets_in[vi], exists,
+                            qv_in)
+                    else:
+                        # float32 at Precision.HIGHEST (ops/vector.py)
+                        scores = vector_ops.unit_scores_batch(
+                            vecs, exists, qv_in)
+                    if not hybrid:
+                        # knn-only: the section boost scales the
+                        # reported scores (rank-preserving — boost > 0
+                        # validated)
+                        scores = scores * boosts_in[:, None]
+                with jax.named_scope("knn_select"):
+                    elig = exists & live
+                    masks = jnp.broadcast_to(elig[None, :],
+                                             (b_pad, elig.shape[0]))
+                    if fmasks[i] is not None:
+                        masks = masks & fmasks[i]
+                    ts, td = vector_ops.filtered_topk_batch(
+                        scores, masks, min(c_static, elig.shape[0]), 0)
+                    knn_counts = knn_counts + masks.sum(axis=1,
+                                                        dtype=jnp.int32)
                 knn_ts.append(ts)
                 knn_td.append(td)
-                knn_counts = knn_counts + masks.sum(axis=1,
-                                                    dtype=jnp.int32)
                 vi += 1
-            ds, dd = topk_ops.merge_top_k_batch_body(
-                knn_ts, knn_td, c_static, vec_bases)
+            with jax.named_scope("knn_merge"):
+                ds, dd = topk_ops.merge_top_k_batch_body(
+                    knn_ts, knn_td, c_static, vec_bases)
             if not hybrid:
                 ts, td = ds[:, :k_static], dd[:, :k_static]
                 return {"top_scores": ts, "top_docs": td,
@@ -2720,7 +2771,9 @@ def run_knn_hybrid_batch(reader, ctx, reqs, pack: _VectorPack,
     args = (flats, packeds, vec_arrs, pack.scales, pack.offsets,
             qv, qmask if qmask is not None else jnp.zeros(0, bool),
             boosts)
-    cost = ("knn", key, n_real if n_real is not None else b, b_pad)
+    rows_real = n_real if n_real is not None else b
+    note_knn_rows(rows_real, b_pad - rows_real)
+    cost = ("knn", key, rows_real, b_pad)
     if hybrid:
         with device_span("fusion-dispatch", cost=cost):
             device_fault_point("fusion-dispatch")
@@ -3078,7 +3131,8 @@ def run_knn_hybrid_mesh(reader, ctx, reqs, pack: _VectorPack,
             if plan is None:
                 return None
             plans.append(plan)
-    qv, qmask, boosts, b_pad = _knn_query_inputs(reqs, pack)
+    with span("jit.pack"):
+        qv, qmask, boosts, b_pad = _knn_query_inputs(reqs, pack)
     if need_seg:
         for plan in plans:
             if plan["b_pad"] is not None and plan["b_pad"] != b_pad:
@@ -3186,8 +3240,8 @@ def run_knn_hybrid_mesh(reader, ctx, reqs, pack: _VectorPack,
                         vecs, scales_in[vi], offsets_in[vi], exists,
                         qv_in)
                 else:
-                    scores = jnp.where(exists[None, :],
-                                       qv_in @ vecs.T, 0.0)
+                    scores = vector_ops.unit_scores_batch(
+                        vecs, exists, qv_in)
                 if not hybrid:
                     scores = scores * boosts_in[:, None]
                 elig = exists & live
@@ -3268,9 +3322,10 @@ def run_knn_hybrid_mesh(reader, ctx, reqs, pack: _VectorPack,
     dummy = jnp.zeros(0, bool) if qmask is None else qmask
     args = (flats, packeds, tuple(placed), scales, offsets, qv, dummy,
             boosts)
+    rows_real = n_real if n_real is not None else b
+    note_knn_rows(rows_real, b_pad - rows_real)
     with device_span("knn-mesh-merge",
-                     cost=("knn-mesh", key,
-                           n_real if n_real is not None else b, b_pad)):
+                     cost=("knn-mesh", key, rows_real, b_pad)):
         device_fault_point("knn-mesh-merge")
         out = fn(*args)
     if b_pad != b:

@@ -56,6 +56,10 @@ JIT_COUNTERS = {
     "impact_requant_refreshes": "impact requantizations forced by "
                                 "cross-segment df drift",
     "knn_admissions": "requests served by the compiled knn lane",
+    "knn_rows_real": "request rows the knn lane's dispatches carried",
+    "knn_rows_padded": "no-op rows that padded knn dispatches to their "
+                       "power-of-two batch bucket (scored, never "
+                       "delivered)",
     "fusion_dispatches": "in-program hybrid fusion dispatches",
     "maxsim_dispatches": "fused MaxSim dispatches over rank_vectors",
     "rescore_fused_dispatches": "impact→rescore plans composed into one "

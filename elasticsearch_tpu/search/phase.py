@@ -962,9 +962,13 @@ class ShardSearcher:
         from elasticsearch_tpu.search import jit_exec
         self._validate_knn(req.knn)
         if jit_exec.plane_breaker.allow():
-            handle = self._knn_batch_launch([req])
+            # the launch stays open in the in-flight book until the end
+            # of its drain, as the planner's launches do
+            with obs_trace.launch_scope() as launches:
+                handle = self._knn_batch_launch([req])
             if handle is not None:
-                return self.query_phase_batch_drain(handle)[0]
+                return self._drain_arm(handle, launches)[0]
+            obs_trace.close_launches(launches, drained=False)
         else:
             jit_exec.note_breaker_skip()
             jit_exec.note_knn_fallback("breaker-open")

@@ -302,6 +302,82 @@ def test_knn_program_768(topo, no_persistent_cache, lanes):
     fits_hbm(compiled, "knn 768 dims")
 
 
+KNN_CELL_SEGMENTS = 3     # dense768-knn.search-k10-c16: 3 x [2^20, 768]
+
+
+@pytest.fixture(scope="module")
+def knn_cell(tmp_path_factory):
+    """The benchmark configuration ``dense768-cosine-knn`` at its own
+    shapes: three packed [2^20, 768] float32 segments. The columns are
+    untouched ``np.zeros`` (pages the kernel never backs: nothing writes
+    them)."""
+    ms = MapperService()
+    ms.merge("_doc", {"properties": {
+        "emb": {"type": "dense_vector", "dims": VEC_DIMS}}})
+    eng = Engine(tmp_path_factory.mktemp("aot_knn_cell"), ms)
+    for _ in range(KNN_CELL_SEGMENTS):
+        eng.install_segment(Segment.from_packed_vectors(
+            0, "emb", np.zeros((ROWS, VEC_DIMS), np.float32),
+            np.ones(ROWS, bool), ROWS, ids=[""] * ROWS),
+            track_versions=False)
+    yield eng, ms
+    eng.close()
+    jit_exec.clear_cache()
+
+
+@contextlib.contextmanager
+def uploads_as_shapes():
+    """Nothing is copied to a device: inside, the vector block cache's
+    upload hands back shapes, which is all a lowering reads (9.66 GB of
+    zeros stay unbacked pages)."""
+    from elasticsearch_tpu.parallel import mesh_engine
+    proxy = types.SimpleNamespace(
+        **{n: getattr(jax, n) for n in dir(jax) if not n.startswith("__")})
+    proxy.device_put = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    real = mesh_engine.jax
+    mesh_engine.jax = proxy
+    try:
+        yield
+    finally:
+        mesh_engine.jax = real
+
+
+@pytest.mark.parametrize("b_pad", [1, 2, 4, 8, 16])
+def test_knn_program_at_the_benchmark_cells_shapes(
+        topo, no_persistent_cache, knn_cell, b_pad):
+    """The knn program of ``dense768-knn.search-k10-c16`` — 3 x [2^20,
+    768] float32, k 10, num_candidates 100 — at every batch bucket its 16
+    clients can fill: it compiles for the chip, two of them fit HBM side
+    by side with the resident vectors counted once, and its product is
+    the six-pass one (nothing under ``knn_score`` touches bfloat16)."""
+    from jax.sharding import SingleDeviceSharding
+    eng, ms = knn_cell
+    chip = SingleDeviceSharding(topo.devices[0])
+    searcher = ShardSearcher(0, device_reader_for(eng), ms)
+    reqs = [parse_search_request({
+        "knn": {"field": "emb", "k": 10, "num_candidates": 100,
+                "query_vector": [float(i + 1)] * VEC_DIMS},
+        "size": 10}) for i in range(b_pad)]
+    with steered_to(lambda a: chip), uploads_as_shapes():
+        compiled = captured(searcher.query_phase_batch, reqs)
+    ma = fits_hbm(compiled, f"knn cell 3 x [2^20, 768] B_pad={b_pad}")
+    vectors = KNN_CELL_SEGMENTS * ROWS * VEC_DIMS * 4
+    assert ma.argument_size_in_bytes >= vectors
+    assert vectors + 2 * (ma.temp_size_in_bytes
+                          + ma.output_size_in_bytes) < HBM_BYTES
+    text = compiled.as_text()
+    products = [ln for ln in text.splitlines()
+                if " convolution(" in ln and "knn_score" in ln]
+    # one MXU product a segment (at the smallest buckets the compiler may
+    # make it a float32 multiply-and-reduce on the VPU instead: exact too)
+    assert len(products) == KNN_CELL_SEGMENTS or b_pad < 4
+    assert all("operand_precision={highest,highest}" in ln
+               for ln in products)
+    assert "bf16" not in "".join(
+        ln for ln in text.splitlines() if "knn_score" in ln)
+    assert "knn_select" in text and "knn_merge" in text
+
+
 def test_impact_mesh_program_1x4(topo, no_persistent_cache, big):
     """One mesh program on four described chips, geometry (1,4): the
     per-shard sweeps must merge through cross-chip collectives."""
@@ -330,3 +406,4 @@ def test_impact_mesh_program_1x4(topo, no_persistent_cache, big):
     text = compiled.as_text()
     assert "all-gather" in text, "no cross-chip candidate merge"
     assert "all-reduce" in text, "no cross-chip count/theta reduction"
+

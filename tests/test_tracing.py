@@ -530,7 +530,7 @@ def test_ring_overflow_is_counted_and_a_lost_interval_reads_nothing():
     finally:
         tracing.reset()
     assert tracing.ring_stats()["capacity"] == tracing.RING_CAP
-    assert tracing.RING_BYTES == 8 * 1024 * 1024
+    assert tracing.RING_BYTES == 64 * 1024 * 1024
 
 
 def test_book_starved_plus_in_flight_is_the_interval(fresh_ring):
