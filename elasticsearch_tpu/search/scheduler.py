@@ -4,11 +4,9 @@ device feeder (ROADMAP item 6, the LLM-serving playbook).
 The admission model before this module was drain-then-refill: a formed
 micro-batch dispatched, every waiter blocked for its drain, and only
 then did the next batch start forming — between dispatches the device
-idled for a full host round trip (BENCH_r04: 16 closed-loop clients at
-152 QPS against a 485 QPS batch ceiling, p50 owned by the 68 ms RTT
-floor). Iteration-level scheduling (Orca/vLLM) inverts it: the device
-never waits for a batch to *form* — it is fed whatever accumulated
-while it was busy.
+idled for a full host round trip. Iteration-level scheduling
+(Orca/vLLM) inverts it: the device never waits for a batch to *form* —
+it is fed what accumulated while it was busy.
 
 Mechanics, per node:
 
@@ -16,14 +14,28 @@ Mechanics, per node:
   / ``knn`` / ``percolate``, keyed by the same pow2 buckets the program
   caches use, so every formed batch is admissible to ONE compiled
   program by construction;
-* one dispatcher thread keeps a dispatch always in flight: while batch
-  N computes on-device, batch N+1 is host-packed and launched
-  (``query_phase_batch_launch`` is async — JAX dispatch returns before
-  the device finishes), and batch N−1's device→host drain rides a
-  worker thread. Admission is continuous — a batch is whatever queued
-  while the in-flight window was full, so an idle device serves a lone
-  request instantly (no formation deadline) and a saturated one forms
-  large batches for free;
+* one dispatcher thread forms the batches and a worker thread per batch
+  launches and drains it: while batch N computes on-device, batch N+1
+  is host-packed and launched (``query_phase_batch_launch`` is async —
+  JAX dispatch returns before the device finishes). An idle device
+  serves a lone request at once (no formation deadline). A busy one is
+  fed just deep enough: the device runs programs one after another, so
+  a batch launched behind others only stands in line there, closed to
+  the rows that arrive meanwhile. Launching whenever one of
+  ``max_in_flight`` (4) places was free made 16 closed-loop kNN clients
+  cycle in five groups that never mixed — 16 / 5 = 3.2 rows a batch
+  (3.17 measured), a 9.66 GB column read once per 3 queries, 236.7
+  queries/s on a chip 99% busy (ledger, PR 28). So a batch that is not
+  full is HELD while the staged depth of batches is launched and
+  undrained (``_await_batch_locked``): one running plus as many staged
+  as a launch takes in device times, both measured here
+  (``_staged_depth_locked``; two where a launch is quicker than the
+  program before it). On that cell a launch takes 14-19 ms of a
+  contended interpreter against 13.4 ms on the device, the depth
+  settles at three, the clients cycle in four groups and the same chip
+  answers 277-284 queries/s at 3.8-3.9 rows a batch (chip runs of
+  PR 29, ``PERF.md`` section 5; the ledger's PR 29 lines have the
+  driver's);
 * pickup across queues is weighted-fair (WRR over lanes, FIFO within a
   lane, oldest-head queue first): a low-rate percolate client is never
   starved by a query storm;
@@ -43,11 +55,14 @@ uses (fuzz-pinned in tests/test_scheduler.py). Counters live in the
 lane registry (``lanes.JIT_COUNTERS`` ``scheduler_*`` keys, bumped via
 ``jit_exec.note_scheduler_*``) and shed reasons in
 ``lanes.LANE_REASONS["scheduler"]`` — the PR 12 counter-discipline and
-fallback-taxonomy rules police the scheduler by construction.
+fallback-taxonomy rules police the scheduler by construction. The
+hold's own counters are in :meth:`ContinuousBatchScheduler.stats`
+(``_nodes/stats.scheduler``): ``batches_held``, ``hold_ms``, ``pace``.
 """
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 from collections import deque
@@ -84,6 +99,15 @@ DEFAULT_WEIGHTS = {"plane": 4, "impact": 3, "knn": 2, "percolate": 1}
 #: shed order under SLO burn: LOWEST priority sheds first (level 1
 #: sheds priority ≤ 1, level 2 ≤ 2, level 3 everything)
 DEFAULT_PRIORITIES = {"plane": 3, "impact": 2, "knn": 2, "percolate": 1}
+
+#: launched-and-undrained batches that keep the device fed where a
+#: launch is quicker than the program it is staged behind: one runs, one
+#: is staged. From the depth on (``_staged_depth_locked`` deepens it by
+#: what it measures) a batch leaves the queue only full.
+STAGED_DEPTH = 2
+
+#: weight of a new sample in a lane's launch / device time means
+PACE_ALPHA = 0.125
 
 #: minimum queue_wait samples in a shed window before the burn signal
 #: is trusted (a single slow wakeup must not open the shed gate)
@@ -235,8 +259,9 @@ EXECUTE_BACKSTOP_S = 600.0
 class _BatchState:
     """Per-launched-batch abandon/finish state: the scheduler lock
     arbitrates the race between the drain worker finishing and the
-    watchdog monitor abandoning, so the in-flight permit releases
-    exactly once and a late (post-abandon) completion is discarded."""
+    watchdog monitor abandoning, so the batch leaves the in-flight
+    window exactly once and a late (post-abandon) completion is
+    discarded."""
 
     __slots__ = ("live", "finished", "abandoned")
 
@@ -293,7 +318,6 @@ class ContinuousBatchScheduler:
         for lane in sorted(self.weights):
             self._wrr.extend([lane] * max(int(self.weights[lane]), 1))
         self._wrr_pos = 0
-        self._inflight_sem = threading.BoundedSemaphore(self.max_in_flight)
         self._dispatcher: threading.Thread | None = None
         self._closed = False
         # counters (all under _lock; stats() snapshots one consistent
@@ -312,6 +336,13 @@ class ContinuousBatchScheduler:
         self._batches_abandoned = 0
         self._inflight_hw = 0
         self._pad_rows = 0
+        self._batches_held = 0
+        self._hold_ms = 0.0
+        # lane → [mean launch ms, its mean deviation, mean device ms]
+        # of its pipelined batches, and the instant the device last
+        # finished one
+        self._pace: dict = {}
+        self._device_free_t = 0.0
         # SLO-burn shed gate: the scheduler's OWN queue-wait good/bad
         # book (classified against the node's queue_wait SLO target) —
         # the shared queue_wait lane also carries threadpool samples,
@@ -448,58 +479,45 @@ class ContinuousBatchScheduler:
     def _dispatch_inner(self) -> None:
         while True:
             with self._cond:
-                while not self._closed and self._queued == 0:
-                    self._cond.wait(0.25)
-                if self._closed:
-                    return
-            # bound launched-but-undrained work BEFORE forming the
-            # batch: while the in-flight window is full, arrivals keep
-            # queueing — the next batch forms larger for free (the
-            # continuous-batching win)
-            self._inflight_sem.acquire()
-            if self._closed:
-                # closed while this thread waited for the window: form
-                # no batch; the flush declines what is queued
-                self._inflight_sem.release()
-                return
-            try:
-                with self._lock:
-                    q, batch = self._next_batch_locked()
-                if q is None:
-                    self._inflight_sem.release()
-                    continue
-                live = self._screen_pickup(batch)
-                if not live:
-                    self._inflight_sem.release()
-                    continue
+                q, batch = self._await_batch_locked()
+            if q is None:
+                return                  # closed: the flush declines the rest
+            live = self._screen_pickup(batch)
+            if live:
                 self._launch_batch(q, live)
-            except Exception:            # noqa: BLE001 — dispatcher must
-                self._inflight_sem.release()   # survive any batch error
-                raise
 
-    def _next_batch_locked(self):
-        """Weighted-fair pickup: cycle lanes by WRR weight, serve the
-        chosen lane's oldest-head queue FIFO, up to max_batch. Empty
-        queues are dropped (shape keys churn with reader generations)."""
-        nonempty: dict = {}
-        for key in list(self._queues):
-            q = self._queues[key]
-            if not q.waiters:
-                del self._queues[key]
-                continue
-            nonempty.setdefault(q.lane, []).append(q)
-        if not nonempty:
+    def _await_batch_locked(self):
+        """Wait until a batch may leave the queue, then form it from
+        everything queued by then → ``(queue, batch)``, or ``(None,
+        None)`` once closed. One wait serves the three reasons to stay:
+        nothing is queued, ``max_in_flight`` batches are launched and
+        undrained, or the hold: the picked queue is NOT full while the
+        staged depth (:meth:`_staged_depth_locked`) of batches is
+        undrained. ``_batches_inflight`` counts every lane's batches
+        because the device is one and serial across lanes — one of them
+        runs, the others are staged behind it or on their way there, so
+        the device has its next program and a further launch would only
+        stand in line, closed to the rows that arrive meanwhile. Held
+        until a batch finishes or is abandoned, the rows start on the
+        device at the same instant and take their later neighbours
+        along. ``submit``, ``_finish_batch``, ``_abandon_batch`` and
+        ``close`` notify; ``_closed`` is re-checked after every wait."""
+        held_at = None
+        while not self._closed:
+            q, wrr_pos = self._pick_queue_locked()
+            if q is not None and self._batches_inflight < self.max_in_flight:
+                if self._batches_inflight < self._staged_depth_locked(
+                        q.lane) or len(q.waiters) >= self.max_batch:
+                    break
+                if held_at is None:
+                    held_at = time.perf_counter()
+                    self._batches_held += 1
+            self._cond.wait(0.25)
+        if held_at is not None:
+            self._hold_ms += (time.perf_counter() - held_at) * 1e3
+        if self._closed:
             return None, None
-        chosen = None
-        for step in range(len(self._wrr)):
-            lane = self._wrr[(self._wrr_pos + step) % len(self._wrr)]
-            if lane in nonempty:
-                self._wrr_pos = (self._wrr_pos + step + 1) % len(self._wrr)
-                chosen = nonempty[lane]
-                break
-        if chosen is None:                # lanes outside the WRR table
-            chosen = next(iter(nonempty.values()))
-        q = min(chosen, key=lambda c: c.waiters[0].enq_t)
+        self._wrr_pos = wrr_pos
         batch = []
         while q.waiters and len(batch) < self.max_batch:
             batch.append(q.waiters.popleft())
@@ -508,6 +526,71 @@ class ContinuousBatchScheduler:
         if not q.waiters:
             self._queues.pop(q.key, None)
         return q, batch
+
+    def _staged_depth_locked(self, lane: str) -> int:
+        """Undrained batches from which on a non-full batch is held. A
+        batch formed when one drains has to be ON the device's queue
+        before the batches ahead of it have run, so the depth is one
+        running plus as many staged as a launch (formation → enqueued:
+        worker start, plan, ``jit.pack``, ``jit.enqueue``) takes in
+        device times — both measured by this scheduler itself
+        (:meth:`_note_pace_locked`), never a setting. A launch counts
+        at its mean plus one mean deviation, the usual launch and not
+        the latest: one that outlasts the staged work costs the device
+        the few ms of its excess, a depth more costs EVERY batch its
+        share of the rows (a quarter from three to four). Two where a
+        launch is quicker than the program before it, or nothing is
+        measured yet; ``max_in_flight`` bounds it from above."""
+        pace = self._pace.get(lane)
+        if pace is None:
+            return STAGED_DEPTH
+        launch_ms, launch_dev_ms, device_ms = pace
+        return min(self.max_in_flight, max(STAGED_DEPTH, 1 + math.ceil(
+            (launch_ms + launch_dev_ms) / max(device_ms, 1e-3))))
+
+    def _note_pace_locked(self, lane: str, t_formed: float,
+                          t_enqueued: float, t_done: float) -> None:
+        """One pipelined batch's two times into its lane's means: the
+        launch, formation → enqueued (and its mean deviation), and the
+        device's — the device is one FIFO server, so the batch ran from
+        its enqueue or from the finish of the batch before it, whichever
+        came later, to its own finish (no assumption that the device
+        was kept busy)."""
+        launch_ms = (t_enqueued - t_formed) * 1e3
+        device_ms = (t_done - max(t_enqueued, self._device_free_t)) * 1e3
+        pace = self._pace.get(lane)
+        if pace is None:
+            self._pace[lane] = [launch_ms, 0.0, device_ms]
+            return
+        pace[1] += PACE_ALPHA * (abs(launch_ms - pace[0]) - pace[1])
+        pace[0] += PACE_ALPHA * (launch_ms - pace[0])
+        pace[2] += PACE_ALPHA * (device_ms - pace[2])
+
+    def _pick_queue_locked(self):
+        """Weighted-fair pickup: cycle lanes by WRR weight, serve the
+        chosen lane's oldest-head queue FIFO → ``(queue, the cycle's next
+        position)``; the caller commits the position when it takes the
+        batch, so a held pick stays the pick. Empty queues are dropped
+        (shape keys churn with reader generations)."""
+        nonempty: dict = {}
+        for key in list(self._queues):
+            q = self._queues[key]
+            if not q.waiters:
+                del self._queues[key]
+                continue
+            nonempty.setdefault(q.lane, []).append(q)
+        if not nonempty:
+            return None, self._wrr_pos
+        chosen, wrr_pos = None, self._wrr_pos
+        for step in range(len(self._wrr)):
+            lane = self._wrr[(self._wrr_pos + step) % len(self._wrr)]
+            if lane in nonempty:
+                wrr_pos = (self._wrr_pos + step + 1) % len(self._wrr)
+                chosen = nonempty[lane]
+                break
+        if chosen is None:                # lanes outside the WRR table
+            chosen = next(iter(nonempty.values()))
+        return min(chosen, key=lambda c: c.waiters[0].enq_t), wrr_pos
 
     def _screen_pickup(self, batch: list) -> list:
         """Queue-time shedding at pickup: a cancelled task aborts (PR 2
@@ -614,7 +697,7 @@ class ContinuousBatchScheduler:
                                     self._batches_inflight)
         jit_exec.note_scheduler_batch(len(live), padded)
         self._spawn_worker(self._run_pipelined, q, live, runner, reqs,
-                           state)
+                           state, t_pick)
 
     def _spawn_worker(self, fn, *args) -> None:
         """One DAEMON worker thread per committed batch. Not a bounded
@@ -622,12 +705,12 @@ class ContinuousBatchScheduler:
         indefinitely (non-cancellable), and under repeated stalls a
         bounded pool starves — batches queue behind wedged threads and
         never even reach watchdog registration. Concurrency is still
-        bounded by ``_inflight_sem`` (abandons release the permit, so
-        live batches, not wedged threads, own the window), and daemon
-        threads never block interpreter exit on a wedge. Each worker
-        runs under this scheduler's node context so compiles, costs,
-        spans and ledger charges attribute to the owning node exactly
-        like the dispatcher thread."""
+        bounded by ``max_in_flight`` (an abandon takes its batch out of
+        ``_batches_inflight``, so live batches, not wedged threads, own
+        the window), and daemon threads never block interpreter exit on
+        a wedge. Each worker runs under this scheduler's node context so
+        compiles, costs, spans and ledger charges attribute to the
+        owning node exactly like the dispatcher thread."""
         def run() -> None:
             from elasticsearch_tpu.observability import use_node
             ctx = use_node(self.node_id) if self.node_id is not None \
@@ -657,37 +740,41 @@ class ContinuousBatchScheduler:
         self._finish_batch(state, live, results)
 
     def _run_pipelined(self, q: _LaneQueue, live: list, runner,
-                       reqs: list, state: _BatchState) -> None:
+                       reqs: list, state: _BatchState,
+                       t_formed: float) -> None:
         """Launch + drain for pipelined lanes, on a worker thread: the
         async launch overlaps the previous batch's drain exactly as
         before (the dispatcher keeps forming batches while this worker
         blocks on the device), but a wedged dispatch now wedges only
-        THIS worker — the watchdog abandons the wait and the in-flight
-        permit, and the dispatcher never stops."""
+        THIS worker — the watchdog abandons the wait and its place
+        in the window, and the dispatcher never stops."""
         from elasticsearch_tpu.search import watchdog as wd
         entry = wd.dispatch_watchdog.register(
             site="dispatch", lane=q.lane, shape_key=q.key,
             n_real=len(live),
             on_stall=lambda err: self._abandon_batch(state))
-        results = None
+        results = t_enqueued = None
         run = runner or _invoke
         try:
             handle = run("scheduler.launch", q.launch, reqs,
                          n_real=len(live))
             if handle is not None:
+                t_enqueued = time.perf_counter()
                 results = run("scheduler.drain", q.drain, handle)
         except Exception:                # noqa: BLE001 — serial retry owns it
             results = None
         wd.dispatch_watchdog.complete(entry)
-        self._finish_batch(state, live, results)
+        self._finish_batch(state, live, results,
+                           None if results is None else
+                           (q.lane, t_formed, t_enqueued))
 
     def _finish_batch(self, state: _BatchState, live: list,
-                      results) -> None:
+                      results, paced=None) -> None:
         """Worker-side batch completion: exactly one of finish/abandon
         wins under the lock. A late completion of an abandoned batch
         discards its results — the waiters already failed over and the
-        abandon path already released the permit and settled the
-        books."""
+        abandon path already gave up its place in the window and
+        settled the books."""
         from elasticsearch_tpu.search import jit_exec
         with self._lock:
             if state.abandoned:
@@ -695,7 +782,11 @@ class ContinuousBatchScheduler:
             state.finished = True
             self._batches_inflight -= 1
             self._batches_drained += 1
-        self._inflight_sem.release()
+            now = time.perf_counter()
+            if paced is not None:
+                self._note_pace_locked(*paced, now)
+            self._device_free_t = now
+            self._cond.notify()         # the window, or a hold, opens
         jit_exec.note_scheduler_drain()
         self._deliver(live, results)
 
@@ -704,8 +795,8 @@ class ContinuousBatchScheduler:
         the wedged worker still blocks on the device — only the WAIT is
         abandoned): shed every waiter back to its serial path with
         registered reason ``device-stall``, settle the batch books, and
-        release the in-flight permit so the dispatcher's window never
-        shrinks under a wedge."""
+        give up its place in the in-flight window so the dispatcher's
+        window never shrinks under a wedge (and a hold ends)."""
         from elasticsearch_tpu.search import jit_exec
         with self._lock:
             if state.finished or state.abandoned:
@@ -716,8 +807,8 @@ class ContinuousBatchScheduler:
             self._inflight_reqs -= len(state.live)
             for _ in state.live:
                 self._note_shed_locked("device-stall")
+            self._cond.notify()         # the window, or a hold, opens
         jit_exec.note_scheduler_shed("device-stall", len(state.live))
-        self._inflight_sem.release()
         for w in state.live:
             w.picked.set()
             if not w.future.done():
@@ -818,6 +909,14 @@ class ContinuousBatchScheduler:
                 "batches_abandoned": self._batches_abandoned,
                 "in_flight_high_water": self._inflight_hw,
                 "pad_rows": self._pad_rows,
+                "batches_held": self._batches_held,
+                "hold_ms": round(self._hold_ms, 3),
+                "pace": {lane: {
+                    "launch_ms": round(pace[0], 3),
+                    "launch_dev_ms": round(pace[1], 3),
+                    "device_ms": round(pace[2], 3),
+                    "staged_depth": self._staged_depth_locked(lane)}
+                    for lane, pace in self._pace.items()},
                 "queue_wait_ms": {
                     "count": self._queue_wait.count,
                     "p50": round(self._queue_wait.percentile(0.50), 4),

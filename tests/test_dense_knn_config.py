@@ -172,6 +172,7 @@ def test_served_path_matches_the_plain_reference(node, seed):
             t.join(timeout=LIMIT_S)
         assert not any(t.is_alive() for t in threads) and not errors, errors
         assert len(replies) == len(pool)
+        (served,) = _call(server, "GET", "/_nodes/stats")["nodes"].values()
     finally:
         server.stop()
     after = jit_exec.cache_stats()
@@ -201,6 +202,13 @@ def test_served_path_matches_the_plain_reference(node, seed):
     assert book1["launches"] - book0["launches"] == launched
     assert book1["launches_without_drain"] \
         == book0["launches_without_drain"]
+    # what an operator reads: the hold's two counters are served (how
+    # often the rule engages is the backend's timing, and not asserted),
+    # the books are whole and no launch was left without its drain
+    assert {"batches_held", "hold_ms"} <= set(served["scheduler"])
+    assert served["scheduler"]["reconciled"]
+    assert served["device"]["launches_without_drain"] \
+        - book0["launches_without_drain"] == 0      # the book is the process's
 
 
 def test_a_lone_knn_search_closes_its_launch_at_its_drain(node):

@@ -363,6 +363,219 @@ def test_queue_full_shed_is_typed_429():
 
 
 # ---------------------------------------------------------------------------
+# when a batch may leave the queue: the hold behind a staged batch
+# ---------------------------------------------------------------------------
+
+WAIT_S = 10.0
+
+
+class _GatedDevice:
+    """Fake ``launch`` / ``drain`` pair: every launch is recorded and
+    announced, every drain blocks until the test releases that batch
+    (``release()`` without a batch: every one, those to come too)."""
+
+    def __init__(self):
+        self.launched: list = []        # the real rows of each launch
+        self.gates: list = []
+        self._open = False
+        self._cv = threading.Condition()
+
+    def launch(self, reqs, n_real=None):
+        rows = list(reqs[:n_real])
+        with self._cv:
+            gate = threading.Event()
+            if self._open:
+                gate.set()
+            self.launched.append(rows)
+            self.gates.append(gate)
+            self._cv.notify_all()
+        return gate, rows
+
+    def drain(self, handle):
+        gate, rows = handle
+        assert gate.wait(WAIT_S)
+        return [r * 10 for r in rows]
+
+    def wait_launches(self, n: int) -> None:
+        with self._cv:
+            assert self._cv.wait_for(lambda: len(self.launched) >= n,
+                                     WAIT_S), self.launched
+
+    def release(self, *batches) -> None:
+        with self._cv:
+            self._open = self._open or not batches
+            gates = [self.gates[b] for b in batches] if batches \
+                else list(self.gates)
+        for gate in gates:
+            gate.set()
+
+
+def _reconciled(sched) -> dict:
+    st = sched.stats()
+    assert st["reconciled"], st
+    return st
+
+
+def _until(sched, key: str, value) -> dict:
+    """Poll the scheduler's own book until it reads ``value``."""
+    deadline = time.monotonic() + WAIT_S
+    while time.monotonic() < deadline:
+        st = _reconciled(sched)
+        if st[key] == value:
+            return st
+        time.sleep(0.001)
+    raise AssertionError(f"{key} never read {value}: {sched.stats()}")
+
+
+@pytest.mark.parametrize("case", [
+    "idle-lone-request", "held-until-a-drain", "full-batch-leaves",
+    "abandon-ends-the-hold", "close-during-a-hold", "one-in-flight",
+    "slow-launch-deepens"])
+def test_a_non_full_batch_is_held_behind_a_staged_one(case):
+    from elasticsearch_tpu.search.scheduler import DECLINED
+    dev = _GatedDevice()
+    states: list = []
+    sched = ContinuousBatchScheduler(
+        node_id=None, max_batch=4,
+        max_in_flight={"one-in-flight": 1,
+                       "slow-launch-deepens": 8}.get(case, 4))
+    run_pipelined = sched._run_pipelined
+
+    def spy(q, live, runner, reqs, state, t_formed):
+        states.append(state)
+        run_pipelined(q, live, runner, reqs, state, t_formed)
+    sched._run_pipelined = spy
+
+    def submit(row):
+        w = sched.submit("knn", ("hold", case), row, dev.launch, dev.drain)
+        _reconciled(sched)
+        return w
+    try:
+        # an idle scheduler serves a lone request at once, and the next
+        # one behind it: with fewer than two undrained nothing is held
+        ws = [submit(1)]
+        dev.wait_launches(1)
+        if case == "idle-lone-request":
+            st = _reconciled(sched)
+            assert st["batches_held"] == 0 and st["hold_ms"] == 0
+            dev.release()
+            assert ws[0].future.result(WAIT_S) == 10
+            _until(sched, "delivered", 1)
+            return
+        if case == "one-in-flight":
+            # the window of one holds everything, as before: the rule
+            # never engages
+            ws += [submit(2), submit(3)]
+            _until(sched, "queue_depth", 2)
+            assert dev.launched == [[1]]
+            dev.release(0)
+            dev.wait_launches(2)
+            assert dev.launched == [[1], [2, 3]]
+            dev.release()
+            assert [w.future.result(WAIT_S) for w in ws] == [10, 20, 30]
+            st = _until(sched, "delivered", 3)
+            assert st["batches_held"] == 0 and st["hold_ms"] == 0
+            return
+        if case == "slow-launch-deepens":
+            # a lane whose launch takes 2.5 device times needs three
+            # staged behind the one that runs: the hold starts at four
+            sched._pace["knn"] = [25.0, 0.0, 10.0]
+            assert sched.stats()["pace"]["knn"]["staged_depth"] == 4
+        for row in range(2, 5 if case == "slow-launch-deepens" else 3):
+            ws.append(submit(row))
+            dev.wait_launches(row)
+        assert dev.launched == [[w.req] for w in ws]
+        # one runs, the others are staged — the next row waits
+        ws.append(submit(len(ws) + 1))
+        _until(sched, "batches_held", 1)
+        if case == "held-until-a-drain":
+            ws += [submit(4), submit(5)]
+            st = _until(sched, "queue_depth", 3)
+            assert st["batches_launched"] == 2 and len(dev.launched) == 2
+            dev.release(0)
+            dev.wait_launches(3)
+            assert dev.launched[2] == [3, 4, 5]     # ONE batch of three
+            st = _until(sched, "batches_launched", 3)
+            assert st["batches_held"] == 1 and st["hold_ms"] > 0
+        elif case == "slow-launch-deepens":
+            assert len(dev.launched) == 4
+        elif case == "full-batch-leaves":
+            ws += [submit(4), submit(5), submit(6)]
+            dev.wait_launches(3)                    # no drain was released
+            assert dev.launched[2] == [3, 4, 5, 6]
+            st = _until(sched, "batches_launched", 3)
+            assert st["batches_drained"] == 0 and st["batches_held"] == 1
+        elif case == "abandon-ends-the-hold":
+            assert sched._abandon_batch(states[0])  # the watchdog's path
+            dev.wait_launches(3)
+            assert dev.launched[2] == [3]
+            assert ws.pop(0).future.result(WAIT_S) is DECLINED
+            st = _until(sched, "batches_launched", 3)
+            assert st["batches_abandoned"] == 1 and st["hold_ms"] > 0
+        elif case == "close-during-a-hold":
+            closer = threading.Thread(target=sched.close)
+            closer.start()
+            closer.join(WAIT_S)
+            assert not closer.is_alive()
+            sched._dispatcher.join(WAIT_S)
+            assert not sched._dispatcher.is_alive()
+            assert ws.pop().future.result(WAIT_S) is DECLINED
+            st = _reconciled(sched)
+            assert st["shed_reasons"] == {"closed": 1}, st
+            assert st["batches_launched"] == 2 and st["hold_ms"] > 0
+        dev.release()
+        assert [w.future.result(WAIT_S) for w in ws] \
+            == [w.req * 10 for w in ws]
+        _until(sched, "delivered", len(ws))
+    finally:
+        dev.release()
+        sched.close()
+
+
+def test_staged_depth_follows_the_measured_launch_and_device_times():
+    """The depth's means, by hand: the device is one FIFO server, so a
+    batch ran from its enqueue or from the finish before it, whichever
+    came later; a launch runs from formation to enqueue and counts at
+    its mean plus one mean deviation."""
+    from elasticsearch_tpu.search.scheduler import PACE_ALPHA as a
+    sched = ContinuousBatchScheduler(node_id=None, max_in_flight=8)
+    try:
+        assert sched._staged_depth_locked("knn") == 2   # nothing measured
+        with sched._lock:
+            # formed 0, on the device's queue at 20 ms, done at 30 ms,
+            # the device free since 0: launch 20, device 10
+            sched._note_pace_locked("knn", 0.0, 0.020, 0.030)
+            assert sched._pace["knn"] == pytest.approx([20.0, 0.0, 10.0])
+            # a launch of 10 ms, enqueued at 40 ms behind a batch that
+            # ran until 60 ms, done at 70 ms: it ran 10 ms, not 30
+            sched._device_free_t = 0.060
+            sched._note_pace_locked("knn", 0.030, 0.040, 0.070)
+            assert sched._pace["knn"] == pytest.approx(
+                [20.0 - a * 10.0, a * 10.0, 10.0])
+            # a launch of 20 ms, enqueued at 100 ms on a device free
+            # since 75 ms, done at 130 ms: it ran 30 ms
+            sched._device_free_t = 0.075
+            sched._note_pace_locked("knn", 0.080, 0.100, 0.130)
+            mean = 20.0 - a * 10.0
+            assert sched._pace["knn"] == pytest.approx(
+                [mean + a * (20.0 - mean),
+                 a * 10.0 + a * (20.0 - mean - a * 10.0), 10.0 + a * 20.0])
+        # 18.75 + 1.1 ms of launch over 12.5 ms: one runs, two staged
+        sched._pace["knn"] = [18.75, 1.1, 12.5]
+        assert sched.stats()["pace"]["knn"] == {
+            "launch_ms": 18.75, "launch_dev_ms": 1.1, "device_ms": 12.5,
+            "staged_depth": 3}
+        sched._pace["knn"] = [12.0, 2.0, 13.0]   # the usual launch shows
+        assert sched._staged_depth_locked("knn") == 3
+        sched._pace["knn"] = [19.0, 5.0, 13.5]   # not the latest
+        assert sched._staged_depth_locked("knn") == 3
+        sched._pace["plane"] = [3.0, 1.0, 14.0]  # a quick launch
+        assert sched._staged_depth_locked("plane") == 2
+    finally:
+        sched.close()
+
+
+# ---------------------------------------------------------------------------
 # weighted-fair pickup
 # ---------------------------------------------------------------------------
 
