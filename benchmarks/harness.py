@@ -450,6 +450,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                and ctx["t_start"] <= r[1] < ctx["t_end"])
     numbers["answers_never_came"] = [int(lost), 0]
     slow_seconds(ctx)
+    window_log(ctx)
     b, a = ctx["before"], ctx["after"]
     log(f"server process in the window: {a['cpu_s'] - b['cpu_s']:.2f} s of "
         f"CPU, collections by generation "
@@ -492,6 +493,42 @@ def slow_seconds(ctx: dict) -> None:
     top = sorted(worst.items(), key=lambda kv: -kv[1])[:8]
     log("slowest request due in second: " + ", ".join(
         f"{sec}: {lat * 1e3:.0f} ms" for sec, lat in sorted(top)))
+
+
+def window_log(ctx: dict) -> dict:
+    """What a run WAS, for whoever reads why a set of runs spread: the
+    scheduler's counters over the window and its pace at both ends (at
+    which staged depth it ran, how many batches it held and for how long,
+    the rows a batch), and the served rate second by second. A run that
+    sat at another depth reads level at another height; one that met a
+    stall shows a few empty seconds in a level run; a rate that wanders
+    inside every run shows as that. Read after the window from what
+    :func:`window` took at its ends and from the generators' records:
+    nothing runs inside the window for it. Logged on standard error only
+    → what was logged, for the self-checks."""
+    sched = stats.scheduler_window(ctx["before"]["scheduler"],
+                                   ctx["after"]["scheduler"])
+    log("scheduler in the window: " + ", ".join(
+        f"{k} {sched[k]:.4g}" for k in (
+            *stats.SCHEDULER_COUNTS, "held_share", "hold_ms_per_held",
+            "rows_per_batch") if k in sched))
+    for lane, ends in sched["pace"].items():
+        log(f"scheduler pace [{lane}]: " + "; ".join(
+            f"at the window's {end}: " + (", ".join(
+                f"{k} {v}" for k, v in doc.items()) if doc else "nothing")
+            for end, doc in ends.items()))
+    by_second = stats.answers_by_second(ctx["records"], ctx["t_start"],
+                                        ctx["t_end"])
+    summary = stats.second_summary(by_second)
+    log("answers in each second of the window: "
+        + " ".join(f"{n:.0f}" for n in by_second))
+    if summary:
+        log(f"answers a second: least {summary['least']:.1f}, median "
+            f"{summary['median']:.1f}, greatest {summary['greatest']:.1f}; "
+            f"stalled seconds (under half the median): "
+            f"{summary['stalled']}")
+    return {"scheduler": sched, "answers_by_second": by_second,
+            "seconds": summary}
 
 
 def traced_slice(node, workdir: str, t_start: float, seconds: float,

@@ -1,10 +1,11 @@
 """Real rows per batch the scheduler launched in the window: ``delivered``
 ÷ ``batches_launched`` from ``ContinuousBatchScheduler.stats()``."""
+from benchmarks import stats
 
 
 def read(ctx):
-    b, a = ctx["before"]["scheduler"], ctx["after"]["scheduler"]
-    launched = a["batches_launched"] - b["batches_launched"]
-    if launched <= 0:
+    b = ctx.get("before", {}).get("scheduler")
+    a = ctx.get("after", {}).get("scheduler")
+    if not a or not b:
         return None
-    return (a["delivered"] - b["delivered"]) / launched
+    return stats.scheduler_window(b, a).get("rows_per_batch")

@@ -1,9 +1,12 @@
 """Percentiles, rates, due times and the traffic schedules."""
 
+import json
+
 import numpy as np
 import pytest
 
 from benchmarks import harness, stats
+from benchmarks.tools import sets
 
 streams = harness.load_module(
     harness.HERE / "traffic" / "streams.py", "streams")
@@ -182,3 +185,100 @@ def test_streams_build_closed_open_and_side_by_side():
     other = streams.build(traffic, Kind, None, 77, 10.0, "ix", 32)
     assert [j["streams"] for j in other["jobs"] if j["mode"] == "closed"] \
         == [j["streams"] for j in closed]
+
+
+def sched_doc(launched, held, hold_ms, delivered, pace):
+    return {"batches_launched": launched, "batches_held": held,
+            "hold_ms": hold_ms, "delivered": delivered, "pad_rows": 0,
+            "pace": pace}
+
+
+def test_the_windows_log_says_what_the_run_was(capsys):
+    """A hand-made ``before`` / ``after`` pair and six seconds of records
+    → the printed depth, held share, rows a batch and per-second rates:
+    100 batches of which 90 waited 4.5 ms each, 380 rows; the depth went
+    from three to four; second 3 stood still."""
+    pace0 = {"knn": {"launch_ms": 15.0, "launch_dev_ms": 3.0,
+                     "device_ms": 13.4, "staged_depth": 3}}
+    pace1 = {"knn": {"launch_ms": 22.0, "launch_dev_ms": 6.0,
+                     "device_ms": 13.5, "staged_depth": 4}}
+    before = sched_doc(1000, 900, 4000.0, 3800, pace0)
+    after = sched_doc(1100, 990, 4405.0, 4180, pace1)
+    per_second = [4, 5, 4, 0, 5, 4]
+    records, rid = [], 0
+    for sec, n in enumerate(per_second):
+        for j in range(n):
+            done = 100.0 + sec + (j + 0.5) / n
+            records.append(rec(rid, done - 0.05, done - 0.05, done))
+            rid += 1
+    records.append(rec(rid, 105.9, 105.9, 106.2))     # a third inside
+    records.append(rec(rid + 1, 102.1, 102.1, 102.2, ok=0, status=429))
+    ctx = {"before": {"scheduler": before}, "after": {"scheduler": after},
+           "records": records, "t_start": 100.0, "t_end": 106.0}
+    got = harness.window_log(ctx)
+    sched = got["scheduler"]
+    assert sched["batches_launched"] == 100 and sched["batches_held"] == 90
+    assert sched["held_share"] == pytest.approx(0.9)
+    assert sched["hold_ms_per_held"] == pytest.approx(4.5)
+    assert sched["rows_per_batch"] == pytest.approx(3.8)
+    assert sched["pace"]["knn"]["start"]["staged_depth"] == 3
+    assert sched["pace"]["knn"]["end"]["staged_depth"] == 4
+    assert got["answers_by_second"] == pytest.approx(
+        [4, 5, 4, 0, 5, 4 + 1 / 3])
+    assert got["seconds"] == pytest.approx(
+        {"least": 0, "median": 4 + 1 / 6, "greatest": 5, "stalled": [3]})
+    err = capsys.readouterr().err
+    assert capsys.readouterr().out == ""
+    for piece in ("batches_launched 100", "batches_held 90", "hold_ms 405",
+                  "held_share 0.9", "rows_per_batch 3.8",
+                  "at the window's start: launch_ms 15.0",
+                  "device_ms 13.5, staged_depth 4",
+                  "window: 4 5 4 0 5 4", "least 0.0, median 4.2, greatest 5.0",
+                  "stalled seconds (under half the median): [3]"):
+        assert piece in err, piece
+    assert all(line.startswith("[bench] ") for line in err.splitlines())
+    # the set runner reads its row back from these very lines
+    line = json.dumps({"correct": True, "failed": 0, "metrics": {
+        "qps": {"value": 3.7, "unit": "queries/s"}}})
+    row = sets.read_run(line, err + "[bench] server process in the window: "
+                        "9.10 s of CPU, collections by generation [70, 6, 2]")
+    assert row == {"correct": True, "failed": 0, "qps": 3.7,
+                   "held_share": 0.9, "hold_ms_per_held": 4.5,
+                   "rows_per_batch": 3.8, "staged_depth": {"knn": [3, 4]},
+                   "stalled": [3], "full_collections": 2}
+
+
+def test_a_run_without_a_scheduler_at_work_logs_that_and_no_more(capsys):
+    # an _msearch bypasses the scheduler: nothing launched, no lane paced
+    idle = sched_doc(0, 0, 0.0, 0, {})
+    got = harness.window_log({
+        "before": {"scheduler": idle}, "after": {"scheduler": idle},
+        "records": [rec(0, 0.1, 0.1, 1.4, ok=64, items=64)],
+        "t_start": 0.0, "t_end": 2.0})
+    assert "rows_per_batch" not in got["scheduler"]
+    # 64 answers over 1.3 s in flight: 0.9 s of it in the first second
+    assert got["answers_by_second"] == pytest.approx(
+        [64 * 0.9 / 1.3, 64 * 0.4 / 1.3])
+    err = capsys.readouterr().err
+    assert "batches_launched 0" in err and "scheduler pace" not in err
+    # a program from before the hold has no such counters: left out
+    old = {"batches_launched": 5, "delivered": 15}
+    assert stats.scheduler_window({"batches_launched": 1, "delivered": 3},
+                                  old) == {
+        "batches_launched": 4, "delivered": 12, "rows_per_batch": 3.0,
+        "pace": {}}
+
+
+def test_the_drivers_spread_leaves_out_the_farthest_run():
+    # PR 30's words: "A spread leaves out the run farthest from its
+    # median where that narrows it"
+    assert stats.driver_spread([280.0, 281.0, 279.0, 282.0, 280.5, 260.0]) \
+        == pytest.approx(3.0)
+    assert stats.driver_spread([1.0, 2.0]) == pytest.approx(1.0)
+    assert stats.driver_spread([5.0, 5.0, 5.0]) == 0.0
+    # PR 31's set B: both forms, as shares of the median
+    got = sets.summarise([277.56, 281.84, 282.69, 284.65, 273.97, 262.78])
+    assert got["median"] == pytest.approx(279.70)
+    assert got["iqr_share"] == pytest.approx(0.0429, abs=1e-4)
+    assert got["driver_spread"] == pytest.approx(10.68)
+    assert got["driver_share"] == pytest.approx(0.0382, abs=1e-4)
