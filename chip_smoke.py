@@ -35,7 +35,7 @@ from pathlib import Path
 
 REPO = Path(__file__).resolve().parent
 
-VOCAB = 30_000            # bench.make_corpus shape: vocab 30k,
+VOCAB = 30_000            # make_corpus shape: vocab 30k,
 MEAN_LEN = 56             # mean length 56,
 MAX_UNIQUE = 80           # unique-term axis trimmed to what is used
 BULK_DOCS = 4096          # docs that take the per-document write path
@@ -121,13 +121,66 @@ def hits_of(resp: dict):
 # corpus → index
 # ---------------------------------------------------------------------------
 
+def make_corpus(rng, n_docs: int, vocab: int, mean_len: int, max_unique: int,
+                chunk: int = 1_000_000):
+    """Vectorized Zipf-ish corpus directly in packed column form (chunked:
+    the f64 sampling scratch of a large corpus would not fit at once).
+    Poisson lengths clipped to [8, 112], terms from a power law over the
+    vocabulary. ``tests/test_chip_smoke.py`` pins the arrays by hash: the
+    AOT tests' shapes and the oracle comparisons rest on them."""
+    import numpy as np
+    lens = np.clip(rng.poisson(mean_len, n_docs), 8, 112).astype(np.int32)
+    L = int(lens.max())
+    U = max_unique
+    toks = np.full((n_docs, L), -1, np.int32)
+    uterms = np.full((n_docs, U), -1, np.int32)
+    utf = np.zeros((n_docs, U), np.float32)
+    df = np.zeros(vocab, np.int64)
+    for lo in range(0, n_docs, chunk):
+        hi = min(lo + chunk, n_docs)
+        n = hi - lo
+        ranks = (rng.pareto(1.1, size=(n, L)) + 1)
+        tk = np.minimum((ranks * 3).astype(np.int64),
+                        vocab - 1).astype(np.int32)
+        del ranks
+        mask = np.arange(L)[None, :] < lens[lo:hi, None]
+        tk = np.where(mask, tk, -1)
+        toks[lo:hi] = tk
+
+        # unique terms + counts per row (vectorized)
+        order = np.argsort(tk, axis=1, kind="stable")
+        st = np.take_along_axis(tk, order, axis=1)
+        new = np.ones_like(st, dtype=bool)
+        new[:, 1:] = st[:, 1:] != st[:, :-1]
+        new &= st >= 0
+        uidx = np.cumsum(new, axis=1) - 1          # unique slot per token
+        rows = np.broadcast_to(np.arange(lo, hi)[:, None], (n, L))
+        valid = (st >= 0) & (uidx < U)
+        np.add.at(utf, (rows[valid], uidx[valid]), 1.0)
+        first = new & valid
+        uterms[rows[first], uidx[first]] = st[first]
+        np.add.at(df, uterms[lo:hi][uterms[lo:hi] >= 0], 1)
+    # trim the unique-term axis to what the corpus actually used
+    used = int(np.argmax((uterms >= 0).any(axis=0)[::-1]))
+    u_eff = U - used if (uterms >= 0).any() else 1
+    return uterms[:, :u_eff], utf[:, :u_eff], lens, df, toks
+
+
+def make_queries(rng, n_queries: int, terms: int, df):
+    """Query terms sampled from the corpus distribution (common + rare mix)."""
+    import numpy as np
+    present = np.nonzero(df > 0)[0]
+    w = df[present].astype(np.float64)
+    w /= w.sum()
+    return rng.choice(present, size=(n_queries, terms), p=w).astype(np.int32)
+
+
 class Corpus:
-    """The seeded corpus in ``bench.make_corpus`` shape plus the columns
-    the filter / aggregation requests read."""
+    """The seeded corpus of ``make_corpus`` plus the columns the filter /
+    aggregation requests read."""
 
     def __init__(self, rng, n_docs: int, vocab: int):
         import numpy as np
-        from bench import make_corpus
         self.n_docs, self.vocab = n_docs, vocab
         (self.uterms, self.utf, self.lens, self.df,
          self.toks) = make_corpus(rng, n_docs, vocab, MEAN_LEN, MAX_UNIQUE)
@@ -386,7 +439,6 @@ def run_one_chip(args, dev: dict) -> None:
 
 def _serve_main_index(args, node, http: Http) -> None:
     import numpy as np
-    from bench import make_queries
     from bm25_oracle import BM25Oracle
 
     rng = np.random.default_rng(args.seed)
@@ -396,7 +448,7 @@ def _serve_main_index(args, node, http: Http) -> None:
     say(f"corpus: {n_docs} docs, vocab {VOCAB}, unique-term width "
         f"U={corpus.uterms.shape[1]}, avg length "
         f"{corpus.lens.mean():.1f}, seed {args.seed}")
-    qtids = make_queries(rng, 64 + 8, VOCAB, QUERY_TERMS, corpus.df)
+    qtids = make_queries(rng, 64 + 8, QUERY_TERMS, corpus.df)
     oracle = phase("oracle (host)", BM25Oracle, corpus.toks)
 
     phase("write path: _bulk + _refresh", bulk_index, http, "smoke",
@@ -612,8 +664,7 @@ def _serve_lanes_index(args, node, http: Http) -> None:
 
 
 def make_lane_queries(rng, corpus: Corpus) -> list:
-    from bench import make_queries
-    qtids = make_queries(rng, 16, corpus.vocab, QUERY_TERMS, corpus.df)
+    qtids = make_queries(rng, 16, QUERY_TERMS, corpus.df)
     return [corpus.query_text(row) for row in qtids]
 
 

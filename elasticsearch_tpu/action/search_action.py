@@ -658,7 +658,8 @@ class SearchActions:
         """Shard-side query phase through the continuous-batching
         scheduler: concurrent single-search traffic targeting the same
         (reader, lane, shape) coalesces into ONE batched device program
-        — the request-at-a-time gap BENCH_r04 measured. Falls back to
+        — what ``sched_batch_fill.tput`` reads on the benchmark's knn
+        cell (PERF.md section 3). Falls back to
         the serial :meth:`ShardSearcher.query_phase` when the request's
         shape is unbatchable, the scheduler declines (ineligible batch,
         device fallback, shutdown), or the plane breaker is open (the

@@ -244,10 +244,10 @@ class LintConfig:
     cost_lane_callers: tuple = ("observed_compile", "_get_compiled")
     #: the registered program lanes (mirrors lanes.PROGRAM_LANES; the
     #: tier-1 fixture suite asserts the two stay in sync)
-    program_lanes: tuple = ("segment", "segment-batch", "reader-batch",
-                            "streamed", "percolate", "impact-eager",
-                            "impact-pruned", "impact-rescore", "knn",
-                            "mesh", "impact-mesh", "knn-mesh")
+    program_lanes: tuple = ("segment", "reader-batch", "streamed",
+                            "percolate", "impact-eager", "impact-pruned",
+                            "impact-rescore", "knn", "mesh", "impact-mesh",
+                            "knn-mesh")
     #: gauge registries in the lane-registry module: emitted into
     #: lane_graph.json next to the counter registries and required (by
     #: counter-unexported) to be referenced by the exporter, but their
@@ -258,13 +258,12 @@ class LintConfig:
     # ---- unbounded-wait --------------------------------------------------
     #: modules where every blocking ``.result()``/``.join()``/``.get()``/
     #: ``.wait()`` must carry a timeout: the device executor, the
-    #: dispatcher, the admission batcher, and the coordinator fan-out —
+    #: dispatcher, the batch scheduler, and the coordinator fan-out —
     #: the layers a wedged device dispatch would otherwise hang.
     #: Worker-loop homes (threadpool, cluster service) stay out: a
     #: worker idling for its next task may block without bound.
     wait_modules: tuple = ("*/search/jit_exec.py",
                            "*/search/scheduler.py",
-                           "*/search/batching.py",
                            "*/search/watchdog.py",
                            "*/action/search_action.py")
 

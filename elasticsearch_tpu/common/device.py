@@ -2,7 +2,7 @@
 and which device the process found.
 
 One process owns one chip; every entry point that compiles (``estpu``,
-``chip_smoke.py``, ``bench.py``, ``__graft_entry__.py``) goes through
+``chip_smoke.py``, ``benchmarks/run.py``, ``__graft_entry__.py``) goes through
 :func:`ensure_compile_cache` before its first compile, and
 ``Node.start()`` calls it for them.
 """

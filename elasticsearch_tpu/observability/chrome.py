@@ -8,8 +8,7 @@ with per-node lanes. Counter samples (the timeseries ring's ledger
 bytes and per-lane rates) become "C" events — Perfetto renders them as
 stacked counter tracks under the node's process, so HBM occupancy and
 lane throughput line up against the spans that caused them.
-``GET /_nodes/trace`` serves this document and ``bench.py`` stamps one
-per leg.
+``GET /_nodes/trace`` serves this document.
 """
 
 from __future__ import annotations

@@ -7,7 +7,7 @@ tests smear one node's activity into every node's ``_nodes/stats``. The
 node id of the moment comes from, in order:
 
 1. an explicit :func:`use_node` override (background pools that work on
-   behalf of a node without a task — the plane warm pool, bench probes);
+   behalf of a node without a task — the plane warm pool);
 2. the thread's current :class:`~elasticsearch_tpu.tasks.manager.Task`
    (the transport layer registers one per inbound request, and
    ``bind_current`` carries it across pool submits), whose ``node_id``

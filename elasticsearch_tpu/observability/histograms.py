@@ -1,7 +1,7 @@
 """Fixed-bucket latency histograms, per lane, per node — always on.
 
-The closed-loop bench legs (and the continuous-batching scheduler they
-will tune) need latency DISTRIBUTIONS, not means: a 68 ms device-RTT
+Closed-loop traffic (and the continuous-batching scheduler it rides)
+needs latency DISTRIBUTIONS, not means: a 68 ms device-RTT
 floor under 16 clients is invisible in an average but owns the p50. The
 reference ships the same idea as the ``search`` / ``indexing`` time
 rollups in nodes stats; here every lane gets a log-spaced fixed-bucket

@@ -37,8 +37,9 @@ records here, ``untracked`` when a site carries no tag) or through
 Each node's ledger lives on its
 :class:`~elasticsearch_tpu.common.breaker.HierarchyCircuitBreakerService`
 (``breaker_service.device_ledger``) — in-process multi-node clusters get
-per-node books for free. The class-level registry lets bench.py stamp a
-process-wide snapshot without a node handle.
+per-node books for free. The module-level registry gives
+:func:`global_snapshot` (the timeseries sampler's view when no node's
+ledger is handed in) every live ledger without a node handle.
 """
 
 from __future__ import annotations
@@ -79,8 +80,8 @@ class LedgerEntry:
         self.device = device
 
 
-#: every live ledger (one per breaker service) — the process-wide view
-#: bench.py stamps without a node handle
+#: every live ledger (one per breaker service) — what
+#: :func:`global_snapshot` merges
 _ALL: "weakref.WeakSet" = weakref.WeakSet()
 
 
@@ -254,9 +255,10 @@ def account_absolute(breaker_service, engine_uuid: str, component: str,
 
 def global_snapshot() -> dict:
     """Merge every live ledger's per-component/per-index books — the
-    process-wide view bench.py stamps into artifacts (in-process
-    clusters have one ledger per node; a bench run without nodes still
-    sees the device reader / block cache charges)."""
+    process-wide view ``timeseries.collect_sample`` reads when it is
+    given no node's ledger (in-process clusters have one ledger per
+    node; a process without nodes still sees the device reader / block
+    cache charges)."""
     totals = {"total_bytes": 0, "charged_bytes": 0, "uncharged_bytes": 0,
               "entries": 0,
               "by_component": {c: 0 for c in COMPONENTS},

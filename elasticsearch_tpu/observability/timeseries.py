@@ -208,7 +208,7 @@ def collect_sample(node_id: str, extra: dict | None = None,
 def tick(node_id: str, extra: dict | None = None, ledger=None,
          now: float | None = None, force: bool = False) -> bool:
     """Collect one sample and record it — the scrape-path entry
-    (_nodes/stats, /_prometheus, bench leg boundaries, tests)."""
+    (_nodes/stats, /_prometheus, tests)."""
     counters, buckets = collect_sample(node_id, extra=extra,
                                        ledger=ledger)
     return record(node_id, counters, buckets, now=now, force=force)

@@ -69,7 +69,7 @@ def merge_top_k_batch(scores_list, docs_list, k: int, bases):
     rankings (segment-LOCAL doc ids) → global ``([B, k], [B, k])``.
 
     The batch-axis companion of :func:`merge_top_k` for the vmapped query
-    path (jit_exec.run_segment_batch): `bases` maps each segment's local
+    path (jit_exec.run_reader_batch): `bases` maps each segment's local
     ids to reader-global ids inside the program, and concatenation in
     segment order + stable top_k keeps the reference's merge tie-break
     (TopDocs.merge, core/search/controller/SearchPhaseController.java:165).

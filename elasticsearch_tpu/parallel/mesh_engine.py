@@ -1974,7 +1974,7 @@ class MeshEngineSearcher:
         # resolve every (shard, slot, query): consts [S, B, ...]; signature
         # must agree across shards AND queries per slot (uniform field
         # layout makes shard structure uniform; mixed query structures are
-        # rejected like run_segment_batch's None)
+        # rejected like _plan_segment_batch's None)
         sigs, layouts, emits, pfs, refss = [], [], [], [], []
         consts_dev = []
         from elasticsearch_tpu.search import jit_exec

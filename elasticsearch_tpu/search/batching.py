@@ -1,272 +1,21 @@
-"""Admission-queue micro-batching for request-at-a-time traffic.
+"""``pow2_bucket`` — the one bucketing rule every batched/jitted layer
+shares.
 
-The reference executes each search on its own thread the moment it
-arrives (core/search/query/QueryPhase.java:314's per-request model over
-the `search` thread pool). On an accelerator the economics invert: one
-fused batched program amortizes the dispatch + device→host round trip
-over every query in the batch (`ShardSearcher.query_phase_batch`), so the
-winning server shape for concurrent low-rate clients is an admission
-queue that coalesces whatever requests arrive within a tiny deadline into
-one device batch — the same latency/throughput trade TPU serving stacks
-make for model inference.
-
-Semantics: each caller blocks until its own result is ready; a request
-never waits longer than `max_wait_s` for peers, and a full batch
-dispatches immediately. Ineligible requests (aggs, sort-by-field, …)
-fall through to the caller's serial path untouched, so this is purely an
-optimization layer — results are produced by the same
-`query_phase_batch` program the msearch path uses.
+This module is only the helper's home (eight import sites name it); the
+batch scheduler is ``search/scheduler.py``.
 """
 
 from __future__ import annotations
-
-import inspect
-import threading
-from concurrent.futures import Future, ThreadPoolExecutor
-from concurrent.futures import TimeoutError as FutTimeout
-
-#: ceiling on `execute()`'s blocking wait for its own batch result — a
-#: wedged run/drain must surface as a serial fallback (None) with a
-#: `stalled` tally, never a caller thread parked forever
-EXECUTE_STALL_S = 60.0
 
 
 def pow2_bucket(n: int, cap: int | None = None) -> int:
     """Smallest power of two >= n (>= 1), clamped to `cap` when given.
 
-    The one bucketing rule every batched/jitted layer shares — admission
-    batches here, jit_exec's vmap batch axis, and the mesh plane's k and
-    batch buckets — so a jagged size distribution compiles O(log N)
-    programs instead of one per distinct count."""
+    The one bucketing rule every batched/jitted layer shares — the
+    scheduler's pad rows, jit_exec's vmap batch axis, and the mesh
+    plane's k and batch buckets — so a jagged size distribution compiles
+    O(log N) programs instead of one per distinct count."""
     b = 1 if n <= 1 else 1 << (n - 1).bit_length()
     if cap is not None and b > cap:
         return cap
     return b
-
-
-class AdaptiveBatcher:
-    """Deadline-bounded micro-batch admission queue in front of a
-    `query_phase_batch`-shaped callable.
-
-    `run_batch(reqs) -> list[results] | None` — None means the batch was
-    ineligible; every waiter then receives None and the caller runs its
-    serial fallback.
-
-    Pipelined mode: pass `drain_batch` and `run_batch` becomes the
-    LAUNCH phase (`query_phase_batch_launch`-shaped: async device
-    dispatch, returns an opaque handle or None-for-ineligible) while
-    `drain_batch(handle) -> list[results]` blocks for the device→host
-    transfer on a worker thread. Launching batch N+1 no longer waits for
-    batch N's results to cross the interconnect — on a high-RTT link
-    that drain otherwise idles the device for its full round trip. Up to
-    `max_in_flight` batches may be launched-but-undrained at once (a
-    semaphore backpressures the admission queue beyond that)."""
-
-    def __init__(self, run_batch, max_batch: int = 64,
-                 max_wait_s: float = 0.002, pad_to_bucket: bool = True,
-                 drain_batch=None, max_in_flight: int = 4):
-        self._run_batch = run_batch
-        self._drain_batch = drain_batch
-        if drain_batch is not None:
-            self._inflight = threading.BoundedSemaphore(max_in_flight)
-            self._drain_pool = ThreadPoolExecutor(
-                max_workers=max_in_flight,
-                thread_name_prefix="batch-drain")
-        else:
-            self._inflight = None
-            self._drain_pool = None
-        self.max_batch = max_batch
-        self.max_wait_s = max_wait_s
-        # Pad formed batches up to the next power of two so a jitted
-        # run_batch compiles O(log B) programs instead of one per
-        # distinct arrival count — jagged batch sizes are the norm under
-        # a deadline trigger. Padding replicates the FIRST request as a
-        # no-op row (results sliced off before delivery); run_batch
-        # callables that take `n_real` get the real-row count so lane
-        # stats never count pad rows (query_phase_batch_launch does).
-        self.pad_to_bucket = pad_to_bucket
-        try:
-            self._pass_n_real = "n_real" in \
-                inspect.signature(run_batch).parameters
-        except (TypeError, ValueError):      # builtins / C callables
-            self._pass_n_real = False
-        self._lock = threading.Lock()
-        self._queue: list[tuple[object, Future]] = []
-        self._timer: threading.Timer | None = None
-        self._closed = False
-        # dispatch counters (read by callers for telemetry; written under
-        # _lock — full-batch and deadline dispatches run on different
-        # threads)
-        self.batches = 0
-        self.requests = 0
-        # execute() waits that hit the stall ceiling and fell back serial
-        self.stalled = 0
-
-    def bucket_sizes(self) -> list[int]:
-        """Every batch size _dispatch can hand to run_batch: powers of two
-        below max_batch plus max_batch itself. Callers that pre-compile
-        (warm) programs iterate exactly this set."""
-        if not self.pad_to_bucket:
-            return list(range(1, self.max_batch + 1))
-        sizes, b = [], 1
-        while b < self.max_batch:
-            sizes.append(b)
-            b <<= 1
-        sizes.append(self.max_batch)
-        return sizes
-
-    def submit(self, req) -> Future:
-        """Enqueue one request; the Future resolves to its result (or None
-        when the formed batch turned out ineligible)."""
-        fut: Future = Future()
-        with self._lock:
-            if self._closed:
-                fut.set_result(None)
-                return fut
-            self._queue.append((req, fut))
-            full = len(self._queue) >= self.max_batch
-            if full:
-                batch = self._drain_locked()
-            elif self._timer is None:
-                t = threading.Timer(self.max_wait_s, self._deadline_fire)
-                t.daemon = True
-                t.start()
-                self._timer = t
-                batch = None
-            else:
-                batch = None
-        if full:
-            self._dispatch(batch)
-        return fut
-
-    def execute(self, req):
-        """Blocking convenience: submit and wait. → result | None.
-
-        BOUNDED: when the batch wedges past ``EXECUTE_STALL_S`` (hung
-        device dispatch or drain) the wait is abandoned and the caller
-        gets None — the serial-fallback contract — with the stall
-        tallied. The batch thread still owns its futures; a late result
-        resolves a future nobody reads, which is harmless."""
-        try:
-            return self.submit(req).result(EXECUTE_STALL_S)
-        except FutTimeout:
-            with self._lock:
-                self.stalled += 1
-            return None
-
-    def close(self) -> None:
-        with self._lock:
-            self._closed = True
-            batch = self._drain_locked()
-        for _, fut in batch:
-            fut.set_result(None)
-        if self._drain_pool is not None:
-            # let in-flight drains finish so no waiter hangs forever
-            self._drain_pool.shutdown(wait=True)
-
-    # ---- internals ---------------------------------------------------------
-
-    def _drain_locked(self) -> list:
-        batch, self._queue = self._queue, []
-        if self._timer is not None:
-            # a full-batch drain must defuse the pending deadline timer, or
-            # it fires into the NEXT forming batch and fragments it
-            self._timer.cancel()
-            self._timer = None
-        return batch
-
-    def _deadline_fire(self) -> None:
-        with self._lock:
-            batch = self._drain_locked()
-        if batch:
-            self._dispatch(batch)
-
-    def _dispatch(self, batch: list) -> None:
-        reqs = [r for r, _ in batch]
-        n_real = len(reqs)
-        if self.pad_to_bucket and len(reqs) < self.max_batch:
-            # bucket sizes that can reach run_batch: powers of two below
-            # max_batch, plus max_batch itself (full batches form at
-            # exactly max_batch anyway) — O(log B) distinct compiles even
-            # for a non-power-of-two max_batch. Pad rows replicate the
-            # first request only: cycling every queued request re-ran
-            # real work through the program a second time and (on the
-            # impact/knn lanes) double-counted admission stats
-            bucket = pow2_bucket(len(reqs), self.max_batch)
-            reqs = reqs + [reqs[0]] * (bucket - len(reqs))
-
-        def run(rs):
-            if self._pass_n_real and len(rs) != n_real:
-                return self._run_batch(rs, n_real=n_real)
-            return self._run_batch(rs)
-        if self._drain_batch is not None:
-            # pipelined: launch here (async device dispatch, fast), drain
-            # on a worker — the next batch forms and launches while this
-            # one's results ride the link
-            self._inflight.acquire()
-            with self._lock:
-                closed = self._closed
-            if closed:
-                # close() raced us while we blocked on the in-flight
-                # semaphore: the pool may already be shut down — resolve
-                # the waiters (None = serial fallback) instead of leaving
-                # them hung on futures nobody will complete
-                self._inflight.release()
-                for _, fut in batch:
-                    if not fut.done():
-                        fut.set_result(None)
-                return
-            try:
-                handle = run(reqs)
-            except Exception as e:           # noqa: BLE001 — fan the error out
-                self._inflight.release()
-                for _, fut in batch:
-                    if not fut.done():
-                        fut.set_exception(e)
-                return
-            if handle is None:
-                self._inflight.release()
-                for _, fut in batch:
-                    fut.set_result(None)
-                return
-            try:
-                self._drain_pool.submit(self._drain_and_deliver, handle,
-                                        batch)
-            except RuntimeError:
-                # pool shut down between the closed check and submit —
-                # drain inline so the launched handle and its waiters
-                # still complete
-                self._drain_and_deliver(handle, batch)
-            return
-        try:
-            results = run(reqs)
-        except Exception as e:               # noqa: BLE001 — fan the error out
-            for _, fut in batch:
-                if not fut.done():
-                    fut.set_exception(e)
-            return
-        self._deliver(batch, results)
-
-    def _drain_and_deliver(self, handle, batch: list) -> None:
-        try:
-            results = self._drain_batch(handle)
-        except Exception as e:               # noqa: BLE001 — fan the error out
-            for _, fut in batch:
-                if not fut.done():
-                    fut.set_exception(e)
-            return
-        finally:
-            self._inflight.release()
-        self._deliver(batch, results)
-
-    def _deliver(self, batch: list, results) -> None:
-        if results is None:
-            for _, fut in batch:
-                fut.set_result(None)
-            return
-        for (_, fut), res in zip(batch, results):
-            if not fut.done():
-                fut.set_result(res)
-        with self._lock:
-            self.batches += 1
-            self.requests += len(batch)

@@ -20,7 +20,7 @@ The ConstTable separates a query's *structure* (static signature tokens +
 constant shapes) from its *constants* (values): queries sharing a signature
 share one compiled XLA program, with constants as inputs — and a batch of
 same-signature queries runs under ``jax.vmap`` with constants stacked on a
-leading axis (jit_exec.run_segment_batch).
+leading axis (jit_exec.run_reader_batch).
 
 Term-to-ordinal resolution happens host-side, which is exactly the part of
 Lucene's per-segment TermsEnum.seek that has no business running on an

@@ -21,7 +21,7 @@ Mechanics (see execute.SegmentResolver):
 3. **emit under jit** — the jitted function rebuilds a segment view from
    traced arrays and calls the emit closures with traced constants.
 4. **batch** — B same-signature queries stack their constants on a leading
-   axis and run under ``jax.vmap`` as one program (run_segment_batch): the
+   axis and run under ``jax.vmap`` as one program (run_reader_batch): the
    TPU-native answer to request-at-a-time dispatch.
 """
 
@@ -1042,7 +1042,7 @@ def run_segments_streamed(segments: list, ctx: ExecutionContext,
     core/index/store/FsDirectoryService.java mmap).
 
     Returns one ``{"count", "top_scores", "top_docs"}`` dict per segment
-    (batch axis padded like :func:`run_segment_batch` — callers slice),
+    (batch axis padded like :func:`run_reader_batch` — callers slice),
     or None when any segment's plan is ineligible for batching.
     """
     if not segments:
@@ -1069,7 +1069,7 @@ def run_segments_streamed(segments: list, ctx: ExecutionContext,
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                 (plan["flat"], plan["packed"]))
             return jax.jit(run).lower(*shapes)
-        # same key space as run_segment_batch: bucketized segments with a
+        # keyed by the plan, not the segment: bucketized segments with a
         # common layout share ONE compiled program across the whole sweep
         return _get_compiled(("batch",) + plan["key"], compile_fn,
                              lane="streamed",
@@ -1079,7 +1079,7 @@ def run_segments_streamed(segments: list, ctx: ExecutionContext,
     # transfers run on a DEDICATED feeder thread, one segment ahead:
     # host→HBM DMA overlaps the in-flight program's compute even when
     # device_put itself blocks the calling thread on this interconnect —
-    # the same reason batching.py drains on worker threads. A
+    # the same reason the scheduler drains on worker threads. A
     # 2-permit semaphore bounds MATERIALIZED segments to two (the
     # over-capacity contract this path exists for); the consumer blocks
     # on segment i−1's completion before granting the next permit, so
@@ -1176,7 +1176,7 @@ def run_segments_streamed(segments: list, ctx: ExecutionContext,
 
 def pack_query_consts(consts_rows: list) -> tuple | None:
     """Stack B same-signature queries' ConstTable values into one [B_pad,
-    total] buffer per dtype (the run_segment_batch packing discipline: two
+    total] buffer per dtype (the _plan_segment_batch packing discipline: two
     packed transfers beat N small ones, and the batch axis pads to the
     next power of two so varying registration counts share programs).
     → (specs, packed, b_pad) or None when the shared plan is const-free
@@ -1314,67 +1314,6 @@ def run_percolate_lanes(lanes: list) -> list:
             results[i] = arr[row, :lane["b"]] if lane["specs"] \
                 else arr[row]
     return results
-
-
-def run_segment_batch(seg: DeviceSegment, ctx: ExecutionContext,
-                      queries: list, *, k: int,
-                      n_real: int | None = None) -> dict | None:
-    """Execute a BATCH of queries against one device segment as ONE vmapped
-    compiled program.
-
-    This is the TPU-native answer to the reference's request-at-a-time
-    search dispatch (SearchService.executeQueryPhase,
-    core/search/SearchService.java:293, driven per request by
-    TransportSearchTypeAction): an accelerator wants batches, so queries
-    sharing a plan signature share one program with their constants stacked
-    on a leading batch axis — scoring, masking and per-query top-k all run
-    under jax.vmap with no host round-trips between queries.
-
-    Only the score-ordered top-k shape is supported (no post_filter /
-    min_score / search_after / aggregation arrays — callers route such
-    requests down the per-query path). Returns ``{"count": [B] i32,
-    "top_scores": [B, k] f32, "top_docs": [B, k] i32}`` (segment-local doc
-    ids) as device arrays, or ``None`` when the queries do not all share
-    one plan signature — or the shared plan has no dynamic constants —
-    (the caller falls back to per-query execution).
-
-    The batch axis is padded to the next power of two (repeating the last
-    query's constants) so varying batch sizes share compiled programs.
-    """
-    plan = _plan_segment_batch(seg, ctx, queries, int(k))
-    if plan is None:
-        return None
-    b = len(queries)
-    key = ("batch",) + plan["key"]
-    flat = plan["flat"]
-    packed = {dt: jnp.asarray(buf) for dt, buf in plan["packed"].items()}
-    if os.environ.get("JIT_DEBUG"):
-        total = sum(int(a.size) * a.dtype.itemsize for a in flat)
-        print(f"[jit-debug] batch flat: {len(flat)} arrays, "
-              f"{total/1e6:.1f} MB traced; pos_for={sorted(plan['pos'])} "
-              f"vecs={sorted(plan['vecs'])}", flush=True)
-
-    def compile_fn():
-        def run(flat_in, packed_in):
-            view = seg_rebuild(seg, flat_in, plan["pos"], plan["vecs"])
-            return jax.vmap(_lane_fn(plan, view))(packed_in)
-
-        shapes = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-            (flat, packed))
-        return jax.jit(run).lower(*shapes)
-
-    fn = _get_compiled(key, compile_fn, lane="segment-batch",
-                       owner=getattr(ctx.reader, "engine_uuid", None))
-    with device_span("dispatch",
-                     cost=("segment-batch", key,
-                           n_real if n_real is not None else b,
-                           plan["b_pad"])):
-        device_fault_point("dispatch")
-        outs = fn(flat, packed)
-    if plan["b_pad"] != b:
-        outs = {name: v[:b] for name, v in outs.items()}
-    return outs
 
 
 # ---------------------------------------------------------------------------
@@ -2002,8 +1941,7 @@ def run_impact_rescore(pack: _ImpactPack, term_lists: list,
 # ---------------------------------------------------------------------------
 # Dense + late-interaction retrieval lane (top-level `knn` search section)
 #
-# Brute-force exact kNN over HBM-resident vector columns (the sharded
-# matmul already beats BM25 QPS on every bench round — ROADMAP item 4),
+# Brute-force exact kNN over HBM-resident vector columns,
 # fused MaxSim over rank_vectors token matrices (ops/maxsim.py,
 # FLASH-MAXSIM-style block accumulation), and IN-PROGRAM hybrid fusion:
 # when a request carries both `knn` and `query`, both lanes score in the
@@ -2158,8 +2096,8 @@ def note_planner_fallback(reason: str) -> None:
 def note_planner_plan(n_nodes: int, cold: bool = False) -> None:
     """One batch the query planner priced and routed onto a compiled
     arm (``n_nodes`` composed sub-plan nodes rode ONE dispatch);
-    ``cold`` marks a plan priced without any measured EWMA — the
-    pricing-confidence split the bench's cost-error leg reads."""
+    ``cold`` marks a plan priced without any measured EWMA, counted
+    apart as ``planner_cold_plans``."""
     with _cache_lock:
         _bump("planner_plans")
         if cold:

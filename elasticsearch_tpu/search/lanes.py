@@ -141,7 +141,6 @@ PERCOLATE_COUNTERS = {
 #: "impact-pruned at this shape", not "the impact lane".
 PROGRAM_LANES = (
     "segment",          # run_segment: one query × one device segment
-    "segment-batch",    # run_segment_batch: B queries × one segment
     "reader-batch",     # run_reader_batch: whole-reader fused program
     "streamed",         # run_segments_streamed: host-pool segment sweep
     "percolate",        # run_percolate_lanes: fused percolate groups

@@ -526,7 +526,7 @@ class ShardSearcher:
         per-request :meth:`query_phase`.
 
         Implemented as launch + drain so a pipelined caller (the
-        AdaptiveBatcher) can overlap batch N's device→host drain with
+        ContinuousBatchScheduler) can overlap batch N's device→host drain with
         batch N+1's device work — a blocking drain otherwise idles the
         chip while the host fetches and unpacks.
         """
@@ -1135,7 +1135,7 @@ class ShardSearcher:
             _, _, _, _, total_blocks, n_real = handle
             # the composed plan's candidate stage is eager — every
             # block scored — and the whole rescore rode the one
-            # dispatch (the counter the fusion bench reconciles)
+            # dispatch
             jit_exec.note_impact_served(self.ctx.index_name, n_real,
                                         total_blocks * n_real, 0)
             jit_exec.note_rescore_fused(n_real)

@@ -92,7 +92,7 @@ class Plan:
     def cold(self) -> bool:
         """True when NO candidate was priced from a measured EWMA —
         the whole plan rests on static analysis (or no estimate at
-        all); bench's cost-error leg splits accuracy on this."""
+        all); ``jit_exec.note_planner_plan`` counts such plans apart."""
         return all(n.cold for n in self.nodes)
 
     @property

@@ -883,7 +883,7 @@ class ContinuousBatchScheduler:
 
     def stats(self) -> dict:
         """The ``_nodes/stats.scheduler`` document. ``reconciled`` is
-        the sample-time invariant the bench and chaos scenarios assert:
+        the sample-time invariant the benchmark and chaos scenarios assert:
         every submitted request is exactly one of queued / in-flight /
         delivered / declined / shed, and every launched batch is
         drained or in flight."""

@@ -1,8 +1,8 @@
 """Independent Lucene-BM25 oracle — written from the published formula.
 
-This module deliberately shares NO code with elasticsearch_tpu's ops or
-bench.py's CSR scorer: it consumes raw token-id sequences, builds its own
-statistics, and scores in float64 straight from the BM25Similarity
+This module deliberately shares NO code with elasticsearch_tpu's ops: it
+consumes raw token-id sequences, builds its own statistics, and scores
+in float64 straight from the BM25Similarity
 javadoc (Lucene 5.x, the version the reference embeds):
 
     idf(t)   = ln(1 + (N - df(t) + 0.5) / (df(t) + 0.5))

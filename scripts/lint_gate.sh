@@ -7,7 +7,7 @@
 #      test in tests/test_lane_graph.py enforces the same);
 #   3. a wall-clock budget assertion: the full-tree lint must finish in
 #      under 30 s on CPU, so the analyzer's own cost stays a tracked
-#      quantity (bench.py stamps the same number as `lint_wall_s`);
+#      quantity;
 #   4. a host-sync-family grep gate: `time.time()` is banned from the
 #      hot/measurement modules — durations measured on the wall clock
 #      go backwards under NTP steps and smear every latency figure.

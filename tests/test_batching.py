@@ -1,184 +1,24 @@
-"""AdaptiveBatcher — admission-queue micro-batching unit tests.
-
-Reference contrast: the reference dispatches each search on its own
-thread immediately (QueryPhase.java per-request model); the batcher is
-the TPU-native server shape (one fused program per formed batch). These
-tests pin the queueing semantics: full-batch immediate dispatch, deadline
-dispatch, error fan-out, ineligible fall-through, close draining.
-"""
+"""``pow2_bucket`` — the one bucketing rule every batched/jitted layer
+shares (the scheduler's pad rows, jit_exec's vmap batch axis, the mesh
+plane's k and batch buckets): the smallest power of two that holds n,
+clamped to a cap."""
 
 from __future__ import annotations
 
-import threading
-import time
+import pytest
 
-from elasticsearch_tpu.search.batching import AdaptiveBatcher
-
-
-def test_full_batch_dispatches_immediately():
-    calls = []
-
-    def run(reqs):
-        calls.append(list(reqs))
-        return [r * 10 for r in reqs]
-
-    b = AdaptiveBatcher(run, max_batch=4, max_wait_s=60.0)
-    futs = [b.submit(i) for i in range(4)]
-    # max_wait is a minute: only the full-batch trigger can have fired
-    assert [f.result(timeout=1.0) for f in futs] == [0, 10, 20, 30]
-    assert len(calls) == 1 and calls[0] == [0, 1, 2, 3]
+from elasticsearch_tpu.search.batching import pow2_bucket
 
 
-def test_deadline_dispatches_partial_batch():
-    def run(reqs):
-        return [r + 1 for r in reqs]
-
-    b = AdaptiveBatcher(run, max_batch=64, max_wait_s=0.01)
-    t0 = time.perf_counter()
-    out = b.execute(41)
-    assert out == 42
-    assert time.perf_counter() - t0 < 1.0
-
-
-def test_concurrent_clients_coalesce():
-    sizes = []
-
-    def run(reqs):
-        sizes.append(len(reqs))
-        time.sleep(0.005)                      # simulated device time
-        return list(reqs)
-
-    b = AdaptiveBatcher(run, max_batch=8, max_wait_s=0.02,
-                        pad_to_bucket=False)
-    results = {}
-    lock = threading.Lock()
-
-    def client(i):
-        r = b.execute(i)
-        with lock:
-            results[i] = r
-
-    threads = [threading.Thread(target=client, args=(i,)) for i in range(8)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    assert results == {i: i for i in range(8)}
-    # 8 clients in a 20ms window must land in far fewer than 8 batches
-    assert sum(sizes) == 8 and len(sizes) <= 3
-
-
-def test_ineligible_batch_returns_none_to_all():
-    b = AdaptiveBatcher(lambda reqs: None, max_batch=2, max_wait_s=0.01)
-    f1, f2 = b.submit("a"), b.submit("b")
-    assert f1.result(1.0) is None and f2.result(1.0) is None
-
-
-def test_error_fans_out_to_waiters():
-    def run(reqs):
-        raise RuntimeError("device fell over")
-
-    b = AdaptiveBatcher(run, max_batch=2, max_wait_s=0.01)
-    f1, f2 = b.submit(1), b.submit(2)
-    for f in (f1, f2):
-        try:
-            f.result(1.0)
-            raise AssertionError("expected the batch error")
-        except RuntimeError as e:
-            assert "device fell over" in str(e)
-
-
-def test_close_drains_queue_with_none():
-    b = AdaptiveBatcher(lambda reqs: list(reqs), max_batch=64,
-                        max_wait_s=60.0)
-    f = b.submit(7)
-    b.close()
-    assert f.result(1.0) is None
-    assert b.submit(8).result(1.0) is None     # post-close submit
-
-# ---- pipelined (launch/drain) mode --------------------------------------
-
-def test_pipelined_overlaps_drains():
-    """With drain_batch set, batch N+1 launches while batch N drains:
-    4 batches whose drains each sleep 50 ms must complete in ~1 drain
-    window, not 4 serialized ones."""
-    launched, lock = [], threading.Lock()
-
-    def launch(reqs):
-        with lock:
-            launched.append(list(reqs))
-        return list(reqs)                    # the handle is just the reqs
-
-    def drain(handle):
-        time.sleep(0.05)                     # simulated link RTT
-        return [r * 2 for r in handle]
-
-    b = AdaptiveBatcher(launch, drain_batch=drain, max_batch=2,
-                        max_wait_s=0.005, pad_to_bucket=False,
-                        max_in_flight=8)
-    futs = []
-    t0 = time.perf_counter()
-    for i in range(8):                       # forms 4 full batches of 2
-        futs.append(b.submit(i))
-    out = [f.result(2.0) for f in futs]
-    dt = time.perf_counter() - t0
-    assert out == [i * 2 for i in range(8)]
-    assert len(launched) == 4
-    # serialized drains would be >= 0.2 s; overlapped is ~0.05-0.1 s
-    assert dt < 0.15, f"drains serialized: {dt:.3f}s"
-    b.close()
-
-
-def test_pipelined_ineligible_and_error_paths():
-    def launch(reqs):
-        if any(r == "bad" for r in reqs):
-            return None                      # ineligible
-        if any(r == "boom" for r in reqs):
-            raise RuntimeError("launch failed")
-        return list(reqs)
-
-    def drain(handle):
-        if any(r == "drainboom" for r in handle):
-            raise RuntimeError("drain failed")
-        return list(handle)
-
-    b = AdaptiveBatcher(launch, drain_batch=drain, max_batch=1,
-                        max_wait_s=0.005, pad_to_bucket=False)
-    assert b.execute("bad") is None
-    try:
-        b.execute("boom")
-        raise AssertionError("expected launch error")
-    except RuntimeError as e:
-        assert "launch failed" in str(e)
-    try:
-        b.execute("drainboom")
-        raise AssertionError("expected drain error")
-    except RuntimeError as e:
-        assert "drain failed" in str(e)
-    assert b.execute("ok") == "ok"
-    b.close()
-
-
-def test_pipelined_in_flight_backpressure():
-    """max_in_flight bounds launched-but-undrained batches."""
-    peak, cur, lock = [0], [0], threading.Lock()
-
-    def launch(reqs):
-        with lock:
-            cur[0] += 1
-            peak[0] = max(peak[0], cur[0])
-        return list(reqs)
-
-    def drain(handle):
-        time.sleep(0.02)
-        with lock:
-            cur[0] -= 1
-        return list(handle)
-
-    b = AdaptiveBatcher(launch, drain_batch=drain, max_batch=1,
-                        max_wait_s=0.001, pad_to_bucket=False,
-                        max_in_flight=2)
-    futs = [b.submit(i) for i in range(10)]
-    assert [f.result(5.0) for f in futs] == list(range(10))
-    assert peak[0] <= 2, f"in-flight exceeded bound: {peak[0]}"
-    b.close()
+@pytest.mark.parametrize("n, cap, want", [
+    (0, None, 1),           # nothing still takes a row
+    (1, None, 1),
+    (2, None, 2),           # a power of two is its own bucket
+    (3, None, 4),
+    (64, None, 64),
+    (65, None, 128),
+    (5, 4, 4),              # a cap below the bucket clamps it
+    (5, 32, 8),             # a cap above it changes nothing
+])
+def test_pow2_bucket(n, cap, want):
+    assert pow2_bucket(n, cap) == want

@@ -4,6 +4,7 @@ and the compile-cache helper leaves an operator's directory alone."""
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import subprocess
@@ -11,6 +12,7 @@ import sys
 from pathlib import Path
 
 import jax
+import numpy as np
 import pytest
 
 REPO = Path(__file__).resolve().parents[1]
@@ -59,6 +61,37 @@ def test_without_a_tpu_it_stops_before_loading_data():
 def test_docs_shrink_only_in_a_rehearsal():
     out = _run("--docs", "6000", timeout=120)
     assert out.returncode == 2 and out.stdout.strip() == ""
+
+
+def test_corpus_and_queries_are_the_arrays_the_old_rig_made():
+    """``make_corpus`` / ``make_queries`` came from the old rig's script
+    when it was deleted: for a recorded seed they return, byte for byte,
+    what they returned there (SHA-256 taken on the parent commit), so the
+    smoke's oracle comparisons and ``tests/test_tpu_aot_compile.py``'s
+    shapes do not drift."""
+    rng = np.random.default_rng(7)
+    corpus = chip_smoke.make_corpus(rng, 4096, 2000, chip_smoke.MEAN_LEN,
+                                    chip_smoke.MAX_UNIQUE)
+    queries = chip_smoke.make_queries(rng, 16, chip_smoke.QUERY_TERMS,
+                                      corpus[3])
+    got = {name: (str(a.dtype), a.shape, hashlib.sha256(
+        np.ascontiguousarray(a).tobytes()).hexdigest())
+        for name, a in zip(("uterms", "utf", "lens", "df", "toks",
+                            "queries"), (*corpus, queries))}
+    assert got == {
+        "uterms": ("int32", (4096, 29), "cb99d6d5bbee3de21f2430482bdc54a3"
+                   "4337363be0f2d451115ba7e5159f93fa"),
+        "utf": ("float32", (4096, 29), "3d5992918ce18e67fb52e5db8bcf511f"
+                "cbb1b7420ca69745b9480d385484aa12"),
+        "lens": ("int32", (4096,), "cf8821b4944a0ec2d94e84639ff3044f"
+                 "fd9c83083c22f767dfea7e0fe165cb80"),
+        "df": ("int64", (2000,), "bfa6d7cc8039afdd76940bb7a75d6d42"
+               "24e2fb3e98d182bf1999992eb281242d"),
+        "toks": ("int32", (4096, 86), "0a81ed3bf845b1876240726554f14a40"
+                 "9ee12e76fa1a362b31e04a75964be9a1"),
+        "queries": ("int32", (16, 4), "5fce2f7db9efef205dbd33e8e77f9a37"
+                    "7530809db7f29754496886445d5b9818"),
+    }
 
 
 @pytest.mark.parametrize("count", [1, 4])

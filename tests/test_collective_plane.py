@@ -18,6 +18,13 @@ DFS = "dfs_query_then_fetch"
 def nodes(tmp_path_factory):
     base = tmp_path_factory.mktemp("cp")
     n = Node({}, data_path=base / "n").start()
+    # tests of this module create indices minutes after start-up: on a
+    # volume over the 85% low watermark the disk-threshold decider would
+    # leave their shards UNASSIGNED once ClusterInfoService has sampled
+    # the filesystem ("primary shard is not active"). Only a full disk
+    # refuses here; the program's default is untouched.
+    n.update_cluster_settings({"transient": {
+        "cluster.routing.allocation.disk.watermark.low": 1.0}})
     rng = np.random.default_rng(5)
     for name, plane in (("on", True), ("off", False)):
         n.indices_service.create_index(name, {

@@ -1,5 +1,5 @@
 """Batched query-path tests: B same-plan requests must execute as one
-vmapped program per segment (jit_exec.run_segment_batch) with results
+vmapped program over the reader (jit_exec.run_reader_batch) with results
 identical to the per-request path, and the bulk columnar ingest
 (Segment.from_packed_text + Engine.install_segment) must be search-
 equivalent to per-document indexing."""

@@ -7,6 +7,7 @@ round-trip and the CLI satellites (``--diff``, ``--emit-lane-graph``,
 
 from __future__ import annotations
 
+import ast
 import json
 import subprocess
 import sys
@@ -133,6 +134,79 @@ def test_unregistered_reason_is_rejected_at_runtime():
     with pytest.raises(AssertionError):
         jit_exec.note_knn_fallback("not-a-registered-reason")
     jit_exec.note_knn_fallback("mixed-shapes")   # registered: fine
+
+
+# ---------------------------------------------------------------------------
+# a program lane cannot outlive its last caller
+# ---------------------------------------------------------------------------
+
+_COMPILE_SEAMS = {"_get_compiled": 2, "observed_compile": 0}
+
+
+def _callee(call: ast.Call):
+    fn = call.func
+    return fn.id if isinstance(fn, ast.Name) else \
+        fn.attr if isinstance(fn, ast.Attribute) else None
+
+
+def _lane_literal(call: ast.Call):
+    """The lane a compile site names: the ``lane=`` keyword or the
+    seam's positional lane argument, as a string literal (a forwarded
+    parameter, as inside ``_get_compiled`` itself, names no lane)."""
+    pos = _COMPILE_SEAMS[_callee(call)]
+    args = [kw.value for kw in call.keywords if kw.arg == "lane"]
+    if not args and len(call.args) > pos:
+        args = [call.args[pos]]
+    for a in args:
+        if isinstance(a, ast.Constant) and isinstance(a.value, str):
+            return a.value
+    return None
+
+
+@pytest.fixture(scope="module")
+def lane_builders():
+    """→ ({lane: {(path, outermost def name, first line, last line)}},
+    {name: [(path, line)]} for every name or attribute the package's
+    code reads — a call, or a function handed on as a value)."""
+    builders: dict = {}
+    uses: dict = {}
+    for path in sorted((REPO / "elasticsearch_tpu").rglob("*.py")):
+        tree = ast.parse(path.read_text(encoding="utf-8"))
+        tops = [n for n in tree.body
+                if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef))]
+        for cls in (n for n in tree.body if isinstance(n, ast.ClassDef)):
+            tops += [n for n in cls.body if isinstance(
+                n, (ast.FunctionDef, ast.AsyncFunctionDef))]
+        for node in ast.walk(tree):
+            if isinstance(node, (ast.Name, ast.Attribute)):
+                name = node.id if isinstance(node, ast.Name) else node.attr
+                uses.setdefault(name, []).append((path, node.lineno))
+        for fn in tops:
+            for node in ast.walk(fn):
+                if isinstance(node, ast.Call) and \
+                        _callee(node) in _COMPILE_SEAMS and \
+                        (lane := _lane_literal(node)) is not None:
+                    builders.setdefault(lane, set()).add(
+                        (path, fn.name, fn.lineno, fn.end_lineno))
+    return builders, uses
+
+
+@pytest.mark.parametrize("lane", lanes.PROGRAM_LANES)
+def test_every_program_lane_is_reached_from_the_package(lane, lane_builders):
+    """Every registered program lane has a compile site, and a function
+    that holds one is used (called, or handed on by name or attribute)
+    in the package outside its own body: a program builder whose last
+    caller went goes with it, and with its entry in ``PROGRAM_LANES``."""
+    builders, uses = lane_builders
+    assert builders.get(lane), f"no compile site names lane {lane!r}"
+    reached = [
+        (name, str(at.relative_to(REPO)), line)
+        for path, name, lo, hi in builders[lane]
+        for at, line in uses.get(name, ())
+        if not (at == path and lo <= line <= hi)]
+    assert reached, (
+        f"lane {lane!r}: nothing in elasticsearch_tpu/ uses "
+        f"{sorted(b[1] for b in builders[lane])}")
 
 
 # ---------------------------------------------------------------------------

@@ -164,6 +164,38 @@ class TestBM25OnePass:
             assert nmatch.max() >= 2 and scores.max() > 0
 
 
+def test_bm25_match_sums_a_repeated_query_term(rng):
+    """A query that names one term twice scores as the query that names
+    it once at twice the idf (Lucene sums a repeated clause), and both
+    agree with the float64 formula; nmatch counts the repeat."""
+    vocab = 40
+    _, _, uterms, utf, doc_len = make_corpus(rng, n_docs=192, vocab=vocab,
+                                             max_len=24)
+    t, u = (int(x) for x in rng.choice(vocab, size=2, replace=False))
+    idf_t, idf_u = np.float32(2.75), np.float32(0.625)
+    k1, b, avgdl = 1.2, 0.75, 17.5
+
+    def run(qtids, qidf):
+        qtids = np.array(qtids, np.int32)
+        qidf = np.array(qidf, np.float32)
+        ones = np.ones(len(qtids), np.float32)
+        got = lexical.bm25_match(
+            jnp.array(uterms), jnp.array(utf), jnp.array(doc_len),
+            jnp.array(qtids), jnp.array(qidf), jnp.array(ones), k1, b, avgdl)
+        ref = np_bm25_slots(uterms, utf, doc_len, qtids, qidf, ones,
+                            k1, b, avgdl)
+        return [np.asarray(a) for a in got], ref
+
+    (twice_s, twice_n), (ref_s, ref_n) = run([t, t, u], [idf_t, idf_t, idf_u])
+    (once_s, once_n), _ = run([t, u], [2 * idf_t, idf_u])
+    holds_t = (uterms == t).any(axis=1)
+    assert holds_t.any() and twice_s[holds_t].min() > 0
+    np.testing.assert_allclose(twice_s, ref_s, rtol=1e-5, atol=0)
+    np.testing.assert_array_equal(twice_n, ref_n)
+    np.testing.assert_allclose(twice_s, once_s, rtol=1e-6, atol=0)
+    np.testing.assert_array_equal(twice_n, once_n + holds_t)
+
+
 class TestPhrase:
     def test_exact_phrase(self):
         # doc0: "a b c", doc1: "b a b c", doc2: "a c b"

@@ -156,8 +156,7 @@ def test_cost_analysis_present_for_all_four_lanes(node):
 
     seen = _lanes_seen()
     # the four serving lanes' program classes all produced records
-    assert seen & {"segment", "segment-batch", "reader-batch", "mesh"}, \
-        seen
+    assert seen & {"segment", "reader-batch", "mesh"}, seen
     assert seen & {"impact-eager", "impact-pruned"}, seen
     assert "knn" in seen, seen
     assert "percolate" in seen, seen
@@ -349,7 +348,7 @@ def test_occupancy_reconciles_with_scheduler_n_real(node):
             agg = rollup.setdefault(lane, {"requests": 0, "rows": 0})
             agg["requests"] += ent["requests"]
             agg["rows"] += ent["rows"]
-    batch_lanes = {"reader-batch", "segment-batch", "streamed"}
+    batch_lanes = {"reader-batch", "streamed"}
     got_reqs = sum(rollup.get(ln, {}).get("requests", 0)
                    for ln in batch_lanes)
     got_rows = sum(rollup.get(ln, {}).get("rows", 0)

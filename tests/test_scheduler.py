@@ -153,7 +153,7 @@ def test_padded_batch_single_delivery_and_exact_counts():
         # with the FIRST request replicated — never another queued one
         assert len(launches) == 2
         reqs2, n_real2 = launches[1]
-        assert n_real2 == 3 and len(reqs2) == 4 and reqs2[3] == reqs2[0]
+        assert n_real2 == 3 and reqs2 == [2, 3, 4, 2]
         js1 = jit_exec.cache_stats()
         assert js1["scheduler_requests_admitted"] - \
             js0["scheduler_requests_admitted"] == 4
@@ -188,23 +188,6 @@ def test_n_real_excludes_pad_rows_from_lane_stats(node):
     assert len(out) >= 3
     js1 = jit_exec.cache_stats()
     assert js1["knn_admissions"] - js0["knn_admissions"] == 3
-
-
-def test_adaptive_batcher_pads_with_first_request_only():
-    from elasticsearch_tpu.search.batching import AdaptiveBatcher
-    seen: list = []
-
-    def run(reqs, n_real=None):
-        seen.append((list(reqs), n_real))
-        return [r + 1 for r in reqs]
-
-    b = AdaptiveBatcher(run, max_batch=8, max_wait_s=0.02)
-    futs = [b.submit(i) for i in (7, 8, 9)]
-    assert [f.result(2.0) for f in futs] == [8, 9, 10]
-    (reqs, n_real), = seen
-    assert n_real == 3
-    assert reqs == [7, 8, 9, 7]           # first request replicated
-    b.close()
 
 
 # ---------------------------------------------------------------------------
@@ -527,6 +510,66 @@ def test_a_non_full_batch_is_held_behind_a_staged_one(case):
         assert [w.future.result(WAIT_S) for w in ws] \
             == [w.req * 10 for w in ws]
         _until(sched, "delivered", len(ws))
+    finally:
+        dev.release()
+        sched.close()
+
+
+@pytest.mark.parametrize("fault", [
+    "launch-returns-none", "launch-raises", "drain-returns-none",
+    "drain-raises"])
+def test_launch_and_drain_outcomes_reach_every_waiter(fault):
+    """A batch whose launch or drain declines (None) or raises sends
+    EVERY waiter back to its serial path (:data:`DECLINED` — the error
+    stays with the worker: "serial retry owns it"), leaves the window,
+    and the book reconciles; the scheduler then serves the next batch."""
+    from elasticsearch_tpu.search.scheduler import DECLINED
+    dev = _GatedDevice()
+    armed = [True]
+
+    def launch(reqs, n_real=None):
+        handle = dev.launch(reqs, n_real)
+        if armed[0] and fault == "launch-raises":
+            raise RuntimeError("launch failed")
+        return None if armed[0] and fault == "launch-returns-none" \
+            else handle
+
+    def drain(handle):
+        out = dev.drain(handle)
+        if armed[0] and fault == "drain-returns-none":
+            return None
+        if armed[0] and fault == "drain-raises":
+            raise RuntimeError("drain failed")
+        return out
+
+    sched = ContinuousBatchScheduler(node_id=None, max_batch=4,
+                                     max_in_flight=1)
+    try:
+        # the window of one: the first batch blocks in its drain, the
+        # next three rows queue behind it and leave as ONE batch
+        # (a queue keeps its creator's callables, so the healthy first
+        # request has a key of its own)
+        first = sched.submit("plane", ("outcome", fault, "first"), 1,
+                             dev.launch, dev.drain)
+        dev.wait_launches(1)
+        ws = [sched.submit("plane", ("outcome", fault), r, launch, drain)
+              for r in (2, 3, 4)]
+        _until(sched, "queue_depth", 3)
+        dev.release()
+        assert first.future.result(WAIT_S) == 10
+        assert [w.future.result(WAIT_S) for w in ws] == [DECLINED] * 3
+        assert dev.launched == [[1], [2, 3, 4]]
+        st = _until(sched, "declined", 3)
+        assert st["batches_in_flight"] == 0 and st["queue_depth"] == 0
+        assert st["in_flight_requests"] == 0 and st["delivered"] == 1
+        assert st["batches_launched"] == st["batches_drained"] == 2
+        assert st["shed"] == 0 and st["batches_abandoned"] == 0
+        # the fault was the batch's own: the next one is served
+        armed[0] = False
+        assert sched.execute("plane", ("outcome", fault), 5, launch,
+                             drain) == 50
+        st = _until(sched, "delivered", 2)
+        assert st["batches_in_flight"] == 0 and st["declined"] == 3
     finally:
         dev.release()
         sched.close()
