@@ -878,8 +878,12 @@ class SearchActions:
     def _handle_shard_msearch_inner(self, request: dict) -> dict:
         """Shard-side _msearch: B request bodies against one shard in ONE
         batched device program when they share a plan
-        (ShardSearcher.query_phase_batch — the TPU-native multi-search),
-        per-request execution otherwise. → {"payloads": [per body]}."""
+        (ShardSearcher.query_phase_batch — the TPU-native multi-search;
+        ``match`` queries of unequal lengths do, padded to the batch's
+        term bucket), per-request execution otherwise (queries of
+        different structure, ineligible requests): the counters
+        ``msearch_items_batched`` / ``msearch_items_serial`` say which.
+        → {"payloads": [per body]}."""
         name, shard = request["index"], request["shard"]
         bodies = request["bodies"]
         svc = self.node.indices_service.index(name)
@@ -903,6 +907,8 @@ class SearchActions:
                 if valid else []
         except Exception:                    # noqa: BLE001 — isolate items
             batch = None
+        from elasticsearch_tpu.search import jit_exec
+        jit_exec.note_msearch_items(len(valid), batched=batch is not None)
         if batch is not None:
             for (i, _), res in zip(valid, batch):
                 results[i] = res

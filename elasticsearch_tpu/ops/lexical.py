@@ -15,8 +15,21 @@ segment that reads ``uterms``, ``utf`` and ``doc_len`` once and writes [N];
 no [N, U] temporary reaches HBM, and a further query term costs a compare,
 a select and an add a slot, not another read of the columns. On a v5e the
 fusion takes 2.47 ms for a [2^20, 224] segment (1.88 GB) at 2 to 12 terms
-(PERF.md section 6, PR 27). ``classic_match`` and ``lm_dirichlet_match``
-still make one pass per term.
+(PERF.md section 6, PR 27).
+
+That is what lets the plan compiler pad: ``execute._res_MatchQuery`` fills
+a match's ``qtids`` / ``qidf`` up to a term bucket (``batching
+.term_bucket``: the batch's widest, or the query's own) with absent terms
+— id -1, idf 0.0 — AFTER the real ones. A pad compares unequal to every slot (-2 is no term
+id and no slot pad), adds 0.0 to ``w`` and 0 to ``cnt``, so op by op
+scores and nmatch are the unpadded query's bit for bit (two compiled
+programs of different widths may each order the sum along U their own
+way: a score's last bit), and queries of unequal lengths share one
+compiled program and one batch. Under ``vmap`` at
+B = 64 the pass is no longer bound by the column read but by the
+compares (64 x T a slot): what a pad costs there is in PERF.md section 6,
+PR 33. ``classic_match`` and ``lm_dirichlet_match`` still make one pass
+per term, so their lists are not padded.
 """
 
 from __future__ import annotations

@@ -46,6 +46,7 @@ from elasticsearch_tpu.ops import (
     vector as vector_ops, functionscore as fs_ops)
 from elasticsearch_tpu.ops.similarity import BM25Params, idf as bm25_idf
 from elasticsearch_tpu.search import query_dsl as q
+from elasticsearch_tpu.search.batching import term_bucket
 from elasticsearch_tpu.search.scripts import ScriptContext, compile_script
 
 
@@ -59,7 +60,8 @@ class ConstTable:
     modifiers, slop windows...) into the signature.
     """
 
-    __slots__ = ("values", "sig", "positions_needed", "vectors_needed")
+    __slots__ = ("values", "sig", "positions_needed", "vectors_needed",
+                 "match_terms_real", "match_terms_padded")
 
     def __init__(self):
         self.values: list[np.ndarray] = []
@@ -74,6 +76,10 @@ class ConstTable:
         # tree-shaking contract as positions_needed (the [N] bool exists
         # arrays are always traced; only vecs are lazy/shaken)
         self.vectors_needed: set = set()
+        # query terms the plan's BM25 match nodes score, and the absent
+        # terms that padded their lists to the term bucket
+        self.match_terms_real = 0
+        self.match_terms_padded = 0
 
     def add(self, v, dtype=None) -> int:
         arr = np.asarray(v, dtype=dtype)
@@ -235,29 +241,63 @@ def _edit_distance_le(a: str, b: str, k: int) -> bool:
     return prev[len(b)] <= k
 
 
+def _search_analyzer(ms, field: str, override: str | None):
+    if override:
+        return ms.analysis.get(override)
+    fm = ms.field_mapper(field)
+    if fm is not None and getattr(fm, "kind", None) == "text":
+        return fm.search_analyzer
+    return ms.analysis.get("standard")
+
+
+def match_term_floor(queries: list, mapper_service) -> int:
+    """The widest term bucket among a batch's ``match`` nodes: one walk
+    of the parsed queries, analysis only (no segment, no statistics).
+    Handed to every query's :class:`SegmentResolver`, it makes a batch of
+    unequal lengths pad to ONE width and share one compiled plan. A
+    ``match`` the walk does not reach (a rewrite made while resolving,
+    as ``multi_match`` does) keeps its own bucket: the batch may then
+    decline, never answer wrongly."""
+    floor = 1
+    stack = list(queries)
+    while stack:
+        node = stack.pop()
+        if isinstance(node, q.MatchQuery):
+            analyzer = _search_analyzer(mapper_service, node.field,
+                                        node.analyzer)
+            floor = max(floor, term_bucket(
+                len(analyzer.analyze(node.text))))
+            continue
+        for v in vars(node).values():
+            if isinstance(v, (list, tuple)):
+                stack.extend(x for x in v
+                             if hasattr(x, "__dataclass_fields__"))
+            elif hasattr(v, "__dataclass_fields__"):
+                stack.append(v)
+    return floor
+
+
 class SegmentResolver:
     """Host-side "createWeight": resolves query ASTs against one segment's
     dictionaries into emit closures + a ConstTable."""
 
     def __init__(self, seg: DeviceSegment, ctx: ExecutionContext,
-                 ct: ConstTable | None = None):
+                 ct: ConstTable | None = None, term_floor: int = 1):
         self.seg = seg
         self.ctx = ctx
         self.ct = ct if ct is not None else ConstTable()
         self.n = seg.padded_docs
         self.c = self.ct.add
         self.sig = self.ct.static
+        # the least width a BM25 match pads its term list to: a batch
+        # hands every query its widest bucket (match_term_floor), so
+        # queries of unequal lengths share one plan signature
+        self.term_floor = term_floor
 
     # ------------------------------------------------------------------ util
 
     def _analyzer_for(self, field: str, override: str | None):
-        ms = self.ctx.mapper_service
-        if override:
-            return ms.analysis.get(override)
-        fm = ms.field_mapper(field)
-        if fm is not None and getattr(fm, "kind", None) == "text":
-            return fm.search_analyzer
-        return ms.analysis.get("standard")
+        return _search_analyzer(self.ctx.mapper_service, field, override)
 
     def _similarity_for(self, field: str) -> str:
         """Per-field similarity module (ref: SimilarityModule — BM25 /
@@ -436,17 +476,16 @@ class SegmentResolver:
             required = _resolve_msm(query.minimum_should_match, len(terms))
         else:
             required = 1
-        n_terms = len(tids)
         similarity = self._similarity_for(field)
         if similarity != "bm25":
+            # classic_match and lm_dirichlet_match make a pass over the
+            # columns PER TERM, so a pad would cost a read: these lists
+            # are not padded and the signature keeps the exact count.
             # reuse the (df, doc_count) per term already gathered by
             # _match_terms — no second stats pass on the planning path
             stats = [self._term_stats(field, t) for t in terms]
             return self._match_alt_similarity(query, field, terms, tids,
                                               similarity, required, stats)
-        r_tids = self.c(tids, np.int32)
-        r_idfs = self.c(idfs, np.float32)
-        r_avgdl = self.c(self._avgdl(field), np.float32)
         # required == 1 (the default OR semantics): a doc matches iff any
         # query term hits, and every present term has idf > 0, so
         # mask ≡ scores > 0 — the nmatch accumulation becomes dead code XLA
@@ -465,8 +504,29 @@ class SegmentResolver:
         all_idf_pos = all(
             idf > 0 or tid < 0 or col_df[tid] == 0
             for tid, idf in zip(tids, idfs))
-        msm1 = required == 1 and all_idf_pos
+        # decided by the query's FORM, not by the number `required`
+        # resolves to: a one-term `and` counts matches like its longer
+        # batch mates, so the signature does not carry the term count
+        counted = query.operator == "and" or \
+            query.minimum_should_match not in (None, 1)
+        msm1 = not counted and all_idf_pos
         self.sig("msm1" if msm1 else "msm")
+        # the plan does not carry the exact number of terms either: the
+        # lists are padded to a term bucket — the batch's widest, or the
+        # query's own — with absent terms (id -1, idf 0.0). bm25_match
+        # maps a negative id to a value no slot holds, so a pad adds 0.0
+        # to a slot's weight and 0 to its count after the real terms:
+        # the arithmetic is the unpadded query's (nmatch, mask and
+        # totals exactly; a score to the last bit a program of another
+        # width may round its sum along U to), at a compare, a select
+        # and an add a slot inside the one pass
+        n_terms = max(term_bucket(len(tids)), self.term_floor)
+        pad = n_terms - len(tids)
+        self.ct.match_terms_real += len(tids)
+        self.ct.match_terms_padded += pad
+        r_tids = self.c(tids + [-1] * pad, np.int32)
+        r_idfs = self.c(idfs + [0.0] * pad, np.float32)
+        r_avgdl = self.c(self._avgdl(field), np.float32)
         r_req = None if msm1 else self.c(required, np.int32)
         r_boost = self.c(query.boost, np.float32)
         p = self.ctx.bm25
@@ -1049,7 +1109,8 @@ class SegmentResolver:
             return self._zeros()
         score_mode = query.score_mode
         self.sig("nested", path, score_mode)
-        inner = SegmentResolver(block.child, self.ctx, self.ct).resolve(
+        inner = SegmentResolver(block.child, self.ctx, self.ct,
+                                self.term_floor).resolve(
             query.query or q.MatchAllQuery())
         r_boost = self.c(query.boost, np.float32)
 

@@ -46,7 +46,8 @@ from elasticsearch_tpu.ops import blockmax as blockmax_ops
 from elasticsearch_tpu.ops import topk as topk_ops
 from elasticsearch_tpu.search import lanes
 from elasticsearch_tpu.search.execute import (
-    ConstTable, EmitCtx, ExecutionContext, SegmentResolver)
+    ConstTable, EmitCtx, ExecutionContext, SegmentResolver,
+    match_term_floor)
 
 _CACHE_CAP = 512
 _cache: OrderedDict[tuple, "jax.stages.Wrapped"] = OrderedDict()
@@ -729,10 +730,12 @@ def layout_key(seg: DeviceSegment) -> tuple:
 # ---------------------------------------------------------------------------
 
 def _plan(seg: DeviceSegment, ctx: ExecutionContext, query, post_filter,
-          flags):
-    """Host resolve → (ConstTable, emit_q, emit_pf mask-emit, flag refs)."""
+          flags, term_floor: int = 1):
+    """Host resolve → (ConstTable, emit_q, emit_pf mask-emit, flag refs).
+    ``term_floor``: the batch's widest ``match`` term bucket (a lone
+    query pads to its own)."""
     ct = ConstTable()
-    resolver = SegmentResolver(seg, ctx, ct)
+    resolver = SegmentResolver(seg, ctx, ct, term_floor)
     emit_q = resolver.resolve(query)
     emit_pf = resolver.resolve_mask(post_filter) \
         if post_filter is not None else None
@@ -867,11 +870,15 @@ def _plan_segment_batch(seg: DeviceSegment, ctx: ExecutionContext,
     every host→device transfer pays a fixed per-call dispatch cost, so 2
     packed buffers beat N small ones; the program unpacks by static slicing
     (free under XLA). The spec layout is a pure function of the plan
-    signature, so cached programs agree on it. Returns None when the
-    queries do not share one plan signature or the shared plan has no
-    dynamic constants (callers fall back to per-query execution)."""
+    signature, so cached programs agree on it. ``match`` queries of
+    unequal lengths share a signature: every query pads its term lists to
+    the batch's widest term bucket, found by one walk of the parsed
+    queries before any is planned. Returns None when the queries do not
+    share one plan signature or the shared plan has no dynamic constants
+    (callers fall back to per-query execution)."""
     if not queries:
         return None
+    term_floor = match_term_floor(queries, ctx.mapper_service)
     flags = {
         "min_score": False, "_min_score": 0.0,
         "search_after": False, "_sa_score": 0.0, "_sa_doc": -1,
@@ -883,8 +890,10 @@ def _plan_segment_batch(seg: DeviceSegment, ctx: ExecutionContext,
     pos_for: frozenset = frozenset()
     vecs: frozenset = frozenset()
     consts_rows: list[list[np.ndarray]] = []
+    match_terms: list[tuple[int, int]] = []     # per query: (real, pads)
     for query in queries:
-        ct, emit_q, _, refs = _plan(seg, ctx, query, None, flags)
+        ct, emit_q, _, refs = _plan(seg, ctx, query, None, flags,
+                                    term_floor)
         if sig0 is None:
             sig0, emit0, refs0 = ct.signature(), emit_q, refs
             pos_for = frozenset(ct.positions_needed)
@@ -892,6 +901,7 @@ def _plan_segment_batch(seg: DeviceSegment, ctx: ExecutionContext,
         elif ct.signature() != sig0:
             return None
         consts_rows.append(ct.values)
+        match_terms.append((ct.match_terms_real, ct.match_terms_padded))
 
     b = len(queries)
     # pad the batch axis to the next power of two (repeating the last
@@ -926,7 +936,7 @@ def _plan_segment_batch(seg: DeviceSegment, ctx: ExecutionContext,
         "key": (sig0, layout_key(seg), pos_for, vecs,
                 float(ctx.bm25.k1), float(ctx.bm25.b), k_static, b_pad,
                 tuple(specs)),
-        "k": k_static,
+        "k": k_static, "match_terms": match_terms,
     }
 
 
@@ -1018,6 +1028,8 @@ def run_reader_batch(segments: list, ctx: ExecutionContext, queries: list,
                            n_real if n_real is not None else b, b_pad)):
         device_fault_point("dispatch")
         out = fn(flats, packeds)
+    note_match_terms(plans[0]["match_terms"][:b if n_real is None
+                                             else n_real])
     if b_pad != b:
         out = out[:b] if pack else {name: v[:b] for name, v in out.items()}
     return out
@@ -2173,6 +2185,24 @@ def note_knn_served(index_name: str | None, n_requests: int,
             bucket["admissions"] += n_requests
             bucket["fusion_dispatches"] += fused
             bucket["maxsim_dispatches"] += maxsim
+
+
+def note_match_terms(rows: list) -> None:
+    """One reader-batch dispatch whose real rows scored ``rows``: per
+    query (real BM25 ``match`` terms, pad terms) — the term bucket's
+    price: a pad term is compared with every slot like a real one."""
+    with _cache_lock:
+        _bump("match_terms_real", sum(r for r, _ in rows))
+        _bump("match_terms_padded", sum(p for _, p in rows))
+
+
+def note_msearch_items(n_items: int, batched: bool) -> None:
+    """One shard-side ``_msearch`` of ``n_items``: answered by
+    ``query_phase_batch`` in one dispatch, or fallen to the one-by-one
+    path."""
+    with _cache_lock:
+        _bump("msearch_items_batched" if batched
+              else "msearch_items_serial", int(n_items))
 
 
 def note_knn_rows(real: int, padded: int) -> None:

@@ -60,6 +60,15 @@ JIT_COUNTERS = {
     "knn_rows_padded": "no-op rows that padded knn dispatches to their "
                        "power-of-two batch bucket (scored, never "
                        "delivered)",
+    "match_terms_real": "BM25 match query terms the real rows of "
+                        "reader-batch dispatches scored",
+    "match_terms_padded": "absent terms that padded those rows' term "
+                          "lists to the batch's term bucket (compared "
+                          "with every slot, never scoring)",
+    "msearch_items_batched": "shard-side _msearch items answered by one "
+                             "batched dispatch (query_phase_batch)",
+    "msearch_items_serial": "shard-side _msearch items that fell to the "
+                            "one-by-one query phase",
     "fusion_dispatches": "in-program hybrid fusion dispatches",
     "maxsim_dispatches": "fused MaxSim dispatches over rank_vectors",
     "rescore_fused_dispatches": "impact→rescore plans composed into one "

@@ -522,8 +522,10 @@ class ShardSearcher:
         primary high-throughput entry. Returns None when the batch is
         ineligible (aggs / sort-by-field / post_filter / min_score /
         search_after / suggest / partial-results modes) or the queries
-        don't share one compiled plan — the caller then falls back to
-        per-request :meth:`query_phase`.
+        differ in structure (a ``match`` beside a ``range``) — the caller
+        then falls back to per-request :meth:`query_phase`. ``match``
+        queries of unequal lengths DO share one compiled plan: their term
+        lists pad to the batch's widest term bucket.
 
         Implemented as launch + drain so a pipelined caller (the
         ContinuousBatchScheduler) can overlap batch N's device→host drain with

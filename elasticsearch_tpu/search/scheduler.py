@@ -71,7 +71,7 @@ from concurrent.futures import TimeoutError as FutTimeout
 from contextlib import nullcontext
 
 from elasticsearch_tpu.common.threadpool import EsRejectedExecutionError
-from elasticsearch_tpu.search.batching import pow2_bucket
+from elasticsearch_tpu.search.batching import pow2_bucket, term_bucket
 
 
 class SchedulerRejectedError(EsRejectedExecutionError):
@@ -134,10 +134,12 @@ def query_shape(q_node) -> tuple:
                    getattr(q_node, "field", None)]
     text = getattr(q_node, "text", None)
     if isinstance(text, str):
-        # the compiled plans pad operand lists to pow2 buckets, so the
-        # fingerprint buckets the same way — "a b c" and "x y z w" share
-        # a program family, "a b" does not
-        parts.append(pow2_bucket(max(len(text.split()), 1)))
+        # the compiled plans pad a match's term lists to a term bucket
+        # (execute._res_MatchQuery), so the fingerprint buckets the
+        # same way — "a b c" and "x y z w" share a program, "a b" meets
+        # them only in a batch that holds a longer query (the batch
+        # pads to its widest bucket)
+        parts.append(term_bucket(max(len(text.split()), 1)))
     values = getattr(q_node, "values", None)
     if isinstance(values, (list, tuple)):
         parts.append(pow2_bucket(max(len(values), 1)))

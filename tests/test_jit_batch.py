@@ -127,6 +127,123 @@ class TestQueryPhaseBatch:
         assert st2["fallbacks"] == 0
 
 
+#: lengths 1, 2, 3, 5 and 12 in one batch: the widest term bucket is 12
+MIXED_TEXTS = [
+    "alpha",
+    "beta word3",
+    "word1 word2 alpha",
+    "word0 word4 beta word5 word9",
+    "alpha beta word0 word1 word2 word3 word4 word5 word6 word7 word8 "
+    "word10",
+]
+MIXED_LENGTHS = [len(t.split()) for t in MIXED_TEXTS]
+
+
+def _mixed_bodies(form: str) -> list:
+    def match(text):
+        if form == "or":
+            return {"match": {"t": text}}
+        if form == "and":
+            return {"match": {"t": {"query": text, "operator": "and"}}}
+        if form == "msm":
+            return {"match": {"t": {"query": text,
+                                    "minimum_should_match": "50%"}}}
+        assert form == "bool"
+        return {"bool": {"should": [
+            {"match": {"t": text}},
+            {"match": {"t": {"query": text.split()[0] + " word6",
+                             "boost": 0.5}}}]}}
+    return [{"query": match(t), "size": 15} for t in MIXED_TEXTS]
+
+
+class TestMixedLengthBatch:
+    """``match`` queries of unequal lengths share ONE compiled plan: the
+    term lists pad to the batch's widest term bucket (PR 33)."""
+
+    @pytest.mark.parametrize("form", ["or", "and", "msm", "bool"])
+    def test_mixed_lengths_equal_per_query(self, node, form):
+        assert MIXED_LENGTHS == [1, 2, 3, 5, 12]
+        _mk(node, "idx", 120)
+        s = _searcher(node, "idx")
+        reqs = [parse_search_request(b) for b in _mixed_bodies(form)]
+        jit_exec.clear_cache()
+        before = jit_exec.cache_stats()
+        batch = s.query_phase_batch(reqs)
+        assert batch is not None
+        after = jit_exec.cache_stats()
+        assert after["misses"] - before["misses"] == 1    # ONE program
+        assert after["fallbacks"] == before["fallbacks"]
+        some = 0
+        for req, got in zip(reqs, batch):
+            ref = s.query_phase(req)
+            assert got.total == ref.total
+            some += ref.total
+            np.testing.assert_array_equal(got.doc_ids, ref.doc_ids)
+            np.testing.assert_allclose(got.scores, ref.scores, rtol=1e-6)
+        assert some > 0
+
+    def test_three_and_four_terms_one_program(self, node):
+        _mk(node, "idx", 60)
+        s = _searcher(node, "idx")
+        jit_exec.clear_cache()
+        before = jit_exec.cache_stats()
+        for text in ("alpha word1 word2", "beta word3 word4 word5",
+                     "word0 word2 beta"):
+            assert s.query_phase_batch([parse_search_request(
+                {"query": {"match": {"t": text}}, "size": 5})]) is not None
+        after = jit_exec.cache_stats()
+        assert after["misses"] - before["misses"] == 1
+        assert after["hits"] - before["hits"] == 2
+        # five terms take the next bucket: another program
+        s.query_phase_batch([parse_search_request(
+            {"query": {"match": {"t": "alpha beta word1 word2 word3"}},
+             "size": 5})])
+        assert jit_exec.cache_stats()["misses"] - before["misses"] == 2
+
+    def test_counters_of_a_hand_worked_batch(self, node):
+        """Lengths 1, 2, 3, 5, 12 pad to 12 each: 23 real query-terms,
+        5 × 12 − 23 = 37 pads — counted once a dispatch (not once a
+        segment), over the real rows only (the batch axis pads 5 → 8)."""
+        _mk(node, "idx", 60)
+        s = _searcher(node, "idx")
+        reqs = [parse_search_request(b) for b in _mixed_bodies("or")]
+        before = jit_exec.cache_stats()
+        assert s.query_phase_batch(reqs) is not None
+        after = jit_exec.cache_stats()
+        assert after["match_terms_real"] - before["match_terms_real"] \
+            == sum(MIXED_LENGTHS) == 23
+        assert after["match_terms_padded"] \
+            - before["match_terms_padded"] == 5 * 12 - 23
+        # a lone query pads to its own bucket: 3 → 4
+        before = after
+        s.query_phase_batch(reqs[2:3])
+        after = jit_exec.cache_stats()
+        assert after["match_terms_real"] - before["match_terms_real"] == 3
+        assert after["match_terms_padded"] \
+            - before["match_terms_padded"] == 1
+
+    def test_alt_similarity_keeps_the_exact_count(self, node):
+        """classic_match makes a pass over the columns per term: its
+        lists are not padded, so unequal lengths still decline."""
+        node.indices_service.create_index("cl", {
+            "settings": {"number_of_shards": 1, "number_of_replicas": 0},
+            "mappings": {"properties": {
+                "t": {"type": "text", "similarity": "classic"}}}})
+        for i in range(30):
+            node.index_doc("cl", str(i), {"t": f"alpha beta word{i % 7}"})
+        node.broadcast_actions.refresh("cl")
+        s = _searcher(node, "cl")
+        reqs = [parse_search_request({"query": {"match": {"t": t}}})
+                for t in ("alpha", "alpha beta word1")]
+        before = jit_exec.cache_stats()
+        assert s.query_phase_batch(reqs) is None
+        after = jit_exec.cache_stats()
+        assert after["match_terms_padded"] == before["match_terms_padded"]
+        same = [parse_search_request({"query": {"match": {"t": t}}})
+                for t in ("alpha beta", "beta word1")]
+        assert s.query_phase_batch(same) is not None
+
+
 class TestBulkIngest:
     def _packed_from_builder(self, docs):
         """Build a reference segment per-document, then re-pack its columns

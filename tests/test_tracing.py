@@ -340,8 +340,9 @@ def served(tmp_path_factory):
         node.close()
 
 
-#: 8 items of mixed lengths (1, 2, 3 terms): no one compiled plan serves
-#: them, so the shard answers query by query — the benchmark cell's path
+#: 8 items of mixed lengths (1, 2, 3 terms): their term lists pad to the
+#: batch's term bucket (4), so ONE compiled plan serves them and the
+#: shard answers all eight in one dispatch — the benchmark cell's path
 MSEARCH = [line for i in range(8) for line in (
     {"index": "ring"},
     {"query": {"match": {"t": " ".join(
@@ -369,6 +370,7 @@ def _self_ns(records):
 def test_msearch_over_rest_one_request_every_layer(served, fresh_ring):
     node, call = served
     before = tracing.spans_allocated()
+    jit0 = node.local_node_stats()["indices"]["jit"]
     t0 = time.monotonic_ns()
     status, reply = call("POST", "/_msearch", MSEARCH)
     t1 = time.monotonic_ns()
@@ -385,11 +387,10 @@ def test_msearch_over_rest_one_request_every_layer(served, fresh_ring):
                  "rest.write", "action.msearch", "action.msearch_group",
                  "action.shard_msearch"):
         assert names.count(name) == 1, name
-    # query by query: one enqueue, one drain, one fetch per item
+    # one batch: one plan, one enqueue, one drain — and a fetch per item
     for name in ("plan.exact", "jit.pack", "jit.enqueue", "jit.drain",
-                 "jit.unpack", "fetch.hits"):
-        assert names.count(name) >= 8, (name, names.count(name))
-    assert names.count("jit.enqueue") == names.count("jit.drain") == 8
+                 "jit.unpack"):
+        assert names.count(name) == 1, (name, names.count(name))
     assert names.count("fetch.hits") == 8
     # an _msearch bypasses the scheduler; every other layer is there
     layers = {tracing.SPAN_LAYERS[n] for n in names}
@@ -419,15 +420,22 @@ def test_msearch_over_rest_one_request_every_layer(served, fresh_ring):
         "rest.read", "rest.serialise", "rest.write"]
     assert all(r[7] == -1 or 0 <= r[7] <= r[6] - r[5] + 20_000_000
                for r in recs)
-    # the book: 8 launches, each closed by its drain
+    # the book: one launch, closed by its drain
     book = tracing.book_stats()
-    assert book["launches"] == 8 and book["launches_in_flight"] == 0
+    assert book["launches"] == 1 and book["launches_in_flight"] == 0
     assert book["launches_without_drain"] == 0
     assert book["starved_ns"] + book["in_flight_ns"] == book["observed_ns"]
-    # ...and the device_rtt lane got the 8 round trips
+    # ...and the device_rtt lane got the one round trip
     stats = node.local_node_stats()
-    assert stats["device"]["launches"] == 8
+    assert stats["device"]["launches"] == 1
     assert stats["tracing"]["ring"]["bytes"] == tracing.RING_BYTES
+    # the counters that say how often the mechanism engages: all eight
+    # items batched; lengths 1, 2, 3, 1, 2, 3, 1, 2 padded to 4 each
+    jit = stats["indices"]["jit"]
+    assert jit["msearch_items_batched"] - jit0["msearch_items_batched"] == 8
+    assert jit["msearch_items_serial"] == jit0["msearch_items_serial"]
+    assert jit["match_terms_real"] - jit0["match_terms_real"] == 15
+    assert jit["match_terms_padded"] - jit0["match_terms_padded"] == 17
 
 
 def test_single_search_over_rest_passes_the_scheduler(served, fresh_ring):
