@@ -236,12 +236,12 @@ class Corpus:
 
 
 def create_index(http: Http, name: str, *, impact: bool = False,
-                 vec_dims: int = 0) -> None:
+                 vec_dims: int = 0, shards: int = 1) -> None:
     props = {"body": {"type": "text", "analyzer": "whitespace"},
              "rank": {"type": "double"}, "cat": {"type": "keyword"}}
     if vec_dims:
         props["vec"] = {"type": "dense_vector", "dims": vec_dims}
-    settings = {"number_of_shards": 1, "number_of_replicas": 0}
+    settings = {"number_of_shards": shards, "number_of_replicas": 0}
     if impact:
         settings["index.search.impact_plane"] = True
     http.call("PUT", f"/{name}", {
@@ -388,17 +388,19 @@ def oracle_check(oracle, qtids, resp: dict, k: int, label: str) -> None:
 
 
 @contextlib.contextmanager
-def served_node():
+def served_node(settings: dict | None = None):
     """A ``Node`` and a ``RestServer`` exactly as ``bootstrap.main``
-    starts them — default settings, HTTP ingress last, port 0, data
-    under a temp dir — → (node, the user's HTTP client)."""
+    starts them — default settings (and ``settings`` over them), HTTP
+    ingress last, port 0, data under a temp dir — → (node, the user's
+    HTTP client)."""
     from elasticsearch_tpu.common.settings import Settings
     from elasticsearch_tpu.node import Node
     from elasticsearch_tpu.rest.server import RestServer
     data = tempfile.mkdtemp(prefix="chip_smoke_")
 
     def start():
-        node = Node(Settings({"path.data": data}), data_path=data).start()
+        node = Node(Settings({**(settings or {}), "path.data": data}),
+                    data_path=data).start()
         return node, RestServer(node, host="127.0.0.1", port=0).start()
 
     node, server = phase("start-up", start)
@@ -673,77 +675,102 @@ def make_lane_queries(rng, corpus: Corpus) -> list:
 # ---------------------------------------------------------------------------
 
 def run_four_chips(args, dev: dict) -> None:
+    """The mesh lanes and the collective plane at (1,4) and (2,2), each
+    on a node whose setting ``search.mesh`` installs the geometry — the
+    way a deployment gets one — against a node without the setting (one
+    chip): the same hits, bit for bit."""
     import jax
     import numpy as np
-    from elasticsearch_tpu.parallel.mesh import make_mesh
-    from elasticsearch_tpu.search import jit_exec
 
     check(dev["count"] == 4, f"--chips 4 found {dev['count']} device(s)")
-    with served_node() as (node, http):
-        create_index(http, "smoke", impact=True)
-        create_index(http, "lanes", impact=True, vec_dims=VEC_DIMS)
-        rng = np.random.default_rng(args.seed)
-        corpus = phase("corpus (host)", Corpus, rng, args.docs, VOCAB)
-        phase("load: bulk columnar ingest", install_packed, node,
-              http, "smoke", corpus, 0, args.docs)
-        rng2, small, vecs = lanes_corpus(args)
-        install_packed(node, http, "lanes", small, 0, small.n_docs, vecs)
+    rng = np.random.default_rng(args.seed)
+    corpus = phase("corpus (host)", Corpus, rng, args.docs, VOCAB)
+    rng2, small, vecs = lanes_corpus(args)
+    texts = make_lane_queries(rng, corpus)
+    impact_bodies = [{"query": {"match": {"body": t}}, "size": 10,
+                      "track_total_hits": False} for t in texts[:8]]
+    plane_bodies = [{"query": {"match": {"body": t}}, "size": 10}
+                    for t in texts[:8]]
+    knn_bodies = [{"knn": {
+        "field": "vec", "k": 10, "num_candidates": 100,
+        "query_vector": (vecs[i] + 0.1 * rng2.standard_normal(
+            VEC_DIMS).astype(np.float32)).tolist()}, "size": 10}
+        for i in range(4)]
+    mesh_lanes = {"impact-mesh", "knn-mesh"}
+    chip_lanes = {"impact-pruned", "knn"}
 
-        texts = make_lane_queries(rng, corpus)
-        impact_bodies = [{"query": {"match": {"body": t}}, "size": 10,
-                          "track_total_hits": False} for t in texts[:8]]
-        knn_bodies = [{"knn": {
-            "field": "vec", "k": 10, "num_candidates": 100,
-            "query_vector": (vecs[i] + 0.1 * rng2.standard_normal(
-                VEC_DIMS).astype(np.float32)).tolist()}, "size": 10}
-            for i in range(4)]
-
-        def serve(label: str, mesh_lanes: bool) -> list:
+    def serve(geometry: str | None) -> list:
+        label = f"geometry {geometry}" if geometry else "one chip"
+        settings = {"search.mesh": geometry} if geometry else None
+        with served_node(settings) as (node, http):
+            create_index(http, "smoke", impact=True)
+            create_index(http, "lanes", impact=True, vec_dims=VEC_DIMS)
+            create_index(http, "sharded", shards=4)
+            install_packed(node, http, "smoke", corpus, 0, args.docs)
+            install_packed(node, http, "lanes", small, 0, small.n_docs,
+                           vecs)
+            # the 4-shard index: a contiguous quarter of the rows a shard
+            quarter = args.docs // 4
+            for sid in range(4):
+                node.indices_service.indices["sharded"].engine(sid) \
+                    .install_segment(corpus.packed_segment(
+                        sid * quarter, (sid + 1) * quarter),
+                        track_versions=False)
+            http.call("POST", "/sharded/_refresh")
+            shown = http.call("GET", "/_nodes/stats")["nodes"]
+            (stats,) = shown.values()
+            say(f"{label}: _nodes/stats device.mesh "
+                f"{json.dumps(stats['device']['mesh'])}")
             before = lane_dispatches()
             out = [[(h["_id"], h["_score"]) for h in r["hits"]["hits"]]
                    for r in http.msearch("smoke", impact_bodies)
-                   + http.msearch("lanes", knn_bodies)]
+                   + http.msearch("lanes", knn_bodies)
+                   + http.msearch("sharded", plane_bodies)]
             after = lane_dispatches()
             moved = {ln: after[ln] - before.get(ln, 0) for ln in after
                      if after[ln] != before.get(ln, 0)}
             say(f"{label}: lane dispatches {json.dumps(moved)}")
-            want = ("impact-mesh", "knn-mesh") if mesh_lanes \
-                else ("impact-pruned", "knn")
+            want = (mesh_lanes if geometry else chip_lanes) | {"mesh"}
             check(all(moved.get(ln, 0) > 0 for ln in want)
                   and not any(moved.get(ln) for ln in
-                              {"impact-mesh", "knn-mesh", "impact-pruned",
-                               "knn"} - set(want)),
-                  f"{label}: expected dispatches on {want}, saw {moved}")
+                              (mesh_lanes | chip_lanes) - want),
+                  f"{label}: expected dispatches on {sorted(want)}, "
+                  f"saw {moved}")
+            if geometry:
+                dp, shard = (int(x) for x in geometry.split("x"))
+                check((stats["device"]["mesh"]["dp"],
+                       stats["device"]["mesh"]["shard"]) == (dp, shard),
+                      f"the node serves over {stats['device']['mesh']}")
+                hbm = hbm_report(http, totals=True)
+                say(f"per-device resident bytes at {geometry} "
+                    f"(_cat/hbm?totals=true): "
+                    f"{json.dumps(hbm['per_device'], sort_keys=True)}")
+                placed = [d for d, b in hbm["per_device"].items()
+                          if d != "-" and b > 0]
+                # the ledger books a shard column to the first of its
+                # dp replicas; the live arrays below sit on all four
+                check(len(placed) == shard,
+                      f"bytes booked on {len(placed)} devices for "
+                      f"{shard} shard columns: {hbm['per_device']}")
+                on = {d.id for a in jax.live_arrays()
+                      for d in a.devices()}
+                check(len(on) == 4,
+                      f"live arrays sit on devices {sorted(on)}")
+            # before the node closes: its programs leave the cost table
+            # with its engines
+            assert_chip_did_the_work(sorted(want), dev["platform"])
             return out
 
-        results = {}
-        for dp, shard in ((1, 4), (2, 2)):
-            jit_exec.set_serving_mesh(make_mesh(dp=dp, shard=shard))
-            results[(dp, shard)] = phase(
-                f"mesh lanes ({dp},{shard})", serve,
-                f"geometry ({dp},{shard})", True)
-            hbm = hbm_report(http, totals=True)
-            say(f"per-device resident bytes at ({dp},{shard}) "
-                f"(_cat/hbm?totals=true): "
-                f"{json.dumps(hbm['per_device'], sort_keys=True)}")
-            placed = [d for d, b in hbm["per_device"].items()
-                      if d != "-" and b > 0]
-            check(len(placed) == 4,
-                  f"bytes on {len(placed)} of 4 devices: "
-                  f"{hbm['per_device']}")
-        jit_exec.set_serving_mesh(None)
-        single = phase("one-chip lanes", serve, "one chip", False)
-        for geom, got in results.items():
-            check(got == single,
-                  f"mesh lanes at {geom} are not bit-identical to the "
-                  f"one-chip lanes")
-        say("mesh lanes at (1,4) and (2,2): hits bit-identical to the "
-            "one-chip lanes (ids and scores)")
-        on = {d.id for a in jax.live_arrays() for d in a.devices()}
-        check(len(on) == 4, f"live arrays sit on devices {sorted(on)}")
-        assert_chip_did_the_work(
-            ("impact-mesh", "knn-mesh", "impact-pruned", "knn"),
-            dev["platform"])
+    results = {g: phase(f"mesh lanes + plane ({g})", serve, g)
+               for g in ("1x4", "2x2")}
+    single = phase("one-chip lanes + plane", serve, None)
+    for geom, got in results.items():
+        check(got == single,
+              f"the mesh lanes and the plane at {geom} are not "
+              f"bit-identical to the one-chip node's")
+    say("mesh lanes and the collective plane at 1x4 and 2x2 (node "
+        "setting search.mesh): hits bit-identical to the one-chip "
+        "node's (ids and scores)")
 
 
 def main(argv=None) -> int:

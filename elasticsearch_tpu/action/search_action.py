@@ -38,7 +38,8 @@ from elasticsearch_tpu.common.errors import (
     TaskCancelledError)
 from elasticsearch_tpu.action.replica_stats import ReplicaStatsTable
 from elasticsearch_tpu.common.settings import parse_time_value
-from elasticsearch_tpu.index.device_reader import device_reader_for
+from elasticsearch_tpu.index.device_reader import (
+    device_reader_for, host_reader_for)
 from elasticsearch_tpu.observability import attribution
 from elasticsearch_tpu.observability import histograms as obs_hist
 from elasticsearch_tpu.observability import tracing as obs_trace
@@ -1686,14 +1687,15 @@ class SearchActions:
                           "dfs_query_then_fetch", "dfs_query_and_fetch")
 
     @staticmethod
-    def _note_plane_fallback(indices, reason: str) -> None:
-        """One plane admission attempt that fell back to the fan-out:
-        label the node-wide reason counter AND each target index's
-        admission stats (surfaced in _stats / _nodes/stats). Admission
-        declines are NOT compiled-path `fallbacks` — the request still
-        runs correctly on the RPC fan-out."""
+    def _note_plane_fallback(indices, reason: str, items: int = 1) -> None:
+        """One plane admission attempt of ``items`` search items that
+        fell back to the fan-out: label the node-wide reason counter AND
+        each target index's admission stats (surfaced in _stats /
+        _nodes/stats). Admission declines are NOT compiled-path
+        `fallbacks` — the request still runs correctly on the RPC
+        fan-out."""
         from elasticsearch_tpu.search import jit_exec
-        jit_exec.note_plane_fallback(reason)
+        jit_exec.note_plane_fallback(reason, items)
         for index in indices:
             index.note_plane_fallback(reason)
 
@@ -1743,7 +1745,8 @@ class SearchActions:
         for index in indices:
             nshards = index.meta.number_of_shards
             if set(index.engines) != set(range(nshards)):
-                self._note_plane_fallback(indices, "not-local")
+                self._note_plane_fallback(indices, "not-local",
+                                          len(bodies))
                 return None               # not every shard lives here
             owners.extend((index, sid) for sid in range(nshards))
         if len(owners) < 2:
@@ -1758,11 +1761,13 @@ class SearchActions:
         # here and reports back through record_success/record_error below
         if not jit_exec.plane_breaker.allow():
             jit_exec.note_breaker_skip()
-            self._note_plane_fallback(indices, "breaker-open")
+            self._note_plane_fallback(indices, "breaker-open",
+                                          len(bodies))
             return None
         for req in reqs:
             if req.suggest or req.rescore:
-                self._note_plane_fallback(indices, "ineligible-shape")
+                self._note_plane_fallback(indices, "ineligible-shape",
+                                          len(bodies))
                 return None
         if not all(self._plane_precheck(index, reqs)
                    for index in indices):
@@ -1770,7 +1775,8 @@ class SearchActions:
             # cursors, …): bail BEFORE the mesh build —
             # _mesh_searcher_for stacks every shard column into HBM, a
             # cost the RPC fallback should not pay per refresh generation
-            self._note_plane_fallback(indices, "ineligible-shape")
+            self._note_plane_fallback(indices, "ineligible-shape",
+                                          len(bodies))
             return None
         from elasticsearch_tpu.search.controller import merge_responses
         from elasticsearch_tpu.search.phase import (ShardQueryResult,
@@ -1789,7 +1795,8 @@ class SearchActions:
             try:
                 msearch = self._mesh_searcher_for(indices)
             except QueryParsingError:     # vector/geo/nested layouts
-                self._note_plane_fallback(indices, "ineligible-shape")
+                self._note_plane_fallback(indices, "ineligible-shape",
+                                          len(bodies))
                 return None
             except jit_exec.DeviceStallError as e:
                 # a watchdog-abandoned wait surfacing through the pack:
@@ -1797,19 +1804,22 @@ class SearchActions:
                 # hardware from ordinary device faults
                 jit_exec.note_fallback(e)
                 jit_exec.note_device_error(e)
-                self._note_plane_fallback(indices, "device-stall")
+                self._note_plane_fallback(indices, "device-stall",
+                                          len(bodies))
                 return None
             except Exception as e:        # noqa: BLE001 — fallback seam
                 jit_exec.note_fallback(e)
                 jit_exec.note_device_error(e)
-                self._note_plane_fallback(indices, "device-error")
+                self._note_plane_fallback(indices, "device-error",
+                                          len(bodies))
                 return None
             if any(r.terminate_after is not None for r in reqs) and \
                     msearch.n_slots > 1:
                 # terminate_after over multi-segment shards diverges
                 # from the fan-out's segment-prefix semantics — stay
                 # exact, let the fan-out serve it
-                self._note_plane_fallback(indices, "ineligible-shape")
+                self._note_plane_fallback(indices, "ineligible-shape",
+                                          len(bodies))
                 return None
             try:
                 outs = msearch.search_batch(list(bodies),
@@ -1819,22 +1829,31 @@ class SearchActions:
                 # is a body that failed the plane's re-parse
                 self._note_plane_fallback(
                     indices, "ineligible-shape" if "RPC" in str(e)
-                    else "parse-error")
+                    else "parse-error", len(bodies))
                 return None
             except TaskCancelledError:
                 raise
             except jit_exec.DeviceStallError as e:
                 jit_exec.note_fallback(e)
                 jit_exec.note_device_error(e)
-                self._note_plane_fallback(indices, "device-stall")
+                self._note_plane_fallback(indices, "device-stall",
+                                          len(bodies))
                 return None
             except Exception as e:        # noqa: BLE001 — fallback seam
                 jit_exec.note_fallback(e)
                 jit_exec.note_device_error(e)
-                self._note_plane_fallback(indices, "device-error")
+                self._note_plane_fallback(indices, "device-error",
+                                          len(bodies))
                 return None
+            # the fetch side's readers: where the pack's blocks sit on
+            # their owning devices, a host-side reader (ids, sources and
+            # the row numbering are all the fetch reads) — the resident
+            # reader would put a second copy of every column on the
+            # default device
+            reader_for = host_reader_for if msearch.placed(msearch.mesh) \
+                else device_reader_for
             searchers = [
-                ShardSearcher(sid, device_reader_for(index.engines[sid]),
+                ShardSearcher(sid, reader_for(index.engines[sid]),
                               index.mapper_service,
                               index_name=index.name,
                               version_fn=index.engines[sid].doc_version)
@@ -1843,42 +1862,52 @@ class SearchActions:
                    for si, s in enumerate(searchers)):
                 break
             if attempt == 1:              # raced twice: fan-out path
-                self._note_plane_fallback(indices, "refresh-race")
+                self._note_plane_fallback(indices, "refresh-race",
+                                          len(bodies))
                 return None
         index_names = [index.name for index, _ in owners]
         responses = []
         q_ms = (time.perf_counter() - t0) * 1e3
         for _ in bodies:
             obs_hist.observe_lane("plane", q_ms / len(bodies))
+        # reader doc base of every (shard, slot): a global plane id maps
+        # to its owning shard's reader numbering by arithmetic alone
+        reader_bases = np.asarray(
+            [[s.reader.segments[j].doc_base
+              if j < len(s.reader.segments) else 0
+              for j in range(msearch.n_slots)] for s in searchers],
+            np.int64)
+        slot_bases = np.asarray(msearch.slot_bases, np.int64)
         for body, req, out in zip(bodies, reqs, outs):
             sort_vals = out.get("sort_values")
-            per_shard: dict[int, list[tuple[int, float, list]]] = {}
-            for pos, (g, sc) in enumerate(zip(out["doc_ids"],
-                                              out["scores"])):
-                si, j, row = msearch.resolve(int(g))
-                rdoc = searchers[si].reader.segments[j].doc_base + row
-                per_shard.setdefault(si, []).append(
-                    (rdoc, float(sc),
-                     sort_vals[pos] if sort_vals is not None else None))
-            results = []
-            ta = req.terminate_after
-            for si, s in enumerate(searchers):
-                rows = per_shard.get(si, [])
-                # real per-shard totals from the program's all_gather
-                # count lane; terminate_after caps them like the
-                # fan-out's per-shard collection cap
-                raw_total = int(out["shard_totals"][si])
-                results.append(ShardQueryResult(
-                    si,
-                    raw_total if ta is None else min(raw_total, ta),
-                    max((sc for _, sc, _ in rows), default=None),
-                    np.asarray([d for d, _, _ in rows], np.int32),
-                    np.asarray([sc for _, sc, _ in rows], np.float32),
-                    [sv for _, _, sv in rows]
-                    if sort_vals is not None else None,
-                    {}, s.reader))
-                if ta is not None and raw_total >= ta:
-                    results[-1].terminated_early = True
+            with obs_trace.span("plane.split"):
+                # the merged global top-k back to its owning shards, in
+                # rank order within each (the winner-only fetch's input)
+                g = np.asarray(out["doc_ids"], np.int64)
+                scores = np.asarray(out["scores"], np.float32)
+                owner, local = np.divmod(g, msearch.shard_stride)
+                slot = np.searchsorted(slot_bases, local,
+                                       side="right") - 1
+                rdocs = reader_bases[owner, slot] + local \
+                    - slot_bases[slot]
+                results = []
+                ta = req.terminate_after
+                for si, s in enumerate(searchers):
+                    mine = np.flatnonzero(owner == si)
+                    # real per-shard totals from the program's
+                    # all_gather count lane; terminate_after caps them
+                    # like the fan-out's per-shard collection cap
+                    raw_total = int(out["shard_totals"][si])
+                    results.append(ShardQueryResult(
+                        si,
+                        raw_total if ta is None else min(raw_total, ta),
+                        float(scores[mine].max()) if len(mine) else None,
+                        rdocs[mine].astype(np.int32), scores[mine],
+                        [sort_vals[pos] for pos in mine]
+                        if sort_vals is not None else None,
+                        {}, s.reader))
+                    if ta is not None and raw_total >= ta:
+                        results[-1].terminated_early = True
             resp = merge_responses(index_names, req, results, searchers,
                                    (time.perf_counter() - t0) * 1e3, None)
             mesh_aggs = out.get("aggregations")
@@ -1915,6 +1944,7 @@ class SearchActions:
         for index in indices:
             index.plane_stats.pop("degraded", None)
             index.note_plane_served(len(bodies))
+        jit_exec.note_plane_served(len(bodies))
         return responses
 
     @staticmethod
@@ -1999,10 +2029,13 @@ class SearchActions:
         return True
 
     def _plane_mesh_get(self):
-        """One shared 1-device mesh for every plane pack on this node:
-        re-using the SAME Mesh object keeps NamedSharding identity stable
-        so shape-keyed programs re-dispatch without retracing."""
-        mesh = getattr(self, "_plane_mesh", None)
+        """The mesh every plane pack on this node is built over: the one
+        the node setting ``search.mesh`` installed (``Node.serving_mesh``
+        — an index of as many shards as its shard axis then sits one
+        shard a device), else one shared 1-device mesh. Re-using the
+        SAME Mesh object keeps NamedSharding identity stable so
+        shape-keyed programs re-dispatch without retracing."""
+        mesh = self.node.serving_mesh or getattr(self, "_plane_mesh", None)
         if mesh is None:
             import jax
             from elasticsearch_tpu.parallel import make_mesh
@@ -2058,6 +2091,12 @@ class SearchActions:
             str(index.index_settings.get(
                 "index.search.plane_incremental", "true")).lower()
             not in ("false", "0") for index in indices)
+        # where the stacked operands ARE the owner-placed blocks (one
+        # shard a device) the pack holds no bytes of its own: the blocks'
+        # own charges are the whole booking
+        mesh = self._plane_mesh_get()
+        if MeshEngineSearcher.composes_in_place(mesh, len(engines)):
+            new_bytes = 0
         charge = _PackCharge(bs, new_bytes if bs is not None else 0,
                              component="pack",
                              index=",".join(index.name
@@ -2066,7 +2105,7 @@ class SearchActions:
                       f"[{','.join(index.name for index in indices)}]")
         try:
             msearch = MeshEngineSearcher(
-                self._plane_mesh_get(), engines,
+                mesh, engines,
                 indices[0].mapper_service, mapper_services=mappers,
                 breaker_service=bs, prev=prev, reuse_blocks=reuse,
                 stats_sinks=sinks)

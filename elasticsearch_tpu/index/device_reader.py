@@ -419,6 +419,22 @@ def device_reader_for(engine, view: SearcherView | None = None,
         return cached
 
 
+def host_reader_for(engine) -> DeviceReader:
+    """The engine's current view as a reader that uploads NOTHING: every
+    segment in the host pool (an HBM budget of zero), so it books no
+    breaker bytes. For callers that read the row numbering, ids and
+    sources only — the fetch side of the collective plane on a mesh of
+    several devices, whose columns already sit on their owning devices
+    (mesh_engine's placed blocks). Cached per refresh generation on the
+    engine, beside the resident reader's cache and apart from it."""
+    view = engine.acquire_searcher()
+    cached = getattr(engine, "_host_reader_cache", None)
+    if cached is None or cached.generation != view.generation:
+        cached = DeviceReader(view, hbm_budget_bytes=0)
+        engine._host_reader_cache = cached     # benign race: equal readers
+    return cached
+
+
 def release_device_reader(engine) -> None:
     """Drop the engine's cached reader and return its breaker reservation
     (called from Engine.close so budget doesn't leak across index

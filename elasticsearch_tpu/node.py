@@ -51,6 +51,7 @@ class Node:
         self.data_path.mkdir(parents=True, exist_ok=True)
         self._hub = transport_hub
         self._started = False
+        self.serving_mesh = None         # search.mesh, installed at start
 
     # ---- lifecycle (Node.start order, core/node/Node.java:230-275) ---------
 
@@ -64,6 +65,7 @@ class Node:
         from elasticsearch_tpu.observability import costs
         ensure_compile_cache()
         costs.machine_constants()
+        self.serving_mesh = self._install_serving_mesh()
         # transport selection (ref: `transport.type` setting resolved by
         # NetworkModule — NettyTransport by default, LocalTransport for
         # embedded/test use; core/node/Node.java:230-275 wiring order).
@@ -966,7 +968,8 @@ class Node:
             # the in-flight book: how long the host left the device
             # with no launch in flight (``starved_pct``), process-wide
             # and cumulative — two reads give a window
-            "device": _tracing.book_stats(),
+            "device": {**_tracing.book_stats(),
+                       "mesh": self.serving_mesh_doc()},
             "process": ps,
             "os": osx,
             # process-level memory reported under the reference's jvm
@@ -1327,8 +1330,59 @@ class Node:
                 return h
             time.sleep(0.01)
 
+    def _install_serving_mesh(self):
+        """``search.mesh: "<dp>x<shard>"`` — the device mesh this node
+        serves over: the collective plane packs an index's shards over
+        its ``shard`` axis (one shard a chip where they are as many) and
+        the impact / knn mesh lanes shard over it
+        (``jit_exec.set_serving_mesh``). Validated against the devices
+        JAX sees by ``make_mesh``, whose rejection lists the valid
+        geometries: a bad geometry stops the node here, not the first
+        search. A geometry of fewer devices than the host has takes the
+        leading ones. Absent = one device, every path as it is without
+        a mesh. → the Mesh, or None."""
+        raw = self.settings.get("search.mesh")
+        if raw is None or str(raw).strip() == "":
+            return None
+        from elasticsearch_tpu.common import IllegalArgumentError
+        from elasticsearch_tpu.parallel.mesh import make_mesh
+        from elasticsearch_tpu.search import jit_exec
+        parts = str(raw).lower().split("x")
+        if len(parts) != 2 or not all(p.strip().isdigit() for p in parts):
+            raise IllegalArgumentError(
+                f"search.mesh [{raw}] is not of the form <dp>x<shard>, "
+                f"as in 1x4")
+        dp, shard = int(parts[0]), int(parts[1])
+        import jax
+        devices = jax.devices()
+        if 0 < dp * shard < len(devices):
+            # a mesh smaller than the host takes its leading devices
+            devices = devices[:dp * shard]
+        mesh = make_mesh(dp=dp, shard=shard, devices=devices)
+        jit_exec.set_serving_mesh(mesh)
+        return mesh
+
+    def serving_mesh_doc(self) -> dict:
+        """``_nodes/stats`` ``device.mesh``: the geometry searches are
+        served over and the devices it spans."""
+        mesh = self.serving_mesh
+        if mesh is None:
+            return {"dp": 1, "shard": 1, "devices": 1, "setting": None}
+        return {"dp": int(mesh.shape["dp"]),
+                "shard": int(mesh.shape["shard"]),
+                "devices": int(mesh.devices.size),
+                "device_ids": [int(d.id) for d in mesh.devices.flat],
+                "setting": str(self.settings.get("search.mesh"))}
+
+    def _remove_serving_mesh(self) -> None:
+        from elasticsearch_tpu.search import jit_exec
+        mesh, self.serving_mesh = self.serving_mesh, None
+        if mesh is not None and jit_exec.serving_mesh() is mesh:
+            jit_exec.set_serving_mesh(None)
+
     def close(self) -> None:
         """Graceful shutdown: leave the cluster, then stop services."""
+        self._remove_serving_mesh()
         if self._started:
             self._started = False
             self.plugins_service.apply_node_stop(self)
@@ -1353,6 +1407,7 @@ class Node:
         """Abrupt death — no leave notification, no flush ordering; the
         cluster must detect the loss via fault detection (test disruption
         helper, mirrors InternalTestCluster restartNode(KILL))."""
+        self._remove_serving_mesh()
         if self._started:
             self._started = False
             if self._delayed_reroute_timer is not None:

@@ -62,9 +62,10 @@ span that fetched its result closes it, and the always-on
 Between launches the book accumulates the time in which nothing was in
 flight — the device was **starved** by the host — which
 ``_nodes/stats`` reports as ``device.starved_pct``. A launch whose
-result is fetched outside a ``jit.drain`` span (the per-segment and
-collective-plane lanes) closes with its enqueue and is counted in
-``launches_without_drain``: there the book over-reads starved time. A
+result is fetched outside a ``jit.drain`` span (the per-segment lane)
+closes with its enqueue and is counted in ``launches_without_drain``:
+there the book over-reads starved time. (The collective plane's launch
+closes at the end of its ``plane.drain``.) A
 late drain (the host reached ``np.asarray`` after the result was
 ready) hides idle time from the book; a profiler's device idle share
 bounds it from above.
@@ -147,6 +148,14 @@ SPAN_LAYERS = {
     "action.fetch": "action", "action.reduce": "action",
     "action.shard": "action", "action.shard_query": "action",
     "action.shard_fetch": "action",
+    # the collective plane under ``action.plane`` — mesh_engine's
+    # search_batch (host planning of the batch, its query constants'
+    # upload, the program's enqueue — it holds a ``jit.enqueue`` — and
+    # the drain that holds ``jit.drain``)
+    # and search_action's split of the global top-k by owning shard
+    "plane.resolve": "action", "plane.upload": "action",
+    "plane.enqueue": "action", "plane.drain": "action",
+    "plane.split": "action",
     # scheduler — search/scheduler.py
     "scheduler.queue": "scheduler", "scheduler.launch": "scheduler",
     "scheduler.drain": "scheduler",

@@ -51,6 +51,18 @@ caller falls back to the RPC fan-out):
   ``reduce_aggs`` pipeline the RPC coordinator uses
   (InternalAggregations.reduce analog).
 
+Placement: on a mesh of several devices (the node setting
+``search.mesh``) every shard's blocks are uploaded to the device(s) of
+its mesh column and stay there; with one shard a device the stacked
+``P("shard")`` operands are assembled from those buffers in place — one
+resident copy of every column, nothing staged through device 0, the
+blocks' own breaker charges the whole booking. On one device
+(``spd = n_shards``) blocks go to the default device and are stacked
+there, as ever. A batch of BM25 ``match`` queries of unequal lengths
+plans to ONE signature (term lists padded to the batch's widest term
+bucket, ``execute.match_term_floor``), so a mixed-length ``_msearch``
+is one dispatch.
+
 Three-layer caching: per-SEGMENT device blocks live in a module-level
 cache keyed by (engine uuid, block uid, slot-layout signature) — a
 refresh uploads only newly built segments' columns and changed live
@@ -73,10 +85,14 @@ the plane too. Multi-index batches pass one mapper per engine shard
 (``mapper_services``) and pack every index's shard columns into the same
 program.
 
-Results are bit-identical to the RPC path (the host merge concatenates
-shard payloads in the same shard order the all_gather does, and the
-selection orders are stable) — asserted by tests/test_mesh_engine.py and
-the driver's dryrun_multichip.
+Results equal the RPC path's (the host merge concatenates shard
+payloads in the same shard order the all_gather does, and the selection
+orders are stable): the same documents in the same order and the same
+totals — asserted by tests/test_mesh_engine.py, the driver's
+dryrun_multichip and tests/test_bm25_4shard_config.py; a BM25 score's
+last bit may differ between the two compiled programs (1.4e-7 relative
+at the most), and is equal between the one-device and the placed
+plane.
 """
 
 from __future__ import annotations
@@ -98,7 +114,8 @@ from elasticsearch_tpu.index.device_reader import (
     dd_split)
 from elasticsearch_tpu.index.segment import (
     KeywordFieldColumn, Segment, TextFieldColumn)
-from elasticsearch_tpu.observability.tracing import device_span
+from elasticsearch_tpu.observability.tracing import (
+    close_launches, device_span, launch_scope, span)
 # module-level on purpose: step_local runs under shard_map tracing, and
 # an import executed at trace time caches foreign tracers into the
 # imported module's globals (trace-purity rule)
@@ -147,6 +164,14 @@ _MAX_KW_SORT_VOCAB = 1 << 24
 _PROGRAM_CACHE_CAP = 64
 _program_cache: "OrderedDict[tuple, object]" = OrderedDict()
 _program_lock = threading.Lock()
+#: one enqueue of a mesh program at a time, process-wide: a program over
+#: several devices is enqueued device by device, and two request threads
+#: that interleave their enqueues can leave two devices with the two
+#: programs in opposite orders — each then waits in its all_gather for
+#: the other, for ever. Held for the (asynchronous) enqueue only, never
+#: for the wait. (A guard, not a repair of something seen: PR 34's first
+#: chip run served 76 dispatches from two threads without it.)
+_enqueue_lock = threading.Lock()
 
 
 def clear_program_cache() -> None:
@@ -178,6 +203,51 @@ _BLOCK_CACHE_CAP = 512
 _EMPTY_UID = 0
 
 
+def _upload_block(flat_np: list, owners: tuple | None,
+                  kind: str = "block") -> list:
+    """One block's host arrays → device, under the ``upload`` seam (a
+    fault raised there leaves the caller's block as it was). ``owners``
+    None: the default device. Else: every array to each owning device,
+    with a leading axis of one (a view on the host, so the device buffer
+    IS a shard of the stacked operand) → per array the tuple of its
+    owners' buffers."""
+    from elasticsearch_tpu.search import jit_exec
+    with device_span("upload") as dsp:
+        jit_exec.device_fault_point("upload")
+        if owners is None:
+            arrays = [jax.device_put(a) for a in flat_np]
+        else:
+            arrays = [tuple(jax.device_put(a[None], d) for d in owners)
+                      for a in flat_np]
+        dsp.set(bytes=int(sum(a.nbytes for a in flat_np)), kind=kind)
+    return arrays
+
+
+def _compose_placed(mesh: Mesh, spd: int, shard_blocks: list) -> list:
+    """The ``P("shard")`` operands of one slot from its owner-placed
+    blocks. ``shard_blocks[si][i]``: array ``i`` of shard ``si`` as the
+    tuple of its owners' ``[1, ...]`` buffers, dp-major. With one shard
+    a device (``spd`` 1) a block's buffer is the operand's shard on its
+    device: nothing is copied and nothing passes through another device.
+    With ``spd`` shards a device the owner concatenates its own blocks
+    (one copy, on the device that keeps it)."""
+    sharding = NamedSharding(mesh, P("shard"))
+    n_dp, s_mesh = mesh.devices.shape
+    out = []
+    for i in range(len(shard_blocks[0])):
+        bufs = []
+        for r in range(n_dp):
+            for col in range(s_mesh):
+                mine = [shard_blocks[col * spd + li][i][r]
+                        for li in range(spd)]
+                bufs.append(mine[0] if spd == 1
+                            else jnp.concatenate(mine, axis=0))
+        shape = (len(shard_blocks),) + tuple(bufs[0].shape[1:])
+        out.append(jax.make_array_from_single_device_arrays(
+            shape, sharding, bufs))
+    return out
+
+
 class _Block:
     __slots__ = ("key", "template", "arrays", "live_np", "col_bytes",
                  "extrema", "charge")
@@ -200,16 +270,26 @@ class _DeviceBlockCache:
         self._lock = threading.Lock()
 
     def fetch(self, engine_uuid: str, lay_sig: tuple, lay: "_SlotLayout",
-              seg, live, doc_base: int, breaker_service, label: str):
+              seg, live, doc_base: int, breaker_service, label: str,
+              owners: tuple | None = None):
         """→ (template, device arrays, extrema, col_up, mask_up, reused):
         the padded per-segment device block, built+uploaded on miss,
         composed from residency on hit. A hit with a changed live mask
         re-uploads ONLY the mask (the delete path's zero-column-byte
         refresh). Byte counts are actual host→device transfer; `reused`
-        is the resident column bytes a rebuild did not re-ship."""
+        is the resident column bytes a rebuild did not re-ship.
+
+        ``owners``: on a multi-device mesh, the devices that own the
+        block's shard (one per ``dp`` replica). Every array then goes up
+        to each owner directly, with a leading axis of one, and the
+        entry of ``arrays`` is the tuple of those single-device buffers:
+        the ``P("shard")`` operands are assembled from them in place
+        (:func:`_compose_placed`). ``None`` = the default device and
+        bare arrays, as ever."""
         from elasticsearch_tpu.search import jit_exec
         uid = seg.block_uid if seg is not None else _EMPTY_UID
-        key = (engine_uuid, uid, lay_sig)
+        key = (engine_uuid, uid, lay_sig) if owners is None else \
+            (engine_uuid, uid, (lay_sig, tuple(int(d.id) for d in owners)))
         live_np = _pad1(live, lay.np_docs, False) if live is not None \
             else None
         with self._lock:
@@ -229,12 +309,8 @@ class _DeviceBlockCache:
                     # This is a real host→device transfer: it draws from
                     # the fault seam like every other upload (a raise
                     # here leaves the block consistent on the old mask)
-                    with device_span("upload") as dsp:
-                        jit_exec.device_fault_point("upload")
-                        blk.arrays = [jax.device_put(live_np)] + \
-                            blk.arrays[1:]
-                        dsp.set(bytes=int(live_np.nbytes),
-                                kind="mask-delta")
+                    blk.arrays = _upload_block(
+                        [live_np], owners, "mask-delta") + blk.arrays[1:]
                     blk.template = dc_replace(blk.template, live=live_np)
                     blk.live_np = live_np
                     mask_up = int(live_np.nbytes)
@@ -245,22 +321,24 @@ class _DeviceBlockCache:
                         blk.col_bytes)
         template = _build_template(lay, seg, live, doc_base)
         flat_np = seg_flatten(template)
-        with device_span("upload") as dsp:
-            jit_exec.device_fault_point("upload")
-            arrays = [jax.device_put(a) for a in flat_np]
-            dsp.set(bytes=int(sum(a.nbytes for a in flat_np)),
-                    kind="block")
+        arrays = _upload_block(flat_np, owners)
         mask_bytes = int(flat_np[0].nbytes)
         col_bytes = int(sum(a.nbytes for a in flat_np[1:]))
         extrema = _segment_extrema(seg) if seg is not None else {}
         charge = None
         if breaker_service is not None:
             from elasticsearch_tpu.common.breaker import OneShotCharge
+            # a placed block's ledger rows name its owning device (dp
+            # replicas share the first owner's attribution, as the
+            # placed cache's do), so _cat/hbm?totals=true and
+            # _nodes/stats device_memory.per_device show the placement
             charge = OneShotCharge(
                 breaker_service, col_bytes + mask_bytes,
                 engine_uuid=engine_uuid, block_id=uid,
                 parts={"mesh-columns": col_bytes,
-                       "masks": mask_bytes}).charge(label)
+                       "masks": mask_bytes},
+                device="" if owners is None
+                else str(int(owners[0].id))).charge(label)
         blk = _Block(key, template, arrays, template.live, col_bytes,
                      extrema, charge)
         evicted = []
@@ -1038,6 +1116,18 @@ def _pad1(a: np.ndarray, rows: int, fill) -> np.ndarray:
     return out
 
 
+def _fit(a: np.ndarray, shape: tuple, fill) -> np.ndarray:
+    """An immutable segment COLUMN at the slot layout's shape: the
+    column itself where it already has it (a 2 GB column of a bulk-
+    loaded segment is neither copied nor held twice on the host), a
+    padded copy otherwise. Live masks keep :func:`_pad1`: the mask-delta
+    refresh compares a block's own copy with the view's."""
+    if a.shape == shape and a.flags.c_contiguous:
+        return a
+    return _pad2(a, shape[0], shape[1], fill) if len(shape) == 2 \
+        else _pad1(a, shape[0], fill)
+
+
 @dataclass
 class _SlotLayout:
     """Common padded layout of one segment slot across every shard."""
@@ -1079,10 +1169,10 @@ def _build_template(lay: _SlotLayout, seg, live, doc_base: int
                 doc_len=c.doc_len, column=c)
         else:
             text[name] = DeviceTextField(
-                tokens=_pad2(c.tokens, n, L, -1),
-                uterms=_pad2(c.uterms, n, U, -1),
-                utf=_pad2(c.utf, n, U, 0.0),
-                doc_len=_pad1(c.doc_len, n, 0), column=c)
+                tokens=_fit(c.tokens, (n, L), -1),
+                uterms=_fit(c.uterms, (n, U), -1),
+                utf=_fit(c.utf, (n, U), 0.0),
+                doc_len=_fit(c.doc_len, (n,), 0), column=c)
     keyword = {}
     for name, kdim in lay.keyword.items():
         c = seg.keyword_fields.get(name) if seg is not None else None
@@ -1183,6 +1273,13 @@ class MeshEngineSearcher:
         self._templates = [[None] * self.n_slots for _ in range(s)]
         blocks = [[None] * self.n_slots for _ in range(s)]
         col_up = mask_up = reused = 0
+        # OWNER PLACEMENT: on a mesh of several devices a shard's blocks
+        # go up to the device(s) of its mesh column and stay there — the
+        # stacked operands below are assembled from them in place. On
+        # one device (spd = n_shards) blocks and stacks are as ever.
+        placed = self.placed(mesh)
+        owners_of = [tuple(mesh.devices[:, si // self.spd])
+                     if placed else None for si in range(s)]
         # exact f64 extrema per numeric field, merged from per-block
         # caches — gives histogram lanes a STATIC dd base (the whole
         # field range maps to one bucket window, so per-query scatter-
@@ -1201,16 +1298,13 @@ class MeshEngineSearcher:
                         _block_cache.fetch(
                             e_uuid, lay_sigs[j], lay, seg, live,
                             self.slot_bases[j], breaker_service,
-                            f"mesh block [{e_uuid[:8]}]")
+                            f"mesh block [{e_uuid[:8]}]",
+                            owners=owners_of[si])
                 else:
                     tpl = _build_template(lay, seg, live,
                                           self.slot_bases[j])
                     flat_np = seg_flatten(tpl)
-                    with device_span("upload") as dsp:
-                        jit_exec.device_fault_point("upload")
-                        arrs = [jax.device_put(a) for a in flat_np]
-                        dsp.set(bytes=int(sum(a.nbytes
-                                              for a in flat_np)))
+                    arrs = _upload_block(flat_np, owners_of[si])
                     extrema = _segment_extrema(seg) if seg is not None \
                         else {}
                     m_up = int(flat_np[0].nbytes)
@@ -1265,7 +1359,9 @@ class MeshEngineSearcher:
             # strong refs, compared by IDENTITY (an `id()` token could
             # alias a freed block's address after GC; holding the arrays
             # both prevents that and costs only references)
-            token = tuple(a for si in range(s) for a in blocks[si][j])
+            token = tuple(
+                buf for si in range(s) for a in blocks[si][j]
+                for buf in (a if placed else (a,)))
             self._block_tokens.append(token)
             if prev_ok and j < len(prev._block_tokens) \
                     and len(prev._block_tokens[j]) == len(token) \
@@ -1277,6 +1373,10 @@ class MeshEngineSearcher:
             n_arr = len(blocks[0][j])
             with device_span("compose"):
                 jit_exec.device_fault_point("compose")
+                if placed:
+                    self._flats.append(_compose_placed(
+                        mesh, self.spd, [blocks[si][j] for si in range(s)]))
+                    continue
                 self._flats.append([
                     jax.device_put(jnp.stack([blocks[si][j][i]
                                               for si in range(s)]),
@@ -1290,6 +1390,10 @@ class MeshEngineSearcher:
                 _block_cache.prune(
                     engines[si].engine_uuid,
                     {g.block_uid for g in views[si].segments})
+        # one reader facade a shard for the life of this point-in-time
+        # pack: its text statistics are read once, not once a plan
+        self._readers = [_TemplateReader(self._templates[si], views[si])
+                         for si in range(s)]
         # keyword-sort data layer: per (field, fill) union-rank columns
         # and their vocabularies, built lazily on first keyword sort and
         # cached for this searcher's point-in-time views
@@ -1298,6 +1402,20 @@ class MeshEngineSearcher:
         self._kw_operand_cache: dict[tuple, object] = {}
 
     # ---- packing ----------------------------------------------------------
+
+    @staticmethod
+    def placed(mesh: Mesh) -> bool:
+        """Are blocks placed on their owning devices (a mesh of several
+        devices) or uploaded to the default one and stacked there?"""
+        return mesh.devices.size > 1
+
+    @staticmethod
+    def composes_in_place(mesh: Mesh, n_shards: int) -> bool:
+        """True where the stacked operands ARE the resident blocks (one
+        shard a device of the shard axis): the pack then holds no bytes
+        of its own for the breaker to book."""
+        return MeshEngineSearcher.placed(mesh) \
+            and n_shards == int(mesh.shape["shard"])
 
     def _slot_layout(self, j: int) -> _SlotLayout:
         np_docs = 0
@@ -1342,7 +1460,7 @@ class MeshEngineSearcher:
         shard_results = []
         for si in range(self.n_shards):
             from elasticsearch_tpu.search.query_dsl import BoolQuery
-            reader = _TemplateReader(self._templates[si], self._views[si])
+            reader = self._readers[si]
             shard_results.append(dfs_mod.shard_dfs(
                 reader, self._mappers[si], BoolQuery(must=list(queries))))
         return dfs_mod.to_execution_stats(
@@ -1510,8 +1628,10 @@ class MeshEngineSearcher:
                         return _build(view, list(cs), emits[j], pfs[j],
                                       refss[j], flags, k)
 
-                    outs = jax.vmap(one)(
-                        jax.tree.map(lambda a, li=li: a[li], consts[j]))
+                    with jax.named_scope("plane_score"):
+                        outs = jax.vmap(one)(
+                            jax.tree.map(lambda a, li=li: a[li],
+                                         consts[j]))
                     b_local = outs["count"].shape[0]
                     if agg_fields:
                         # per-shard metric partials from the query mask,
@@ -1691,9 +1811,10 @@ class MeshEngineSearcher:
                     scores = jnp.concatenate(seg_scores, axis=1)
                     docs = jnp.concatenate(seg_docs, axis=1)
                     kk = min(k, scores.shape[1])
-                    top_s, idx = jax.lax.top_k(
-                        jnp.where(docs >= 0, scores, -jnp.inf), kk)
-                    top_d = jnp.take_along_axis(docs, idx, axis=1)
+                    with jax.named_scope("plane_select"):
+                        top_s, idx = jax.lax.top_k(
+                            jnp.where(docs >= 0, scores, -jnp.inf), kk)
+                        top_d = jnp.take_along_axis(docs, idx, axis=1)
                     top_d = jnp.where(top_s > -jnp.inf,
                                       top_d + shard_off, -1)
                     if kk < k:
@@ -1729,17 +1850,20 @@ class MeshEngineSearcher:
                                   -jnp.inf), k)
                 return _gather_payload(allp, idx)
 
-            local = merge(cand)
+            with jax.named_scope("plane_select"):
+                local = merge(cand)
             # ---- reduce over ICI: per-shard count lane + gathered merge
             counts_stack = jnp.stack(counts_blocks)        # [spd, B]
-            shard_counts = jax.lax.all_gather(
-                counts_stack, "shard")                     # [s_mesh, spd, B]
-            gathered = {name: jax.lax.all_gather(arr, "shard")
-                        for name, arr in local.items()}    # [S, B, k]
+            with jax.named_scope("plane_gather"):
+                shard_counts = jax.lax.all_gather(
+                    counts_stack, "shard")                 # [s_mesh, spd, B]
+                gathered = {name: jax.lax.all_gather(arr, "shard")
+                            for name, arr in local.items()}    # [S, B, k]
             s_ax = next(iter(gathered.values())).shape[0]
-            flat = {name: jnp.moveaxis(arr, 0, 1).reshape(
-                -1, s_ax * k) for name, arr in gathered.items()}
-            g = merge([flat], force=True)
+            with jax.named_scope("plane_merge"):
+                flat = {name: jnp.moveaxis(arr, 0, 1).reshape(
+                    -1, s_ax * k) for name, arr in gathered.items()}
+                g = merge([flat], force=True)
             if sort_mode:
                 g["docs"] = jnp.where(g["inval"] > 0, -1, g["docs"])
                 g["scores"] = jnp.where(g["inval"] > 0, -jnp.inf,
@@ -1974,92 +2098,110 @@ class MeshEngineSearcher:
         # resolve every (shard, slot, query): consts [S, B, ...]; signature
         # must agree across shards AND queries per slot (uniform field
         # layout makes shard structure uniform; mixed query structures are
-        # rejected like _plan_segment_batch's None)
+        # rejected like _plan_segment_batch's None). BM25 `match` nodes of
+        # unequal lengths do agree: every plan pads its term lists to the
+        # batch's widest term bucket (execute.match_term_floor), as the
+        # reader-batch path's do, so a mixed-length _msearch is ONE
+        # dispatch
         sigs, layouts, emits, pfs, refss = [], [], [], [], []
-        consts_dev = []
+        stacked_np = []
         from elasticsearch_tpu.search import jit_exec
-        # the per-slot stacked query constants below are host→device
-        # transfers: one seam draw covers the batch's upload phase
-        jit_exec.device_fault_point("upload")
-        q_sharding = NamedSharding(self.mesh, P("shard", "dp"))
-        for j in range(self.n_slots):
-            sig_j = emit_j = pf_j = refs_j = None
-            rows = []                      # [S][B] → list of const arrays
-            for si in range(self.n_shards):
-                ctx = ExecutionContext(
-                    reader=_TemplateReader(self._templates[si],
-                                           self._views[si]),
-                    mapper_service=self._mappers[si],
-                    bm25=self._bm25,
-                    dfs_stats=dfs_stats)
-                row = []
-                for req in reqs_p:
-                    flags_q = dict(base_flags,
-                                   _min_score=float(req.min_score)
-                                   if req.min_score is not None else 0.0)
-                    if score_cursor:
-                        # in-program (score, doc) continuation with no
-                        # doc pivot: ids > -1 is vacuous, so the mask
-                        # reduces to run_segment's score cursor exactly
-                        flags_q.update(search_after=True,
-                                       _sa_score=float(req.search_after[0]),
-                                       _sa_doc=-1)
-                    ct, emit_q, emit_pf, refs = _plan(
-                        self._templates[si][j], ctx, req.query,
-                        req.post_filter, flags_q)
-                    if sig_j is None:
-                        sig_j, emit_j, pf_j, refs_j = \
-                            ct.signature(), emit_q, emit_pf, refs
-                    elif ct.signature() != sig_j:
-                        raise QueryParsingError(
-                            "mesh engine plane requires one plan signature "
-                            "per batch (mixed query structures)")
-                    row.append(ct.values)
-                rows.append(row)
-            n_c = len(rows[0][0])
-            stacked = tuple(
-                jax.device_put(
+        from elasticsearch_tpu.search.execute import match_term_floor
+        with span("plane.resolve"):
+            floors: dict = {}
+            for mapper in self._mappers:
+                if id(mapper) not in floors:
+                    floors[id(mapper)] = match_term_floor(queries, mapper)
+            ctxs = [ExecutionContext(
+                reader=self._readers[si],
+                mapper_service=self._mappers[si],
+                bm25=self._bm25, dfs_stats=dfs_stats)
+                for si in range(self.n_shards)]
+            for j in range(self.n_slots):
+                sig_j = emit_j = pf_j = refs_j = None
+                rows = []                  # [S][B] → list of const arrays
+                for si in range(self.n_shards):
+                    floor = floors[id(self._mappers[si])]
+                    row = []
+                    for req in reqs:
+                        flags_q = dict(
+                            base_flags,
+                            _min_score=float(req.min_score)
+                            if req.min_score is not None else 0.0)
+                        if score_cursor:
+                            # in-program (score, doc) continuation with
+                            # no doc pivot: ids > -1 is vacuous, so the
+                            # mask reduces to run_segment's score cursor
+                            # exactly
+                            flags_q.update(
+                                search_after=True,
+                                _sa_score=float(req.search_after[0]),
+                                _sa_doc=-1)
+                        ct, emit_q, emit_pf, refs = _plan(
+                            self._templates[si][j], ctxs[si], req.query,
+                            req.post_filter, flags_q, floor)
+                        if sig_j is None:
+                            sig_j, emit_j, pf_j, refs_j = \
+                                ct.signature(), emit_q, emit_pf, refs
+                        elif ct.signature() != sig_j:
+                            raise QueryParsingError(
+                                "mesh engine plane requires one plan "
+                                "signature per batch (mixed query "
+                                "structures) — use the RPC fan-out path")
+                        row.append(ct.values)
+                    # the rows that pad the batch to its bucket repeat
+                    # the last request's constants
+                    rows.append(row + [row[-1]] * (b_pad - b_real))
+                n_c = len(rows[0][0])
+                stacked_np.append(tuple(
                     np.stack([np.stack([rows[si][bi][i]
                                         for bi in range(b_pad)])
-                              for si in range(self.n_shards)]),
-                    q_sharding)
-                for i in range(n_c))
-            sigs.append(sig_j)
-            layouts.append(layout_key(self._templates[0][j]))
-            emits.append(emit_j)
-            pfs.append(pf_j)
-            refss.append(refs_j)
-            consts_dev.append(stacked)
+                              for si in range(self.n_shards)])
+                    for i in range(n_c)))
+                sigs.append(sig_j)
+                layouts.append(layout_key(self._templates[0][j]))
+                emits.append(emit_j)
+                pfs.append(pf_j)
+                refss.append(refs_j)
 
-        # search_after cursor operand: transformed (hi, lo) per spec —
-        # the same key space the program sorts in
-        n_spec = len(sort_specs) if sort_specs else 0
-        cur_np = np.zeros((self.n_shards, b_pad, max(2 * n_spec, 1)),
-                          np.float32)
-        if has_cursor:
-            for bi, req in enumerate(reqs_p):
-                for i, sp in enumerate(sort_specs):
-                    if sp.kind == "keyword":
-                        # string cursor → union rank; a term absent from
-                        # the union sits between its lexicographic
-                        # neighbors (the host path's bisect − 0.5)
-                        _, union = self._kw_sort_ranks(sp.field, sp.fill)
-                        sval = str(req.search_after[i])
-                        pos = bisect.bisect_left(union, sval)
-                        if pos < len(union) and union[pos] == sval:
-                            chi, clo = float(pos), 0.0
+            # search_after cursor operand: transformed (hi, lo) per spec
+            # — the same key space the program sorts in
+            n_spec = len(sort_specs) if sort_specs else 0
+            cur_np = np.zeros((self.n_shards, b_pad, max(2 * n_spec, 1)),
+                              np.float32)
+            if has_cursor:
+                for bi, req in enumerate(reqs_p):
+                    for i, sp in enumerate(sort_specs):
+                        if sp.kind == "keyword":
+                            # string cursor → union rank; a term absent
+                            # from the union sits between its
+                            # lexicographic neighbors (the host path's
+                            # bisect − 0.5)
+                            _, union = self._kw_sort_ranks(sp.field,
+                                                           sp.fill)
+                            sval = str(req.search_after[i])
+                            pos = bisect.bisect_left(union, sval)
+                            if pos < len(union) and union[pos] == sval:
+                                chi, clo = float(pos), 0.0
+                            else:
+                                chi, clo = float(pos) - 0.5, 0.0
                         else:
-                            chi, clo = float(pos) - 0.5, 0.0
-                    else:
-                        chi, clo = _dd_fill(float(req.search_after[i]))
-                    if sp.order == "desc":
-                        chi, clo = -chi, -clo
-                    cur_np[:, bi, 2 * i] = float(chi)
-                    cur_np[:, bi, 2 * i + 1] = float(clo)
-        with device_span("upload") as dsp:
+                            chi, clo = _dd_fill(float(req.search_after[i]))
+                        if sp.order == "desc":
+                            chi, clo = -chi, -clo
+                        cur_np[:, bi, 2 * i] = float(chi)
+                        cur_np[:, bi, 2 * i + 1] = float(clo)
+        # the query constants and the cursors are host→device transfers:
+        # one seam draw covers the batch's upload phase
+        q_sharding = NamedSharding(self.mesh, P("shard", "dp"))
+        with span("plane.upload"), device_span("upload") as dsp:
             jit_exec.device_fault_point("upload")
+            consts_dev = [tuple(jax.device_put(a, q_sharding) for a in tup)
+                          for tup in stacked_np]
             cursors = jax.device_put(cur_np, q_sharding)
-            dsp.set(bytes=int(cur_np.nbytes), kind="cursors")
+            dsp.set(bytes=int(cur_np.nbytes + sum(
+                a.nbytes for tup in stacked_np for a in tup)),
+                kind="query-constants")
         kwsorts = self._kw_rank_operand(sort_specs)
 
         t1 = time.perf_counter()
@@ -2070,24 +2212,43 @@ class MeshEngineSearcher:
             agg_spec=agg_spec, bucket_specs=bucket_specs,
             sort_specs=sort_specs, has_cursor=has_cursor,
             cursors=cursors, kwsorts=kwsorts)
-        from elasticsearch_tpu.search.jit_exec import device_fault_point
-        # the span covers dispatch AND the first host fetches — the
-        # np.asarray calls are where the host actually waits on the
-        # device, so this duration IS the plane's device round trip
-        with device_span("plane-dispatch",
-                         cost=("mesh", prog_key, len(reqs),
-                               b_pad)) as dsp:
-            device_fault_point("plane-dispatch")
-            outs = fn(self._flats, consts_dev, cursors, kwsorts)
-            t2 = time.perf_counter()
-            g_s = np.asarray(outs["scores"])
-            g_d = np.asarray(outs["docs"])
-            totals = np.asarray(outs["totals"])
-            shard_counts = np.asarray(outs["shard_counts"]).reshape(
-                self.n_shards, b_pad)
-            skeys = [(np.asarray(h), np.asarray(l))
-                     for h, l in outs["skeys"]] if sort_specs else None
-            dsp.set(batch=b_pad, shards=self.n_shards)
+        # the launch opens at the enqueue and closes at the END of the
+        # drain (the in-flight book: the chip is not starved while this
+        # program runs). The seam's own span covers enqueue AND drain:
+        # the np.asarray calls are where the host waits on the device,
+        # so its duration — the cost observatory's sample — is the
+        # plane's device round trip, as ever
+        launches = []
+        try:
+            with launch_scope() as launches, \
+                    device_span("plane-dispatch",
+                                cost=("mesh", prog_key, len(reqs),
+                                      b_pad)) as dsp:
+                with span("plane.enqueue"):
+                    jit_exec.device_fault_point("plane-dispatch")
+                    # the enqueue proper under the name every lane's
+                    # has (``jit.enqueue``: jit_exec's host time)
+                    with _enqueue_lock, span("jit.enqueue"):
+                        outs = fn(self._flats, consts_dev, cursors,
+                                  kwsorts)
+                t2 = time.perf_counter()
+                with span("plane.drain"), span("jit.drain"):
+                    g_s = np.asarray(outs["scores"])
+                    g_d = np.asarray(outs["docs"])
+                    totals = np.asarray(outs["totals"])
+                    shard_counts = np.asarray(
+                        outs["shard_counts"]).reshape(self.n_shards, b_pad)
+                    skeys = [(np.asarray(h), np.asarray(l))
+                             for h, l in outs["skeys"]] \
+                        if sort_specs else None
+                dsp.set(batch=b_pad, shards=self.n_shards)
+        finally:
+            close_launches(launches)
+        # S × B × k candidates of (score f32, doc i32[, sort keys]) cross
+        # the shard axis a dispatch, and S × B counts
+        jit_exec.note_plane_dispatch(
+            self.n_shards * b_pad * (k * 4 * (2 + 2 * n_spec
+                                              + bool(sort_specs)) + 4))
         if debug:
             print(f"[mesh-debug] dfs {t_dfs*1e3:.0f}ms "
                   f"plan+stack {(t1-t0-t_dfs)*1e3:.0f}ms "
@@ -2277,6 +2438,7 @@ class _TemplateReader:
     def __init__(self, templates, view):
         self.segments = templates          # DeviceSegment-shaped
         self._view = view
+        self._stats: dict = {}             # field → TextFieldStats
 
     @property
     def num_docs(self) -> int:
@@ -2284,6 +2446,9 @@ class _TemplateReader:
 
     def text_stats(self, field: str):
         from elasticsearch_tpu.index.device_reader import TextFieldStats
+        st = self._stats.get(field)
+        if st is not None:
+            return st
         doc_count = docs_with = total = 0
         for seg in self._view.segments:
             c = seg.text_fields.get(field)
@@ -2291,7 +2456,11 @@ class _TemplateReader:
                 doc_count += seg.num_docs
                 docs_with += int((c.doc_len[:seg.num_docs] > 0).sum())
                 total += c.total_tokens
-        return TextFieldStats(doc_count, docs_with, total)
+        # the view is a point in time: one pass over the lengths a field
+        # (a plan asks for these per term, 512 plans a request of 64)
+        st = self._stats[field] = TextFieldStats(doc_count, docs_with,
+                                                 total)
+        return st
 
     def df(self, field: str, term: str) -> int:
         out = 0

@@ -423,6 +423,9 @@ _stats = {k: 0 for k in lanes.JIT_COUNTERS}
 #: why searches left the compiled/collective path, by label
 #: (ineligible-shape / parse-error / refresh-race / device-error / …)
 _fallback_reasons: dict[str, int] = {}
+#: the same declines counted in search ITEMS (an _msearch of 64 that
+#: falls to the fan-out is one decline of 64 items)
+_plane_items_fallback_reasons: dict[str, int] = {}
 #: why impact-lane admission declined, by label — only bumped for
 #: indices that OPTED IN to the impact plane (the exact scorer is the
 #: default; a disabled index never logs an impact fallback)
@@ -493,6 +496,8 @@ def cache_stats(node_id: str | None = None) -> dict:
         return out
     with _cache_lock:
         out = {**_stats, "fallback_reasons": dict(_fallback_reasons),
+               "plane_items_fallback_reasons":
+                   dict(_plane_items_fallback_reasons),
                "impact_fallback_reasons": dict(_impact_fallback_reasons),
                "knn_fallback_reasons": dict(_knn_fallback_reasons),
                "percolate_fallback_reasons":
@@ -526,19 +531,37 @@ def note_data_refresh(kind: str) -> None:
         _data_layer[key] += 1
 
 
+def note_plane_dispatch(gather_bytes: int) -> None:
+    """One collective-plane program dispatch and the candidate bytes its
+    all_gather moved over the shard axis."""
+    with _cache_lock:
+        _bump("plane_dispatches")
+        _bump("plane_gather_bytes", int(gather_bytes))
+
+
+def note_plane_served(items: int) -> None:
+    """``items`` search items answered by the collective plane."""
+    with _cache_lock:
+        _bump("plane_items_served", int(items))
+
+
 def note_mesh_program(hit: bool) -> None:
     """One collective-plane program-cache lookup (mesh_engine._program)."""
     with _cache_lock:
         _bump("mesh_program_hits" if hit else "mesh_program_misses")
 
 
-def note_plane_fallback(reason: str) -> None:
-    """One collective-plane admission decline, reason-labeled."""
+def note_plane_fallback(reason: str, items: int = 1) -> None:
+    """One collective-plane admission decline of ``items`` search items,
+    reason-labeled."""
     lanes.check_reason("plane", reason)
     _attribution.label("fallback", reason)
     with _cache_lock:
         _bump("plane_fallbacks")
+        _bump("plane_items_fallback", int(items))
         _fallback_reasons[reason] = _fallback_reasons.get(reason, 0) + 1
+        _plane_items_fallback_reasons[reason] = \
+            _plane_items_fallback_reasons.get(reason, 0) + int(items)
         nid = current_node_id()
         if nid is not None:
             bucket = _node_fallback_reasons.setdefault(nid, {})
@@ -576,6 +599,7 @@ def clear_cache() -> None:
         _cache.clear()
         _stats.update({k: 0 for k in _stats})
         _fallback_reasons.clear()
+        _plane_items_fallback_reasons.clear()
         _impact_fallback_reasons.clear()
         _impact_index_stats.clear()
         _knn_fallback_reasons.clear()

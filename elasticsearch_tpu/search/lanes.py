@@ -43,6 +43,15 @@ JIT_COUNTERS = {
     "mesh_program_misses": "collective-plane program trace+compiles",
     "plane_fallbacks": "collective-plane admission declines "
                        "(request served by the RPC fan-out)",
+    "plane_items_served": "search items (an _msearch item counts one) "
+                          "the collective plane answered",
+    "plane_items_fallback": "items of plane admission declines (served "
+                            "by the RPC fan-out; by reason in "
+                            "plane_items_fallback_reasons)",
+    "plane_dispatches": "collective-plane program dispatches (one a "
+                        "served batch)",
+    "plane_gather_bytes": "candidate bytes all_gathered over the shard "
+                          "axis (shards x batch x k x bytes a dispatch)",
     "percolate_program_hits": "fused percolate lane program cache hits",
     "percolate_program_misses": "fused percolate lane trace+compiles",
     "breaker_open_skips": "requests the open plane breaker routed to "

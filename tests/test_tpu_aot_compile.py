@@ -486,3 +486,145 @@ def test_impact_mesh_program_1x4(topo, no_persistent_cache, big):
     assert "all-gather" in text, "no cross-chip candidate merge"
     assert "all-reduce" in text, "no cross-chip count/theta reduction"
 
+
+
+# ---------------------------------------------------------------------------
+# the collective plane at the four-chip cell's shapes
+# ---------------------------------------------------------------------------
+
+PLANE_CELL_SHARDS = 4          # msmarco-bm25-4shard.msearch64-top1000-4chip:
+PLANE_CELL_SHARD_SEGMENTS = 2  # 4 shards x 2 x [1,105,920, 224], one shard
+PLANE_CELL_ROWS = 1_105_920    # a chip of a (1,4) mesh
+
+
+@pytest.fixture(scope="module")
+def plane_cell(tmp_path_factory):
+    """The configuration ``msmarco-passage-bm25-4shard`` at its own
+    shapes: four engines (shards) of two packed segments each. The
+    columns are untouched ``np.zeros`` (15.9 GB of pages nothing backs:
+    the pack takes a column of the layout's shape as it is)."""
+    ms = MapperService()
+    ms.merge("_doc", {"properties": {
+        "body": {"type": "text", "analyzer": "whitespace"}}})
+    w = len(str(VOCAB - 1))
+    terms = [f"t{i:0{w}d}" for i in range(VOCAB)]
+    engines = []
+    for s in range(PLANE_CELL_SHARDS):
+        eng = Engine(tmp_path_factory.mktemp(f"aot_plane_{s}"), ms)
+        for j in range(PLANE_CELL_SHARD_SEGMENTS):
+            eng.install_segment(Segment.from_packed_text(
+                j, "body", terms=terms, tokens=None,
+                uterms=np.zeros((PLANE_CELL_ROWS, BM25_CELL_UNIQUE),
+                                np.int32),
+                utf=np.zeros((PLANE_CELL_ROWS, BM25_CELL_UNIQUE),
+                             np.float32),
+                doc_len=np.ones(PLANE_CELL_ROWS, np.int32),
+                df=np.ones(VOCAB, np.int64), num_docs=PLANE_CELL_ROWS,
+                ids=[""] * PLANE_CELL_ROWS), track_versions=False)
+        engines.append(eng)
+    yield engines, ms
+    for eng in engines:
+        eng.close()
+    from elasticsearch_tpu.parallel import mesh_engine
+    mesh_engine.clear_program_cache()
+    mesh_engine.clear_block_cache()
+
+
+@contextlib.contextmanager
+def plane_steered_to(described):
+    """Steer ``parallel/mesh_engine`` from the test, as :func:`steered_to`
+    steers ``jit_exec``: a block's upload of a large column hands back
+    its shape, operands assembled from such blocks are shapes with their
+    sharding, the shard_map is built over the ``described`` mesh, and the
+    program is lowered for it — its ``Compiled`` leaves through
+    :class:`_Captured`."""
+    from jax.sharding import NamedSharding
+    from elasticsearch_tpu.parallel import mesh as mesh_mod
+    from elasticsearch_tpu.parallel import mesh_engine
+
+    def device_put(a, *args, **kw):
+        if getattr(a, "nbytes", 0) > 1 << 24:
+            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+        return jax.device_put(a, *args, **kw)
+
+    def make_array(shape, sharding, bufs):
+        if any(isinstance(b, jax.ShapeDtypeStruct) for b in bufs):
+            return jax.ShapeDtypeStruct(shape, bufs[0].dtype,
+                                        sharding=sharding)
+        return jax.make_array_from_single_device_arrays(shape, sharding,
+                                                        bufs)
+
+    class Lowered:
+        def __init__(self, lowered):
+            self.lowered = lowered
+
+        def compile(self):
+            raise _Captured(self.lowered.compile())
+
+    class Jitted:
+        def __init__(self, fn, **kw):
+            self.jitted = jax.jit(fn, **kw)
+
+        def lower(self, *args):
+            return Lowered(self.jitted.lower(*jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=NamedSharding(described, a.sharding.spec)),
+                args)))
+
+    proxy = types.SimpleNamespace(
+        **{n: getattr(jax, n) for n in dir(jax) if not n.startswith("__")})
+    proxy.device_put = device_put
+    proxy.make_array_from_single_device_arrays = make_array
+    proxy.jit = Jitted
+    real_jax, real_sm = mesh_engine.jax, mesh_mod.shard_map_compat
+    mesh_engine.jax = proxy
+    mesh_mod.shard_map_compat = lambda f, mesh, in_specs, out_specs: \
+        real_sm(f, mesh=described, in_specs=in_specs, out_specs=out_specs)
+    try:
+        yield
+    finally:
+        mesh_engine.jax = real_jax
+        mesh_mod.shard_map_compat = real_sm
+
+
+def test_plane_program_at_the_4shard_cells_shapes(
+        topo, no_persistent_cache, plane_cell):
+    """A request of ``msmarco-bm25-4shard.msearch64-top1000-4chip`` — 64
+    `match` queries of 2 to 12 terms, size 1000, over 4 shards x 2 x
+    [1,105,920, 224] on a (1,4) mesh — plans to ONE plane program (term
+    lists padded to 12, k bucket 1024) that compiles for four described
+    chips: a quarter of the columns a device, the shards' candidates
+    met by an all-gather, and two programs in flight (two clients) fit
+    HBM beside the resident columns."""
+    from elasticsearch_tpu.parallel.mesh import make_mesh
+    from elasticsearch_tpu.parallel.mesh_engine import MeshEngineSearcher
+    engines, ms = plane_cell
+    cpu_mesh = make_mesh(dp=1, shard=4, devices=jax.devices()[:4])
+    tpu_mesh = make_mesh(dp=1, shard=4, devices=topo.devices)
+    w = len(str(VOCAB - 1))
+    lengths = [2 + (7 * i) % 11 for i in range(BATCH)]
+    bodies = [{"query": {"match": {"body": " ".join(
+        f"t{(17 * i + 5 * j) % VOCAB:0{w}d}" for j in range(ln))}},
+        "size": 1000} for i, ln in enumerate(lengths)]
+    before = jit_exec.cache_stats()["mesh_program_misses"]
+    with plane_steered_to(tpu_mesh):
+        searcher = MeshEngineSearcher(cpu_mesh, engines, ms)
+        assert searcher.spd == 1 and searcher.n_slots == 2
+        compiled = captured(searcher.search_batch, bodies,
+                            global_stats=False)
+    assert jit_exec.cache_stats()["mesh_program_misses"] - before == 1
+    ma = fits_hbm(compiled, "plane (1,4) mixed msearch64 k=1024, "
+                            "2 x [1105920, 224] a device")
+    shard_columns = PLANE_CELL_SHARD_SEGMENTS * PLANE_CELL_ROWS \
+        * BM25_CELL_UNIQUE * (4 + 4)
+    # a device holds ITS shard's columns and no other's
+    assert shard_columns <= ma.argument_size_in_bytes < 1.1 * shard_columns
+    assert ma.argument_size_in_bytes + 2 * (
+        ma.temp_size_in_bytes + ma.output_size_in_bytes) < HBM_BYTES
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_step_local"), text[:80]
+    assert "all-gather" in text, "no cross-chip candidate merge"
+    for scope in ("plane_score", "plane_select", "plane_gather",
+                  "plane_merge", "bm25_score"):
+        assert scope in text, scope
