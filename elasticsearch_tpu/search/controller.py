@@ -152,26 +152,52 @@ def merge_shard_payloads(req: ParsedSearchRequest, payloads: list[dict],
                              total_shards, failures)
 
 
+def _page_by_score(results: list[ShardQueryResult],
+                   req: ParsedSearchRequest) -> tuple[np.ndarray, np.ndarray]:
+    """The [from, from+size) page of score-ordered results as (shard
+    index, position) arrays: one stable sort of the concatenated scores,
+    descending — the rows arrive in (shard, position) order, so a tie
+    keeps it, the order :func:`sort_docs` gives."""
+    lens = [len(r.doc_ids) for r in results]
+    if not sum(lens):
+        return np.zeros(0, np.int64), np.zeros(0, np.int64)
+    scores = np.concatenate([np.asarray(r.scores[:n], np.float64)
+                             for r, n in zip(results, lens)])
+    page = np.argsort(-scores, kind="stable")[req.from_: req.from_ + req.size]
+    starts = np.cumsum([0] + lens[:-1])
+    shard_of = np.searchsorted(starts, page, side="right") - 1
+    return shard_of, page - starts[shard_of]
+
+
 def merge_responses(index_name: str | list, req: ParsedSearchRequest,
                     results: list[ShardQueryResult], searchers,
                     took_ms: float, agg_nodes) -> dict:
     """`index_name` is one name, or one name PER SEARCHER — the
     collective plane's multi-index batches merge shards of several
-    indices in one result list and each hit must render its owner."""
+    indices in one result list and each hit must render its owner.
+    Score-ordered results merge by one array sort; field-sorted ones
+    (mixed types, ``missing``, strings) by :func:`sort_docs`."""
+    from elasticsearch_tpu.search import jit_exec
     names = list(index_name) if isinstance(index_name, (list, tuple)) \
         else [index_name] * len(searchers)
-    page = sort_docs(results, req)
-    # fetch phase only on shards owning winning docs (fillDocIdsToLoad)
-    by_shard: dict[int, list[int]] = {}
-    for ref in page:
-        by_shard.setdefault(ref.shard_idx, []).append(ref.position)
-    fetched: dict[tuple[int, int], dict] = {}
-    for si, positions in by_shard.items():
+    by_score = all(r.sort_values is None for r in results)
+    jit_exec.note_merge(by_score)
+    if by_score:
+        shard_of, pos_of = _page_by_score(results, req)
+    else:
+        page = sort_docs(results, req)
+        shard_of = np.asarray([ref.shard_idx for ref in page], np.int64)
+        pos_of = np.asarray([ref.position for ref in page], np.int64)
+    # fetch phase only on shards owning winning docs (fillDocIdsToLoad):
+    # one call a shard, in the order the page first meets it, with its
+    # positions in page order; each hit goes back to its page slot
+    hits_out: list = [None] * len(shard_of)
+    for si in dict.fromkeys(shard_of.tolist()):
+        slots = np.flatnonzero(shard_of == si)
         hits = searchers[si].fetch_phase(req, results[si], names[si],
-                                         positions)
-        for pos, hit in zip(positions, hits):
-            fetched[(si, pos)] = hit
-    hits_out = [fetched[(ref.shard_idx, ref.position)] for ref in page]
+                                         pos_of[slots].tolist())
+        for slot, hit in zip(slots.tolist(), hits):
+            hits_out[slot] = hit
 
     total = sum(r.total for r in results)
     max_scores = [r.max_score for r in results if r.max_score is not None]

@@ -2229,6 +2229,15 @@ def note_msearch_items(n_items: int, batched: bool) -> None:
               else "msearch_items_serial", int(n_items))
 
 
+def note_merge(by_score: bool) -> None:
+    """One search item whose shard results the coordinator's
+    ``merge_responses`` ordered: by one array sort (score order), or by
+    the field-sort comparator."""
+    with _cache_lock:
+        _bump("merge_items_array" if by_score
+              else "merge_items_comparator")
+
+
 def note_knn_rows(real: int, padded: int) -> None:
     """One knn-lane dispatch of ``real`` request rows and ``padded``
     no-op rows (the power-of-two batch bucket's price: a padded row is

@@ -78,6 +78,11 @@ JIT_COUNTERS = {
                              "batched dispatch (query_phase_batch)",
     "msearch_items_serial": "shard-side _msearch items that fell to the "
                             "one-by-one query phase",
+    "merge_items_array": "search items whose shard results the "
+                         "coordinator merged by one array sort (score "
+                         "order)",
+    "merge_items_comparator": "search items the coordinator merged by "
+                              "the field-sort comparator",
     "fusion_dispatches": "in-program hybrid fusion dispatches",
     "maxsim_dispatches": "fused MaxSim dispatches over rank_vectors",
     "rescore_fused_dispatches": "impact→rescore plans composed into one "

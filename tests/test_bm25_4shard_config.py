@@ -161,7 +161,8 @@ def deployment(tmp_path_factory):
             "counters": {k: after[k] - before[k] for k in (
                 "plane_items_served", "plane_items_fallback",
                 "plane_dispatches", "plane_gather_bytes",
-                "plane_fallbacks")},
+                "plane_fallbacks", "merge_items_array",
+                "merge_items_comparator")},
             "book": {k: tracing.book_stats()[k] - book[k] for k in (
                 "launches", "launches_without_drain")}}
     yield out
@@ -248,6 +249,13 @@ def test_a_mixed_length_msearch_is_one_mesh_dispatch(deployment, seed):
     assert c["plane_gather_bytes"] == 4 * ITEMS * (64 * 8 + 4)
 
 
+@pytest.mark.parametrize("seed", SEEDS)
+def test_a_score_ordered_msearch_merges_by_the_array_sort(deployment, seed):
+    c = deployment["seeds"][seed]["counters"]
+    assert c["merge_items_array"] == ITEMS
+    assert c["merge_items_comparator"] == 0
+
+
 def test_the_plane_launch_closes_at_its_drain(deployment):
     for d in deployment["seeds"].values():
         assert d["book"]["launches"] >= 1
@@ -304,7 +312,8 @@ def test_the_geometry_is_in_nodes_stats(deployment):
     b = deployment["b"].local_node_stats()["device"]["mesh"]
     assert (b["dp"], b["shard"], b["setting"]) == (1, 1, None)
     for key in ("plane_items_served", "plane_items_fallback",
-                "plane_dispatches", "plane_gather_bytes"):
+                "plane_dispatches", "plane_gather_bytes",
+                "merge_items_array", "merge_items_comparator"):
         assert key in node_doc["indices"]["jit"]
 
 
@@ -352,3 +361,35 @@ def test_two_shards_a_device_and_dp_replicas_give_the_same_hits(
         server.stop()
         node.close()
     assert jit_exec.serving_mesh() is not mesh
+
+
+def test_a_field_sorted_search_merges_by_the_comparator(deployment):
+    """A ``sort`` on a numeric field, served by the plane on the ``1x4``
+    mesh, is ordered at the coordinator by the comparator: one item."""
+    ra = deployment["ra"]
+    ra.call("PUT", "/sorted", {
+        "settings": {"number_of_shards": 4, "number_of_replicas": 0},
+        "mappings": {"_doc": {"properties": {
+            "body": {"type": "text", "analyzer": "whitespace"},
+            "n": {"type": "long"}}}}})
+    ra.call("GET", "/_cluster/health/sorted"
+                   "?wait_for_status=green&timeout=30s")
+    lines = []
+    for i in range(40):
+        lines += [json.dumps({"index": {"_index": "sorted", "_type": "_doc",
+                                        "_id": str(i)}}),
+                  json.dumps({"body": f"w{i % 3} w9", "n": (i * 7) % 40})]
+    assert not ra.call("POST", "/_bulk", "\n".join(lines) + "\n")["errors"]
+    ra.call("POST", "/sorted/_refresh")
+    before = jit_exec.cache_stats()
+    out = ra.call("POST", "/sorted/_search", {
+        "query": {"match": {"body": "w9"}}, "size": 15,
+        "sort": [{"n": {"order": "desc"}}]})
+    after = jit_exec.cache_stats()
+    assert [h["sort"][0] for h in out["hits"]["hits"]] == \
+        list(range(39, 24, -1))
+    assert after["plane_items_served"] - before["plane_items_served"] == 1
+    assert after["merge_items_comparator"] - \
+        before["merge_items_comparator"] == 1
+    assert after["merge_items_array"] == before["merge_items_array"]
+    ra.call("DELETE", "/sorted")
