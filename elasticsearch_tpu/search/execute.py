@@ -61,7 +61,8 @@ class ConstTable:
     """
 
     __slots__ = ("values", "sig", "positions_needed", "vectors_needed",
-                 "match_terms_real", "match_terms_padded")
+                 "match_terms_real", "match_terms_padded",
+                 "phrase_terms_real", "phrase_terms_padded")
 
     def __init__(self):
         self.values: list[np.ndarray] = []
@@ -80,6 +81,9 @@ class ConstTable:
         # terms that padded their lists to the term bucket
         self.match_terms_real = 0
         self.match_terms_padded = 0
+        # the same for exact phrases (pads match every position)
+        self.phrase_terms_real = 0
+        self.phrase_terms_padded = 0
 
     def add(self, v, dtype=None) -> int:
         arr = np.asarray(v, dtype=dtype)
@@ -250,31 +254,42 @@ def _search_analyzer(ms, field: str, override: str | None):
     return ms.analysis.get("standard")
 
 
-def match_term_floor(queries: list, mapper_service) -> int:
-    """The widest term bucket among a batch's ``match`` nodes: one walk
-    of the parsed queries, analysis only (no segment, no statistics).
-    Handed to every query's :class:`SegmentResolver`, it makes a batch of
-    unequal lengths pad to ONE width and share one compiled plan. A
-    ``match`` the walk does not reach (a rewrite made while resolving,
-    as ``multi_match`` does) keeps its own bucket: the batch may then
-    decline, never answer wrongly."""
-    floor = 1
+def _query_nodes(queries: list):
+    """Every node of the parsed queries, sub-queries included."""
     stack = list(queries)
     while stack:
         node = stack.pop()
-        if isinstance(node, q.MatchQuery):
-            analyzer = _search_analyzer(mapper_service, node.field,
-                                        node.analyzer)
-            floor = max(floor, term_bucket(
-                len(analyzer.analyze(node.text))))
-            continue
+        yield node
         for v in vars(node).values():
             if isinstance(v, (list, tuple)):
                 stack.extend(x for x in v
                              if hasattr(x, "__dataclass_fields__"))
             elif hasattr(v, "__dataclass_fields__"):
                 stack.append(v)
+
+
+def match_term_floor(queries: list, mapper_service) -> int:
+    """The widest term bucket among a batch's ``match`` and
+    ``match_phrase`` nodes: one walk of the parsed queries, analysis only
+    (no segment, no statistics). Handed to every query's
+    :class:`SegmentResolver`, it makes a batch of unequal lengths pad to
+    ONE width and share one compiled plan. A node the walk does not reach
+    (a rewrite made while resolving, as ``multi_match`` does) keeps its
+    own bucket: the batch may then decline, never answer wrongly."""
+    floor = 1
+    for node in _query_nodes(queries):
+        if isinstance(node, (q.MatchQuery, q.MatchPhraseQuery)):
+            analyzer = _search_analyzer(mapper_service, node.field,
+                                        node.analyzer)
+            floor = max(floor, term_bucket(
+                len(analyzer.analyze(node.text))))
     return floor
+
+
+def bears_phrase(query) -> bool:
+    """Whether a parsed query holds a ``match_phrase`` node."""
+    return any(isinstance(node, q.MatchPhraseQuery)
+               for node in _query_nodes([query]))
 
 
 class SegmentResolver:
@@ -473,7 +488,11 @@ class SegmentResolver:
         if query.operator == "and":
             required = len(terms)
         elif query.minimum_should_match is not None:
-            required = _resolve_msm(query.minimum_should_match, len(terms))
+            # an `or` match is a should-only BooleanQuery: Lucene matches
+            # a document that holds at least one clause whatever the
+            # minimum resolves to ("30%" of 2 or 3 terms resolves to 0)
+            required = max(_resolve_msm(query.minimum_should_match,
+                                        len(terms)), 1)
         else:
             required = 1
         similarity = self._similarity_for(field)
@@ -616,8 +635,12 @@ class SegmentResolver:
         tids, idfs = resolved
         deltas = [t.position - toks[0].position for t in toks]
         slop = query.slop
-        self.sig("phrase", tuple(deltas), slop)
         self.ct.positions_needed.add(field)
+        if slop == 0 and deltas == list(range(len(toks))):
+            return self._exact_phrase(query, field, tids, idfs)
+        # a phrase with gaps (a stop filter) or a slop keeps its exact
+        # signature: a batch that mixes it with others may decline
+        self.sig("phrase", tuple(deltas), slop)
         p = self.ctx.bm25
         r_tids = [self.c(t, np.int32) for t in tids]
         r_idfs = self.c(idfs, np.float32)
@@ -636,6 +659,35 @@ class SegmentResolver:
                 scores, mask = phrase_ops.phrase_score(
                     col.tokens, col.doc_len, tid_scalars, deltas,
                     em.get(r_sum_idf), p.k1, p.b, em.get(r_avgdl))
+            return scores * em.get(r_boost), mask
+        return emit
+
+    def _exact_phrase(self, query: q.MatchPhraseQuery, field: str,
+                      tids: list[int], idfs: list[float]) -> Emit:
+        """A phrase of consecutive terms, slop 0. Like a BM25 ``match`` its
+        term list pads to a term bucket — the batch's widest, or its own —
+        so phrases of unequal lengths share one plan: a pad term
+        (``PAD_TID``) matches every position, past the document's end too,
+        so the padded frequency is the unpadded one exactly. A term absent
+        from this segment (id -1) stays in its place and matches nothing."""
+        n_terms = max(term_bucket(len(tids)), self.term_floor)
+        pad = n_terms - len(tids)
+        self.sig("phrase-exact", n_terms)
+        self.ct.phrase_terms_real += len(tids)
+        self.ct.phrase_terms_padded += pad
+        r_tids = self.c(tids + [phrase_ops.PAD_TID] * pad, np.int32)
+        r_sum_idf = self.c(sum(idfs), np.float32)
+        r_avgdl = self.c(self._avgdl(field), np.float32)
+        r_boost = self.c(query.boost, np.float32)
+        p = self.ctx.bm25
+
+        def emit(em):
+            col = em.seg.text[field]
+            freq = phrase_ops.exact_phrase_freq(col.tokens,
+                                                jnp.asarray(em.get(r_tids)))
+            scores, mask = phrase_ops.freq_score(
+                freq, col.doc_len, em.get(r_sum_idf), p.k1, p.b,
+                em.get(r_avgdl))
             return scores * em.get(r_boost), mask
         return emit
 
@@ -979,11 +1031,16 @@ class SegmentResolver:
         should = [self.resolve(sub) for sub in query.should]
         must_not = [self.resolve_mask(sub) for sub in query.must_not]
         filters = [self.resolve_mask(sub) for sub in query.filter]
+        should_only = bool(query.should) and not query.must \
+            and not query.filter
         if query.minimum_should_match is not None:
+            # with no required clause Lucene matches a document through
+            # at least one should clause, whatever the minimum resolves to
             msm = _resolve_msm(query.minimum_should_match, len(query.should))
+            if should_only:
+                msm = max(msm, 1)
         else:
-            msm = 1 if (query.should and not query.must and not query.filter) \
-                else 0
+            msm = 1 if should_only else 0
         r_msm = self.c(msm, np.int32) if should else None
         r_boost = self.c(query.boost, np.float32)
 

@@ -47,7 +47,7 @@ from elasticsearch_tpu.ops import topk as topk_ops
 from elasticsearch_tpu.search import lanes
 from elasticsearch_tpu.search.execute import (
     ConstTable, EmitCtx, ExecutionContext, SegmentResolver,
-    match_term_floor)
+    bears_phrase, match_term_floor)
 
 _CACHE_CAP = 512
 _cache: OrderedDict[tuple, "jax.stages.Wrapped"] = OrderedDict()
@@ -642,14 +642,17 @@ def _fetch(seg: DeviceSegment, col, attr: str):
     vecs) onto the reader's device on first use. The result is cached back
     on the column object so the transfer happens once per reader
     generation; the lock stops concurrent first-phrase-queries from
-    shipping the same hundreds of MB twice."""
+    shipping the same hundreds of MB twice. The transfer is a
+    ``jit.upload`` span of whatever request first reads the column (the
+    reader booked its bytes when it was built)."""
     a = getattr(col, attr)
     if seg.lazy_put is None or not isinstance(a, np.ndarray):
         return a
     with _materialize_lock:
         a = getattr(col, attr)
         if isinstance(a, np.ndarray):
-            a = seg.lazy_put(a)
+            with device_span("upload"):
+                a = seg.lazy_put(a)
             setattr(col, attr, a)
     return a
 
@@ -914,7 +917,8 @@ def _plan_segment_batch(seg: DeviceSegment, ctx: ExecutionContext,
     pos_for: frozenset = frozenset()
     vecs: frozenset = frozenset()
     consts_rows: list[list[np.ndarray]] = []
-    match_terms: list[tuple[int, int]] = []     # per query: (real, pads)
+    # per query: (real, pads) of its match terms, then of its phrase terms
+    match_terms: list[tuple[int, int, int, int]] = []
     for query in queries:
         ct, emit_q, _, refs = _plan(seg, ctx, query, None, flags,
                                     term_floor)
@@ -925,7 +929,8 @@ def _plan_segment_batch(seg: DeviceSegment, ctx: ExecutionContext,
         elif ct.signature() != sig0:
             return None
         consts_rows.append(ct.values)
-        match_terms.append((ct.match_terms_real, ct.match_terms_padded))
+        match_terms.append((ct.match_terms_real, ct.match_terms_padded,
+                            ct.phrase_terms_real, ct.phrase_terms_padded))
 
     b = len(queries)
     # pad the batch axis to the next power of two (repeating the last
@@ -1002,6 +1007,7 @@ def run_reader_batch(segments: list, ctx: ExecutionContext, queries: list,
         for seg in segments:
             plan = _plan_segment_batch(seg, ctx, queries, k_static)
             if plan is None:
+                note_phrase_items(queries[:n_real], batched=False)
                 return None
             plans.append(plan)
         b = len(queries)
@@ -1054,6 +1060,7 @@ def run_reader_batch(segments: list, ctx: ExecutionContext, queries: list,
         out = fn(flats, packeds)
     note_match_terms(plans[0]["match_terms"][:b if n_real is None
                                              else n_real])
+    note_phrase_items(queries[:n_real], batched=True)
     if b_pad != b:
         out = out[:b] if pack else {name: v[:b] for name, v in out.items()}
     return out
@@ -2213,11 +2220,26 @@ def note_knn_served(index_name: str | None, n_requests: int,
 
 def note_match_terms(rows: list) -> None:
     """One reader-batch dispatch whose real rows scored ``rows``: per
-    query (real BM25 ``match`` terms, pad terms) — the term bucket's
-    price: a pad term is compared with every slot like a real one."""
+    query (real BM25 ``match`` terms, pad terms, real exact-phrase terms,
+    pad terms) — the term bucket's price: a pad term is compared with
+    every slot (or position) like a real one."""
     with _cache_lock:
-        _bump("match_terms_real", sum(r for r, _ in rows))
-        _bump("match_terms_padded", sum(p for _, p in rows))
+        _bump("match_terms_real", sum(r[0] for r in rows))
+        _bump("match_terms_padded", sum(r[1] for r in rows))
+        _bump("phrase_terms_real", sum(r[2] for r in rows))
+        _bump("phrase_terms_padded", sum(r[3] for r in rows))
+
+
+def note_phrase_items(queries: list, batched: bool) -> None:
+    """The real rows of one reader batch: those that hold a
+    ``match_phrase`` were answered by the batch's one dispatch, or — when
+    the rows' plans did not share one signature — fall to the
+    query-by-query path."""
+    n = sum(1 for query in queries if bears_phrase(query))
+    if n:
+        with _cache_lock:
+            _bump("phrase_items_batched" if batched
+                  else "phrase_items_serial", n)
 
 
 def note_msearch_items(n_items: int, batched: bool) -> None:

@@ -74,6 +74,17 @@ JIT_COUNTERS = {
     "match_terms_padded": "absent terms that padded those rows' term "
                           "lists to the batch's term bucket (compared "
                           "with every slot, never scoring)",
+    "phrase_terms_real": "terms of consecutive-term match_phrase nodes "
+                         "the real rows of reader-batch dispatches "
+                         "compared",
+    "phrase_terms_padded": "pad terms that filled those phrases' term "
+                           "lists to the batch's term bucket (matching "
+                           "every position)",
+    "phrase_items_batched": "search items holding a match_phrase that one "
+                            "reader-batch dispatch answered",
+    "phrase_items_serial": "search items holding a match_phrase that fell "
+                           "to the query-by-query path because the "
+                           "batch's plans did not share one signature",
     "msearch_items_batched": "shard-side _msearch items answered by one "
                              "batched dispatch (query_phase_batch)",
     "msearch_items_serial": "shard-side _msearch items that fell to the "

@@ -355,3 +355,64 @@ class TestBulkIngest:
         assert e2.get("0").found
         assert not e2.get("2").found
         e2.close()
+
+
+def _docs_holding(node, name, terms, at_least):
+    """Documents of ``_mk``'s index that hold ``at_least`` of ``terms``."""
+    out = 0
+    for i in range(node.indices_service.indices[name].engine(0)
+                   .acquire_searcher().segments[0].num_docs):
+        words = {"alpha", "beta", f"word{i % 7}", f"word{i % 11}"}
+        out += len(words & set(terms)) >= at_least
+    return out
+
+
+class TestShouldOnlyMinimum:
+    """A ``match`` is a should-only BooleanQuery: whatever its
+    ``minimum_should_match`` resolves to, Lucene matches a document
+    through one of its terms at least ("30%" of 2 or 3 terms is 0)."""
+
+    def test_thirty_percent_of_two_terms_needs_one(self, node):
+        node.indices_service.create_index(
+            "probe", {"settings": {"number_of_shards": 1,
+                                   "number_of_replicas": 0}})
+        for i in range(61):
+            text = "a x" if i < 5 else ("b y" if i < 9 else "c z")
+            node.index_doc("probe", str(i), {"t": text})
+        node.broadcast_actions.refresh("probe")
+        s = _searcher(node, "probe")
+        req = parse_search_request({"query": {"match": {"t": {
+            "query": "a b", "minimum_should_match": "30%"}}}, "size": 61})
+        assert s.query_phase(req).total == 9
+        (got,) = s.query_phase_batch([req])
+        assert got.total == 9 and len(got.doc_ids) == 9
+        # and a should-only bool whose minimum resolves to 0 as well
+        bool_req = parse_search_request({"query": {"bool": {
+            "should": [{"match": {"t": "a"}}, {"match": {"t": "b"}}],
+            "minimum_should_match": "30%"}}, "size": 61})
+        assert s.query_phase(bool_req).total == 9
+
+    def test_two_and_three_term_thirty_percent_batch_with_longer(
+            self, node):
+        _mk(node, "idx", 120)
+        s = _searcher(node, "idx")
+        texts = ["word1 word9", "alpha word3 word10",
+                 "word0 word4 beta word5 word9",
+                 "alpha beta word0 word1 word2 word3 word4 word5 word6 "
+                 "word7 word8 word10"]
+        reqs = [parse_search_request({"query": {"match": {"t": {
+            "query": t, "minimum_should_match": "30%"}}}, "size": 120})
+            for t in texts]
+        jit_exec.clear_cache()
+        before = jit_exec.cache_stats()
+        batch = s.query_phase_batch(reqs)
+        assert batch is not None
+        assert jit_exec.cache_stats()["misses"] - before["misses"] == 1
+        for text, req, got in zip(texts, reqs, batch):
+            terms = text.split()
+            need = max(len(terms) * 30 // 100, 1)
+            assert got.total == _docs_holding(node, "idx", terms, need)
+            ref = s.query_phase(req)
+            assert got.total == ref.total
+            np.testing.assert_array_equal(got.doc_ids, ref.doc_ids)
+            np.testing.assert_allclose(got.scores, ref.scores, rtol=1e-6)

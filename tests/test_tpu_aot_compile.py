@@ -628,3 +628,90 @@ def test_plane_program_at_the_4shard_cells_shapes(
     for scope in ("plane_score", "plane_select", "plane_gather",
                   "plane_merge", "bm25_score"):
         assert scope in text, scope
+
+
+PHRASE_CELL_SEGMENTS = 4  # msmarco-phrase.bool-phrase-top10-c16
+
+
+@pytest.fixture(scope="module")
+def phrase_cell(tmp_path_factory):
+    """The benchmark configuration ``msmarco-passage-bm25-phrase`` at its
+    own shapes: four packed [2^20, 224] segments WITH their [2^20, 224]
+    position matrices (untouched ``np.zeros``, as ``bm25_cell``'s)."""
+    ms = MapperService()
+    ms.merge("_doc", {"properties": {
+        "body": {"type": "text", "analyzer": "whitespace"}}})
+    eng = Engine(tmp_path_factory.mktemp("aot_phrase_cell"), ms)
+    w = len(str(VOCAB - 1))
+    terms = [f"t{i:0{w}d}" for i in range(VOCAB)]
+    for _ in range(PHRASE_CELL_SEGMENTS):
+        eng.install_segment(Segment.from_packed_text(
+            0, "body", terms=terms,
+            tokens=np.zeros((ROWS, BM25_CELL_UNIQUE), np.int32),
+            uterms=np.zeros((ROWS, BM25_CELL_UNIQUE), np.int32),
+            utf=np.zeros((ROWS, BM25_CELL_UNIQUE), np.float32),
+            doc_len=np.ones(ROWS, np.int32), df=np.ones(VOCAB, np.int64),
+            num_docs=ROWS, ids=[""] * ROWS), track_versions=False)
+    yield eng, ms
+    eng.close()
+    jit_exec.clear_cache()
+
+
+def test_phrase_program_at_the_benchmark_cells_shapes(
+        topo, no_persistent_cache, phrase_cell):
+    """A batch of 16 bodies of ``msmarco-phrase.bool-phrase-top10-c16`` —
+    bool must match (msm 30%) + should match_phrase, 9 to 12 terms (the
+    widest term bucket), size 10, over 4 x [2^20, 224] columns and
+    positions — plans to ONE `reader-batch` program, which compiles for
+    the chip and fits HBM twice beside the resident columns: the phrase
+    pass keeps no [B, N, L] array."""
+    from jax.sharding import SingleDeviceSharding
+    eng, ms = phrase_cell
+    chip = SingleDeviceSharding(topo.devices[0])
+    w = len(str(VOCAB - 1))
+    reqs = []
+    for i in range(16):
+        text = " ".join(f"t{(17 * i + 5 * j) % VOCAB:0{w}d}"
+                        for j in range(9 + i % 4))
+        reqs.append(parse_search_request({"query": {"bool": {
+            "must": [{"match": {"body": {
+                "query": text, "minimum_should_match": "30%"}}}],
+            "should": [{"match_phrase": {"body": text}}]}}, "size": 10}))
+    before = jit_exec.cache_stats()["misses"]
+    with reader_uploads_as_shapes():
+        searcher = ShardSearcher(0, device_reader_for(eng), ms)
+        with steered_to(lambda a: chip):
+            compiled = captured(searcher.query_phase_batch, reqs)
+    assert jit_exec.cache_stats()["misses"] - before == 1
+    ma = fits_hbm(compiled, "phrase cell B=16 k=10, 4 x [2^20, 224] x 3")
+    columns = PHRASE_CELL_SEGMENTS * ROWS * BM25_CELL_UNIQUE * (4 + 4 + 4)
+    assert ma.argument_size_in_bytes >= columns
+    assert ma.argument_size_in_bytes + 2 * (
+        ma.temp_size_in_bytes + ma.output_size_in_bytes) < HBM_BYTES
+    assert "phrase_match" in compiled.as_text()
+
+
+@pytest.mark.parametrize("deltas", [tuple(range(12)), (0, 2, 3)])
+def test_phrase_kernel_at_the_benchmark_cells_shapes(topo,
+                                                     no_persistent_cache,
+                                                     deltas):
+    """The exact-phrase kernel alone over one [2^20, 224] position matrix,
+    B = 16: consecutive terms (the padded phrase) and a phrase with a gap
+    (a stop filter) are one `tpu_custom_call` each, the transposed matrix
+    is a bitcast of the one on the device (no copy), and nothing of
+    [B, N, L] or a shifted copy is kept."""
+    from jax.sharding import SingleDeviceSharding
+    from elasticsearch_tpu.ops import phrase as phrase_ops
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled = jax.jit(
+        lambda t, q: phrase_ops._phrase_counts_tpu(t, q, deltas)).lower(
+        jax.ShapeDtypeStruct((ROWS, BM25_CELL_UNIQUE), jnp.int32,
+                             sharding=chip),
+        jax.ShapeDtypeStruct((16, len(deltas)), jnp.int32,
+                             sharding=chip)).compile()
+    ma = fits_hbm(compiled, f"phrase kernel deltas={deltas}")
+    assert ma.temp_size_in_bytes < ROWS * BM25_CELL_UNIQUE * 4 // 8
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not [ln for ln in text.splitlines()
+                if str(ROWS) in ln and (" copy(" in ln or " transpose(" in ln)]
