@@ -1,29 +1,93 @@
 """Top-k selection and cross-shard/segment merge.
 
 Lucene's TopScoreDocCollector heap (core/search/query/QueryPhase.java:196)
-becomes ``lax.top_k``; the coordinator's cross-shard merge
+becomes :func:`select_top_k`; the coordinator's cross-shard merge
 (SearchPhaseController.sortDocs via TopDocs.merge,
 core/search/controller/SearchPhaseController.java:165-268) becomes a
 concat + re-top-k that stays on device — inside shard_map it runs after an
 all_gather over the shard mesh axis so the whole scatter-gather-reduce is
 one XLA program over ICI.
 
-Tie-breaking matches Lucene exactly because ``lax.top_k`` is stable (equal
-values → lower index first): within a segment, index order == doc id order;
-across shards, concatenating in shard order before re-top-k reproduces
-TopDocs.merge's (shard index, position) tie-break. (Measured otherwise on
-the TPU v5e at k = 1000: PERF.md section 7, fault 1 — the benchmark counts
-such pairs as ``ties_not_by_id``, for the BM25 and the knn cells alike.)
+Every top-k of a long row of scores is taken in two exact stages
+(:func:`select_top_k`): rank the maxima of fixed-length blocks, then rank
+the scores of the k chosen blocks, gathered in index order. Exactness:
+every score above the k-th chosen block maximum lies in a chosen block,
+and the k chosen maxima are k scores at or above it, so the k served
+scores are, as a multiset, the whole row's top k — whatever order the
+device gives equal maxima. Short rows (the merges over shards × k
+candidates among them) keep one ``lax.top_k``.
+
+Ties: the CPU backend's ``lax.top_k`` puts equal values at the lower index
+first, and the two stages then give its indices too (a block left out has
+k chosen blocks before it, each holding a score that is larger, or equal
+and at a lower index). The TPU's is not stable: at k = 1000 equal scores
+leave a segment out of document order (PERF.md section 7, fault 1; the
+benchmark counts such pairs as ``ties_not_by_id``). Elasticsearch promises
+no order among equal scores. Concatenating in shard/segment order before a
+merge keeps TopDocs.merge's (shard index, position) order where the
+backend is stable.
 """
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 NEG_INF = jnp.float32(-jnp.inf)
+
+
+def _select_block(n: int, k: int) -> int:
+    """Block length of the two-stage selection for ``k`` of ``n`` scores,
+    or 0 where one ``lax.top_k`` over the row is as good: the power of two
+    nearest ``sqrt(n / k)``, which balances the ``n / block`` maxima the
+    first stage ranks against the ``k · block`` candidates of the second,
+    and 32 at the least; for rows of 2^16 scores and more that it divides
+    into 2k blocks or more. On a TPU v5e (PERF.md section 6) it gives the
+    fastest of the blocks tried at k = 1000 (32), k = 100 (128) and
+    k = 10 (256) over rows of 2^20."""
+    if n < (1 << 16) or k < 1:
+        return 0
+    block = 1 << max(5, round(math.log2(n / k) / 2))
+    return block if n % block == 0 and n // block >= 2 * k else 0
+
+
+def _block_top_k(masked, k: int, block: int):
+    """``lax.top_k(masked, k)`` without ranking the whole row: rank the
+    block maxima, gather the k chosen blocks in index order and rank
+    their ``k · block`` scores (the module docstring has why this is
+    exact). On the TPU XLA takes a large k of a whole row by sorting all
+    of it, in chunks of 8,192 scores, and then the chunks' top k.
+
+    masked: [..., N] f32, N a multiple of ``block``, N // block >= k."""
+    *lead, n = masked.shape
+    blocks = masked.reshape(*lead, n // block, block)
+    _, bid = jax.lax.top_k(blocks.max(axis=-1), k)
+    bid = jnp.sort(bid, axis=-1)         # candidates in index order
+    cand = jnp.take_along_axis(blocks, bid[..., None], axis=-2)
+    ts, pos = jax.lax.top_k(cand.reshape(*lead, k * block), k)
+    idx = jnp.take_along_axis(bid, pos // block, axis=-1) * block \
+        + pos % block
+    return ts, idx
+
+
+def select_top_k(masked, k: int):
+    """``lax.top_k(masked, k)`` over the last axis of ``masked`` ([N], as
+    under ``vmap``, or [B, N]): in two stages wherever
+    :func:`_select_block` gives the row's length and k a block, else in
+    one. The form follows the shape alone."""
+    block = _select_block(masked.shape[-1], k)
+    if block:
+        return _block_top_k(masked, k, block)
+    return jax.lax.top_k(masked, k)
+
+
+def two_stage(n: int, k: int) -> bool:
+    """Whether :func:`top_k` selects ``k`` of ``n`` scores in two stages
+    (the host counts a dispatch's rows by form)."""
+    return _select_block(n, min(k, n)) > 0
 
 
 @jax.named_scope("topk_select")
@@ -39,7 +103,7 @@ def top_k(scores, mask, k: int, doc_base: int = 0):
     """
     masked = jnp.where(mask, scores, NEG_INF)
     kk = min(k, masked.shape[-1])
-    top_scores, idx = jax.lax.top_k(masked, kk)
+    top_scores, idx = select_top_k(masked, kk)
     valid = top_scores > NEG_INF
     top_docs = jnp.where(valid, idx.astype(jnp.int32) + doc_base, -1)
     top_scores = jnp.where(valid, top_scores, NEG_INF)

@@ -24,6 +24,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from elasticsearch_tpu.ops import topk as topk_ops
+
 #: the matmul precision of every float32 score in this module and in the
 #: knn lane's programs (jit_exec.run_knn_hybrid_batch / _mesh)
 EXACT = jax.lax.Precision.HIGHEST
@@ -84,66 +86,20 @@ def cosine_scores_int8_batch(qvecs, scale, offset, exists, qs):
     return jnp.where(exists[None, :], s, 0.0)
 
 
-def _select_block(n: int, k: int) -> int:
-    """Block length of the two-stage selection for ``k`` of ``n`` scores,
-    or 0 where one ``lax.top_k`` over the row is as good: a power of two
-    near ``sqrt(n / k)`` (which balances the block maxima the second
-    stage ranks against the candidates the third does), at least one
-    128-lane row, for rows of 2^16 scores and more that it divides."""
-    if n < (1 << 16) or k < 1:
-        return 0
-    block = 128
-    while block * block * 4 * k <= n:
-        block *= 2
-    return block if n % block == 0 and n // block >= 2 * k else 0
-
-
-def _block_top_k(masked, k: int, block: int):
-    """``lax.top_k(masked, k)`` — the same values AND the same indices,
-    ties to the lower index — without ranking the whole row: the k best
-    lie in the k blocks whose maxima rank first (a block left out has k
-    blocks before it, each holding an element that is larger, or equal
-    and at a lower index), so rank the block maxima, gather those k blocks
-    in index order and rank their ``k · block`` scores. On the TPU the
-    full-row ``TopK`` over ``[B, 2^20]`` cost a third of the column's read
-    at B = 4 and grew with every padded row of a batch bucket (PERF.md
-    section 5, ``dense768-knn.search-k10-c16``).
-
-    masked: [B, N] f32, N a multiple of ``block``, N // block >= k."""
-    b, n = masked.shape
-    nb = n // block
-    blocks = masked.reshape(b, nb, block)
-    _, bid = jax.lax.top_k(blocks.max(axis=2), k)
-    bid = jnp.sort(bid, axis=1)          # candidates in index order
-    cand = jnp.take_along_axis(blocks, bid[:, :, None], axis=1)
-    ts, pos = jax.lax.top_k(cand.reshape(b, k * block), k)
-    idx = jnp.take_along_axis(bid, pos // block, axis=1) * block \
-        + pos % block
-    return ts, idx
-
-
 def filtered_topk_batch(scores, masks, k: int, doc_base: int = 0):
     """Batched filtered-kNN candidate selection: per-query top-k over
     pre-computed score rows with per-query eligibility masks (exists ∧
     live ∧ knn-filter) — the candidate-oversample step of the knn lane
-    (``num_candidates`` rows per segment survive to the merge).
-    ``lax.top_k`` batches over leading axes natively, so the whole
-    batch is one fused selection (stable: ties → lower doc id — on the
-    CPU backend; on the v5e equal scores can leave out of document order,
-    PERF.md section 7 fault 1, counted by the benchmark as
-    ``ties_not_by_id``). Long rows are selected in two stages
-    (:func:`_block_top_k`), with the same result.
+    (``num_candidates`` rows per segment survive to the merge). The whole
+    batch is one selection, :func:`ops.topk.select_top_k` (two stages
+    over long rows; ties as ``ops/topk.py`` states).
 
     scores: [B, N] f32; masks: [B, N] bool → ([B, k] f32, [B, k] i32).
     """
     neg_inf = jnp.float32(-jnp.inf)
     masked = jnp.where(masks, scores, neg_inf)
     kk = min(k, masked.shape[-1])
-    block = _select_block(masked.shape[-1], kk)
-    if block:
-        ts, idx = _block_top_k(masked, kk, block)
-    else:
-        ts, idx = jax.lax.top_k(masked, kk)
+    ts, idx = topk_ops.select_top_k(masked, kk)
     valid = ts > neg_inf
     td = jnp.where(valid, idx.astype(jnp.int32) + doc_base, -1)
     ts = jnp.where(valid, ts, neg_inf)

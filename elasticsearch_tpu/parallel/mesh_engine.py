@@ -119,7 +119,7 @@ from elasticsearch_tpu.observability.tracing import (
 # module-level on purpose: step_local runs under shard_map tracing, and
 # an import executed at trace time caches foreign tracers into the
 # imported module's globals (trace-purity rule)
-from elasticsearch_tpu.ops import aggs_ops
+from elasticsearch_tpu.ops import aggs_ops, topk as topk_ops
 from elasticsearch_tpu.search import dfs as dfs_mod
 from elasticsearch_tpu.search.execute import ExecutionContext
 from elasticsearch_tpu.search.jit_exec import (
@@ -1812,7 +1812,7 @@ class MeshEngineSearcher:
                     docs = jnp.concatenate(seg_docs, axis=1)
                     kk = min(k, scores.shape[1])
                     with jax.named_scope("plane_select"):
-                        top_s, idx = jax.lax.top_k(
+                        top_s, idx = topk_ops.select_top_k(
                             jnp.where(docs >= 0, scores, -jnp.inf), kk)
                         top_d = jnp.take_along_axis(docs, idx, axis=1)
                     top_d = jnp.where(top_s > -jnp.inf,
@@ -2249,6 +2249,11 @@ class MeshEngineSearcher:
         jit_exec.note_plane_dispatch(
             self.n_shards * b_pad * (k * 4 * (2 + 2 * n_spec
                                               + bool(sort_specs)) + 4))
+        if not sort_specs:
+            # every shard takes each slot's top k for every batch row
+            jit_exec.note_topk_rows(
+                [self._templates[0][j].padded_docs
+                 for j in range(self.n_slots)], k, self.n_shards * b_pad)
         if debug:
             print(f"[mesh-debug] dfs {t_dfs*1e3:.0f}ms "
                   f"plan+stack {(t1-t0-t_dfs)*1e3:.0f}ms "

@@ -887,7 +887,10 @@ def run_segment(seg: DeviceSegment, ctx: ExecutionContext, query,
                        owner=getattr(ctx.reader, "engine_uuid", None))
     with device_span("dispatch", cost=("segment", key, 1, 1)):
         device_fault_point("dispatch")
-        return fn(flat, consts)
+        out = fn(flat, consts)
+    if k is not None:
+        note_topk_rows((seg.padded_docs,), k_static, 1)
+    return out
 
 
 def _plan_segment_batch(seg: DeviceSegment, ctx: ExecutionContext,
@@ -1061,6 +1064,7 @@ def run_reader_batch(segments: list, ctx: ExecutionContext, queries: list,
     note_match_terms(plans[0]["match_terms"][:b if n_real is None
                                              else n_real])
     note_phrase_items(queries[:n_real], batched=True)
+    note_topk_rows([seg.padded_docs for seg in segments], k_static, b_pad)
     if b_pad != b:
         out = out[:b] if pack else {name: v[:b] for name, v in out.items()}
     return out
@@ -1180,6 +1184,7 @@ def run_segments_streamed(segments: list, ctx: ExecutionContext,
                 device_fault_point("dispatch")
                 outs = fn(cur, packed)      # async dispatch
             stats["dispatch_s"] += time.perf_counter() - t1
+            note_topk_rows((seg.padded_docs,), k_static, plan["b_pad"])
             outs_all.append(outs)
             del cur                         # free as soon as compute drains
             if i >= 1:
@@ -1806,6 +1811,7 @@ def run_impact_batch(pack: _ImpactPack, term_lists: list, boosts: list,
                            n_real if n_real is not None else b, b_pad)):
         device_fault_point("dispatch")
         out = fn(seg_arrs, qtids, pack.scales, boosts_a, cs, cd)
+    note_topk_rows([a[0].shape[0] for a in seg_arrs], k_static, b_pad)
     if b_pad != b:
         out = {name: v[:b] for name, v in out.items()}
     return out
@@ -1976,6 +1982,7 @@ def run_impact_rescore(pack: _ImpactPack, term_lists: list,
         device_fault_point("rescore-dispatch")
         out = fn(seg_arrs, qtids, pack.scales, boosts_a, cs, cd,
                  qtids2, boosts2_a, windows_a, qws_a, rws_a)
+    note_topk_rows([a[0].shape[0] for a in seg_arrs], k_static, b_pad)
     if b_pad != b:
         out = {name: v[:b] for name, v in out.items()}
     return out
@@ -2258,6 +2265,16 @@ def note_merge(by_score: bool) -> None:
     with _cache_lock:
         _bump("merge_items_array" if by_score
               else "merge_items_comparator")
+
+
+def note_topk_rows(lengths, k: int, rows: int) -> None:
+    """One dispatch that takes the top ``k`` of ``rows`` query rows of
+    each length in ``lengths`` (a segment's, a slot's, a shard's), counted
+    by the form ``ops/topk.select_top_k`` takes at that shape."""
+    with _cache_lock:
+        for n in lengths:
+            _bump("topk_rows_two_stage" if topk_ops.two_stage(int(n), k)
+                  else "topk_rows_full", int(rows))
 
 
 def note_knn_rows(real: int, padded: int) -> None:
@@ -2796,6 +2813,9 @@ def run_knn_hybrid_batch(reader, ctx, reqs, pack: _VectorPack,
             boosts)
     rows_real = n_real if n_real is not None else b
     note_knn_rows(rows_real, b_pad - rows_real)
+    note_topk_rows([a[0].shape[0] for a in vec_arrs if a], c_static, b_pad)
+    if hybrid:
+        note_topk_rows([s.padded_docs for s in segments], c_static, b_pad)
     cost = ("knn", key, rows_real, b_pad)
     if hybrid:
         with device_span("fusion-dispatch", cost=cost):
@@ -3071,6 +3091,10 @@ def run_impact_mesh(reader, pack: _ImpactPack, mesh, term_lists: list,
                            n_real if n_real is not None else b, b_pad)):
         device_fault_point("impact-shard-dispatch")
         out = fn(seg_arrs, qtids, scales, boosts_a, cs, cd)
+    if not prune:
+        s_axis = int(mesh.shape["shard"])
+        note_topk_rows([a[0].shape[0] // s_axis for a in seg_arrs],
+                       k_static, b_pad * s_axis)
     if b_pad != b:
         out = {name: v[:b] for name, v in out.items()}
     return out
@@ -3347,6 +3371,13 @@ def run_knn_hybrid_mesh(reader, ctx, reqs, pack: _VectorPack,
             boosts)
     rows_real = n_real if n_real is not None else b
     note_knn_rows(rows_real, b_pad - rows_real)
+    # every shard selects from its own rows of each vector segment, and
+    # from the whole of each (replicated) lexical segment
+    note_topk_rows([a[0].shape[0] // s_axis for a in placed if a],
+                   c_static, b_pad * s_axis)
+    if hybrid:
+        note_topk_rows([s.padded_docs for s in segments], c_static,
+                       b_pad * s_axis)
     with device_span("knn-mesh-merge",
                      cost=("knn-mesh", key, rows_real, b_pad)):
         device_fault_point("knn-mesh-merge")

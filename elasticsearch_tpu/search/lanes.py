@@ -94,6 +94,12 @@ JIT_COUNTERS = {
                          "order)",
     "merge_items_comparator": "search items the coordinator merged by "
                               "the field-sort comparator",
+    "topk_rows_two_stage": "query x segment (or x slot) rows whose top-k "
+                           "a dispatch took in two stages: the block "
+                           "maxima, then the chosen blocks",
+    "topk_rows_full": "query x segment (or x slot) rows whose top-k a "
+                      "dispatch took by one lax.top_k over the row "
+                      "(a short row, or k too large for its blocks)",
     "fusion_dispatches": "in-program hybrid fusion dispatches",
     "maxsim_dispatches": "fused MaxSim dispatches over rank_vectors",
     "rescore_fused_dispatches": "impact→rescore plans composed into one "

@@ -1367,12 +1367,15 @@ class ShardSearcher:
                                        agg_partials, self.reader)
         else:
             # score ordering: device top-k per segment, device merge
+            from elasticsearch_tpu.search import jit_exec
             seg_scores, seg_docs = [], []
             for seg, (s, m) in zip(segments, per_seg):
                 ts, td = topk_ops.top_k(s, m, min(k, seg.padded_docs),
                                         seg.doc_base)
                 seg_scores.append(ts)
                 seg_docs.append(td)
+            jit_exec.note_topk_rows([seg.padded_docs for seg in segments],
+                                    k, 1)
             res = self._finish_score_order(k, total, seg_scores, seg_docs,
                                            agg_partials)
         res.terminated_early = terminated_early

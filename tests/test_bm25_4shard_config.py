@@ -96,12 +96,12 @@ def load(rest: Rest, node, name: str, docs: dict, plane: bool) -> dict:
     return shard_of
 
 
-def msearch(rest: Rest, name: str, queries: list) -> list:
+def msearch(rest: Rest, name: str, queries: list, size: int = K) -> list:
     lines = []
     for q in queries:
         lines += [json.dumps({"index": name}),
                   json.dumps({"query": {"match": {"body": " ".join(q)}},
-                              "size": K})]
+                              "size": size})]
     out = rest.call("POST", "/_msearch", "\n".join(lines) + "\n")
     assert all("hits" in r for r in out["responses"]), out
     return [([(h["_id"], h["_score"]) for h in r["hits"]["hits"]],
@@ -313,7 +313,8 @@ def test_the_geometry_is_in_nodes_stats(deployment):
     assert (b["dp"], b["shard"], b["setting"]) == (1, 1, None)
     for key in ("plane_items_served", "plane_items_fallback",
                 "plane_dispatches", "plane_gather_bytes",
-                "merge_items_array", "merge_items_comparator"):
+                "merge_items_array", "merge_items_comparator",
+                "topk_rows_two_stage", "topk_rows_full"):
         assert key in node_doc["indices"]["jit"]
 
 
@@ -393,3 +394,56 @@ def test_a_field_sorted_search_merges_by_the_comparator(deployment):
         before["merge_items_comparator"] == 1
     assert after["merge_items_array"] == before["merge_items_array"]
     ra.call("DELETE", "/sorted")
+
+
+def test_long_slots_select_in_two_stages_as_one_full_sort(deployment,
+                                                          monkeypatch):
+    """Shards whose slot holds 2^16 rows take each slot's top k in two
+    stages (``ops/topk.select_top_k``): the plane serves the same ids,
+    scores and totals as with one ``lax.top_k`` over every slot, and
+    counts 4 shards x 1 slot x 4 rows a form."""
+    from elasticsearch_tpu.index.segment import Segment
+    from elasticsearch_tpu.ops import topk
+    from elasticsearch_tpu.parallel import mesh_engine
+    a, ra = deployment["a"], deployment["ra"]
+    ra.call("PUT", "/long", {
+        "settings": {"number_of_shards": 4, "number_of_replicas": 0},
+        "mappings": MAPPINGS})
+    ra.call("GET", "/_cluster/health/long?wait_for_status=green&timeout=30s")
+    rows, vocab = 1 << 16, 64
+    for s in range(4):
+        rng = np.random.default_rng([s, 37])
+        uterms = np.sort(np.stack([rng.choice(vocab, 6, replace=False)
+                                   for _ in range(64)]), axis=1)
+        uterms = uterms[rng.integers(0, 64, rows)].astype(np.int32)
+        utf = rng.integers(1, 3, uterms.shape).astype(np.float32)
+        a.indices_service.indices["long"].engine(s).install_segment(
+            Segment.from_packed_text(
+                0, "body", terms=[f"w{i:03d}" for i in range(vocab)],
+                tokens=None, uterms=uterms, utf=utf,
+                doc_len=utf.sum(axis=1).astype(np.int32),
+                df=np.bincount(uterms.ravel(), minlength=vocab),
+                num_docs=rows, ids=[f"{s}-{i}" for i in range(rows)]),
+            track_versions=False)
+    queries = [["w001", "w002"], ["w003"], ["w004", "w005", "w006"],
+               ["w007", "w008", "w009", "w010"]]
+
+    def run():
+        jit_exec.clear_cache()
+        mesh_engine.clear_program_cache()
+        hits = msearch(ra, "long", queries, size=100)
+        st = jit_exec.cache_stats()
+        assert st["plane_items_served"] == len(queries)
+        return hits, st["topk_rows_two_stage"], st["topk_rows_full"]
+
+    try:
+        with monkeypatch.context() as m:
+            m.setattr(topk, "_select_block", lambda n, k: 0)
+            full, two_rows, full_rows = run()
+        assert (two_rows, full_rows) == (0, 4 * len(queries))
+        got, two_rows, full_rows = run()
+        assert (two_rows, full_rows) == (4 * len(queries), 0)
+        assert got == full
+        assert all(total > 100 for _, total in got)
+    finally:
+        ra.call("DELETE", "/long")

@@ -284,18 +284,22 @@ def test_a_bfloat16_product_fails_the_cells_limits(seed):
 
 @pytest.mark.parametrize("k,density,ties", [
     (10, 1.0, True), (100, 0.5, True), (37, 0.001, True),
-    (100, 1.0, False), (10, 0.0, False)])
+    (100, 1.0, False), (10, 0.0, False),
+    (1000, 1.0, True), (1000, 0.0005, True), (1000, 1.0, False),
+    (1000, 0.0, False)])
 def test_two_stage_selection_is_lax_top_k(k, density, ties):
     """Long rows are selected through the block maxima
-    (``ops/vector._block_top_k``): the same values and the same indices
+    (``ops/topk._block_top_k``): the same values and the same indices
     as ``lax.top_k`` over the whole row, ties to the lower index, with
-    masked rows, rows with fewer than k eligible scores and none."""
+    masked rows, rows with fewer than k eligible scores and none. The
+    rows of k = 1000 are 2^20 long, a segment of the BM25 cells."""
     import jax.numpy as jnp
+    from elasticsearch_tpu.ops import topk as topk_ops
     from elasticsearch_tpu.ops import vector as vector_ops
     rng = np.random.default_rng([k, int(density * 1000)])
-    b, n = 3, 1 << 16
-    assert vector_ops._select_block(n, k) > 0
-    assert vector_ops._select_block(1 << 15, k) == 0
+    b, n = 3, 1 << 20 if k == 1000 else 1 << 16
+    assert topk_ops._select_block(n, k) > 0
+    assert topk_ops._select_block(1 << 15, k) == 0
     scores = (rng.integers(0, 50, (b, n)) / 7).astype(np.float32) if ties \
         else rng.standard_normal((b, n)).astype(np.float32)
     masks = rng.random((b, n)) < density

@@ -416,3 +416,65 @@ class TestShouldOnlyMinimum:
             assert got.total == ref.total
             np.testing.assert_array_equal(got.doc_ids, ref.doc_ids)
             np.testing.assert_allclose(got.scores, ref.scores, rtol=1e-6)
+
+
+def _packed_segment(seed: int, rows: int, vocab: int = 64,
+                    unique: int = 6) -> Segment:
+    """A segment of ``rows`` documents over a small vocabulary, built
+    from packed columns: many documents share a score (ties)."""
+    rng = np.random.default_rng(seed)
+    uterms = np.sort(np.stack([rng.choice(vocab, unique, replace=False)
+                               for _ in range(64)]), axis=1)
+    uterms = uterms[rng.integers(0, 64, rows)].astype(np.int32)
+    utf = rng.integers(1, 3, (rows, unique)).astype(np.float32)
+    return Segment.from_packed_text(
+        0, "t", terms=[f"w{i:02d}" for i in range(vocab)], tokens=None,
+        uterms=uterms, utf=utf, doc_len=utf.sum(axis=1).astype(np.int32),
+        df=np.bincount(uterms.ravel(), minlength=vocab), num_docs=rows,
+        ids=[f"{seed}-{i}" for i in range(rows)])
+
+
+class TestTwoStageSelection:
+    """Segments of 2^16 rows select their top-k in two stages
+    (``ops/topk.select_top_k``): the same hits, scores and totals as one
+    ``lax.top_k`` over each whole row, and the counters say which form
+    each row took."""
+
+    def test_reader_batch_equals_the_full_row_selection(self, tmp_path,
+                                                        monkeypatch):
+        from elasticsearch_tpu.index.engine import Engine
+        from elasticsearch_tpu.mapping import MapperService
+        from elasticsearch_tpu.ops import topk
+        from elasticsearch_tpu.search.phase import ShardSearcher
+        ms = MapperService()
+        ms.merge("_doc", {"properties": {"t": {"type": "text",
+                                               "analyzer": "whitespace"}}})
+        eng = Engine(tmp_path / "e", ms)
+        for seed in (1, 2):
+            eng.install_segment(_packed_segment(seed, 1 << 16),
+                                track_versions=False)
+        s = ShardSearcher(0, device_reader_for(eng), ms)
+        lengths = [seg.padded_docs for seg in s.reader.segments]
+        assert len(lengths) == 2 and min(lengths) >= 1 << 16
+        reqs = [parse_search_request(
+            {"query": {"match": {"t": t}}, "size": 100})
+            for t in ("w01 w02", "w03", "w04 w05 w06 w07", "w08 w09 w10")]
+
+        def run():
+            jit_exec.clear_cache()
+            got = s.query_phase_batch(reqs)
+            assert got is not None
+            st = jit_exec.cache_stats()
+            return ([(r.total, r.doc_ids.tolist(), r.scores.tolist())
+                     for r in got],
+                    st["topk_rows_two_stage"], st["topk_rows_full"])
+
+        with monkeypatch.context() as m:
+            m.setattr(topk, "_select_block", lambda n, k: 0)
+            full, two_rows, full_rows = run()
+        assert (two_rows, full_rows) == (0, len(reqs) * len(lengths))
+        got, two_rows, full_rows = run()
+        assert (two_rows, full_rows) == (len(reqs) * len(lengths), 0)
+        assert got == full
+        assert all(total > 100 for total, _, _ in got)
+        eng.close()

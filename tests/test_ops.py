@@ -397,6 +397,48 @@ class TestTopK:
         np.testing.assert_allclose(np.asarray(ms), [5, 4, 3, 3])
 
 
+#: a segment of the one-chip BM25 cells, and a slot of the four-chip one
+SELECT_ROWS = (1 << 16, 1 << 20, 1_105_920)
+
+
+@pytest.mark.parametrize("form", ["vmap", "batch"])
+@pytest.mark.parametrize("ties", [True, False])
+@pytest.mark.parametrize("density", [1.0, 0.001, 0.0])
+@pytest.mark.parametrize("n", SELECT_ROWS)
+@pytest.mark.parametrize("k", [10, 100, 1000])
+def test_select_top_k_is_lax_top_k(k, n, density, ties, form):
+    """The shared selection gives ``lax.top_k``'s values AND indices, ties
+    to the lower index, on a row ``[N]`` under ``vmap`` (as a program body
+    calls it) and on ``[B, N]``: with every score eligible, with fewer
+    than k eligible (the empty places -inf) and with none."""
+    rng = np.random.default_rng([k, n, int(density * 1000), ties])
+    b = 2
+    scores = (rng.integers(0, 50, (b, n)) / 7).astype(np.float32) if ties \
+        else rng.standard_normal((b, n)).astype(np.float32)
+    masked = jnp.asarray(np.where(rng.random((b, n)) < density, scores,
+                                  -np.inf).astype(np.float32))
+    if form == "vmap":
+        got = jax.jit(jax.vmap(lambda row: topk.select_top_k(row, k)))(
+            masked)
+    else:
+        got = jax.jit(lambda m: topk.select_top_k(m, k))(masked)
+    want = jax.lax.top_k(masked, k)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
+@pytest.mark.parametrize("n,k,block", [
+    (1 << 20, 1000, 32), (1_105_920, 1000, 32), (1 << 20, 100, 128),
+    (1 << 20, 10, 256), (1 << 16, 100, 32), (1 << 16, 1000, 32),
+    (1 << 15, 10, 0),                 # under 2^16: one lax.top_k
+    (1 << 16, 1500, 0),               # 2,048 blocks of 32: under 2k
+    ((1 << 16) + 8, 10, 0),           # the block of 64 does not divide it
+    (1 << 20, 0, 0)])
+def test_select_block_rule(n, k, block):
+    assert topk._select_block(n, k) == block
+    assert topk.two_stage(n, k) == (block > 0)
+
+
 class TestVector:
     def test_cosine_exact(self, rng):
         vecs = rng.standard_normal((10, 8)).astype(np.float32)

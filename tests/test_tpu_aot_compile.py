@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import re
 import types
 
 import jax
@@ -149,6 +150,28 @@ def fits_hbm(compiled, label: str):
           f"{ma.temp_size_in_bytes / 2**20:.1f} MiB per device")
     assert need < HBM_BYTES, f"{label} needs {need} bytes of 16 GB HBM"
     return ma
+
+
+def whole_row_selections(text: str, elements: int) -> list:
+    """The compiled program's sorts and ``TopK`` calls whose operand holds
+    ``elements`` scores or more: a top-k that ranks a whole segment's (or
+    slot's) scores for every row of the batch. (XLA lays a top-k of
+    [64, 2^20] out as a sort of [8192, 8192], so the minor dimension
+    alone cannot tell.) Two stages rank block maxima and the chosen
+    blocks."""
+    size = {}
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]", ln)
+        if m:
+            size[m.group(1)] = int(np.prod(
+                [int(d) for d in m.group(2).split(",") if d]))
+    out = []
+    for ln in text.splitlines():
+        if " sort(" in ln or 'custom_call_target="TopK"' in ln:
+            m = re.search(r" (?:sort|custom-call)\(%([^,)\s]+)", ln)
+            if m and size.get(m.group(1), 0) >= elements:
+                out.append(ln)
+    return out
 
 
 def _engine(tmp_path, rows: int, vocab: int, unique: int, *,
@@ -341,6 +364,8 @@ def test_mixed_msearch_at_the_benchmark_cells_shapes(
     assert ma.argument_size_in_bytes >= columns
     assert ma.argument_size_in_bytes + 2 * (
         ma.temp_size_in_bytes + ma.output_size_in_bytes) < HBM_BYTES
+    # each segment's top 1000 in two stages: no sort of a whole row
+    assert whole_row_selections(compiled.as_text(), BATCH * ROWS) == []
     cost = compiled.cost_analysis()
     print(f"[aot] mixed msearch64: flops {cost['flops']:.4g}, bytes "
           f"accessed {cost['bytes accessed']:.4g}")
@@ -455,6 +480,37 @@ def test_knn_program_at_the_benchmark_cells_shapes(
     assert "bf16" not in "".join(
         ln for ln in text.splitlines() if "knn_score" in ln)
     assert "knn_select" in text and "knn_merge" in text
+    # the selection lowers as the knn lane's own form did before the
+    # rule and the two stages were shared with every lane
+    from elasticsearch_tpu.ops import vector as vector_ops
+    rows = [jax.ShapeDtypeStruct((b_pad, ROWS), dt, sharding=chip)
+            for dt in (jnp.float32, jnp.bool_)]
+
+    def lowered(select):
+        return jax.jit(lambda s, m: select(s, m, 100)).lower(*rows).as_text()
+    assert lowered(vector_ops.filtered_topk_batch) == \
+        lowered(_knn_selection_for_b_n_rows)
+
+
+def _knn_selection_for_b_n_rows(scores, masks, k: int, doc_base: int = 0):
+    """``ops/vector.filtered_topk_batch`` as written for ``[B, N]`` rows
+    alone, with its own block rule (N a multiple of the block)."""
+    neg_inf = jnp.float32(-jnp.inf)
+    masked = jnp.where(masks, scores, neg_inf)
+    b, n = masked.shape
+    block = 128
+    while block * block * 4 * k <= n:
+        block *= 2
+    blocks = masked.reshape(b, n // block, block)
+    _, bid = jax.lax.top_k(blocks.max(axis=2), k)
+    bid = jnp.sort(bid, axis=1)
+    cand = jnp.take_along_axis(blocks, bid[:, :, None], axis=1)
+    ts, pos = jax.lax.top_k(cand.reshape(b, k * block), k)
+    idx = jnp.take_along_axis(bid, pos // block, axis=1) * block \
+        + pos % block
+    valid = ts > neg_inf
+    td = jnp.where(valid, idx.astype(jnp.int32) + doc_base, -1)
+    return jnp.where(valid, ts, neg_inf), td
 
 
 def test_impact_mesh_program_1x4(topo, no_persistent_cache, big):
@@ -624,6 +680,7 @@ def test_plane_program_at_the_4shard_cells_shapes(
         ma.temp_size_in_bytes + ma.output_size_in_bytes) < HBM_BYTES
     text = compiled.as_text()
     assert text.startswith("HloModule jit_step_local"), text[:80]
+    assert whole_row_selections(text, BATCH * PLANE_CELL_ROWS) == []
     assert "all-gather" in text, "no cross-chip candidate merge"
     for scope in ("plane_score", "plane_select", "plane_gather",
                   "plane_merge", "bm25_score"):
@@ -689,6 +746,7 @@ def test_phrase_program_at_the_benchmark_cells_shapes(
     assert ma.argument_size_in_bytes + 2 * (
         ma.temp_size_in_bytes + ma.output_size_in_bytes) < HBM_BYTES
     assert "phrase_match" in compiled.as_text()
+    assert whole_row_selections(compiled.as_text(), 16 * ROWS) == []
 
 
 @pytest.mark.parametrize("deltas", [tuple(range(12)), (0, 2, 3)])
