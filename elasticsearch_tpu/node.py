@@ -982,7 +982,9 @@ class Node:
                             "heap_max_in_bytes": total_mem},
                     "threads": {"count": _threading.active_count(),
                                 "peak_count": _threading.active_count()},
-                    "gc": {"collectors": {}},
+                    # the interpreter's collector: young = generations
+                    # 0 and 1, old = generation 2 (the full collection)
+                    "gc": {"collectors": _tracing.collector_stats()},
                     "buffer_pools": {
                         "direct": {"count": 0, "used_in_bytes": 0,
                                    "total_capacity_in_bytes": 0},

@@ -29,6 +29,15 @@ per-term loop. Each span also enters a ``jax.profiler.TraceAnnotation``
 records host events (``host_tracer_level`` ≥ 1) shows the same spans on
 the device trace's clock; with no such session it is a no-op.
 
+**The interpreter's collections.** A ``gc.callbacks`` hook, installed
+when this module loads, counts and times every collection by
+generation (``_nodes/stats`` ``jvm.gc.collectors``:
+:func:`collector_stats`). A full collection — generation 2, the one
+that walks the whole heap while every thread stands — also leaves an
+``interp.collect`` ring record under the collecting thread's innermost
+open span, so that span's self time no longer holds the stop, and an
+``es.interp.collect`` annotation.
+
 **The opt-in view — the tree.** A trace is keyed by the COORDINATING
 task id (tasks/manager.py mints it), so the span tree and the task tree
 describe the same request and ``GET /_tasks/{id}/trace`` can reassemble
@@ -80,6 +89,7 @@ spans left behind; the ring record and the launch close likewise.
 from __future__ import annotations
 
 import contextlib
+import gc
 import itertools
 import struct
 import threading
@@ -101,7 +111,7 @@ __all__ = [
     "open_span_count", "build_tree", "reset", "current_node_id",
     "use_node", "SPAN_LAYERS", "RING_CAP", "RING_BYTES", "request",
     "ring_records", "ring_stats", "launch_scope", "close_launches",
-    "starved_intervals", "book_stats",
+    "starved_intervals", "book_stats", "collector_stats",
 ]
 
 #: request-dict key carrying {"id": trace_id, "parent": span_id} across
@@ -157,8 +167,8 @@ SPAN_LAYERS = {
     "plane.enqueue": "action", "plane.drain": "action",
     "plane.split": "action",
     # scheduler — search/scheduler.py
-    "scheduler.queue": "scheduler", "scheduler.launch": "scheduler",
-    "scheduler.drain": "scheduler",
+    "scheduler.queue": "scheduler", "scheduler.result": "scheduler",
+    "scheduler.launch": "scheduler", "scheduler.drain": "scheduler",
     # planner — search/planner.py
     "plan.knn": "planner", "plan.rescore": "planner",
     "plan.impact": "planner", "plan.exact": "planner",
@@ -170,6 +180,9 @@ SPAN_LAYERS = {
     **{name: "jit_exec" for name in _SITE_SPAN.values()},
     # fetch — search/phase.py fetch_phase
     "fetch.hits": "fetch",
+    # interpreter — a full collection (generation 2) stops every thread;
+    # recorded by the collector's callback (:func:`_on_gc`), not by span()
+    "interp.collect": "interpreter",
 }
 
 #: what the tree (Profile API, ``GET /_tasks/{id}/trace``) has always
@@ -477,8 +490,11 @@ class _RingSpan:
         tls.open_seq = self._parent
         if self._minted:
             tls.rid = 0
-        _ring.write(self._seq, self._parent, self._rid, self._code,
-                    threading.get_ident(), self._t0, t1, cpu)
+        ring = _ring
+        ring.write(self._seq, self._parent, self._rid, self._code,
+                   threading.get_ident(), self._t0, t1, cpu)
+        if ring.parked:
+            ring.unpark()
         if self._tree is not None:
             self._tree.__exit__(exc_type, exc, tb)
             self._tree = None
@@ -554,7 +570,12 @@ class _Ring:
     span opens (children name it as ``parent_seq``) and the record is
     written when the span closes, in one ``pack_into`` (atomic under
     the interpreter lock). Only a write that wraps takes the lock, to
-    count what it overwrites."""
+    count what it overwrites.
+
+    The collector's callback writes from whatever thread allocated —
+    perhaps this one, inside a wrapping write that holds the lock — so
+    it never waits for the lock: a record the lock keeps out is
+    *parked*, and the next span close (or reader) writes it."""
 
     def __init__(self, cap: int):
         assert cap & (cap - 1) == 0, "capacity is a power of two"
@@ -567,27 +588,53 @@ class _Ring:
         #: the latest end of any record the ring has lost: an interval
         #: that starts before it can no longer be answered whole
         self.lost_end_ns = 0
+        self.parked: list = []
 
     def write(self, seq, parent, rid, code, tid, t0, t1, cpu) -> None:
-        off = (seq & (self.cap - 1)) * _REC.size
         if seq > self.cap:
             with self.lock:
-                old = _REC.unpack_from(self.buf, off)
-                if old[0] > seq:
-                    # a span that outlived a whole turn of the ring:
-                    # its own record is the one that is lost
-                    self.overwritten += 1
-                    self.lost_end_ns = max(self.lost_end_ns, t1)
-                    return
-                if old[0]:
-                    self.overwritten += 1
-                    self.lost_end_ns = max(self.lost_end_ns, old[6])
-                _REC.pack_into(self.buf, off, seq, parent, rid, code,
-                               tid, t0, t1, cpu)
+                self._wrap(seq, parent, rid, code, tid, t0, t1, cpu)
         else:
-            _REC.pack_into(self.buf, off, seq, parent, rid, code, tid,
-                           t0, t1, cpu)
+            _REC.pack_into(self.buf, (seq & (self.cap - 1)) * _REC.size,
+                           seq, parent, rid, code, tid, t0, t1, cpu)
+            self.written += 1
+
+    def _wrap(self, seq, parent, rid, code, tid, t0, t1, cpu) -> None:
+        off = (seq & (self.cap - 1)) * _REC.size
+        old = _REC.unpack_from(self.buf, off)
+        if old[0] > seq:
+            # a span that outlived a whole turn of the ring: its own
+            # record is the one that is lost
+            self.overwritten += 1
+            self.lost_end_ns = max(self.lost_end_ns, t1)
+            return
+        if old[0]:
+            self.overwritten += 1
+            self.lost_end_ns = max(self.lost_end_ns, old[6])
+        _REC.pack_into(self.buf, off, seq, parent, rid, code, tid, t0, t1,
+                       cpu)
         self.written += 1
+
+    def write_nowait(self, rec: tuple) -> None:
+        """:meth:`write` for the collector's callback: parks the record
+        where the lock is held rather than wait for it."""
+        if rec[0] <= self.cap:
+            self.write(*rec)
+        elif self.lock.acquire(blocking=False):
+            try:
+                self._wrap(*rec)
+            finally:
+                self.lock.release()
+        else:
+            self.parked.append(rec)
+
+    def unpark(self) -> None:
+        while True:
+            try:
+                rec = self.parked.pop()
+            except IndexError:
+                return
+            self.write(*rec)
 
 
 _ring = _Ring(RING_CAP)
@@ -599,6 +646,7 @@ def ring_records(t0_ns: int, t1_ns: int) -> "list[tuple] | None":
     cpu_ns)`` in ``seq`` order — or ``None`` when the ring has lost a
     record that met the interval: never a short list."""
     ring = _ring
+    ring.unpark()
     snap = bytes(ring.buf)               # one atomic copy
     if t0_ns < ring.lost_end_ns:
         return None
@@ -626,6 +674,62 @@ def request():
         yield _tls.rid
     finally:
         _tls.rid = prev
+
+
+# ---------------------------------------------------------------------------
+# the interpreter's collections
+# ---------------------------------------------------------------------------
+
+#: collections by generation since the process began, and the time they
+#: stopped the interpreter on the ring's clock. Plain integers that only
+#: :func:`_on_gc` writes: the interpreter runs one collection at a time
+_gc_count = [0, 0, 0]
+_gc_ns = [0, 0, 0]
+#: the collection under way: its start, and its annotation when full
+_gc_now = [0, None]
+_COLLECT = _SPAN_CODE["interp.collect"]
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: counts and times every collection; a full
+    one (generation 2) also leaves an ``interp.collect`` ring record on
+    the collecting thread — under its innermost open span, with its
+    request id (none is minted), ``cpu_ns`` -1 (the CPU is the enclosing
+    stretch's) — and an ``es.interp.collect`` annotation. Runs inside
+    whatever that thread was doing, so it waits for no lock."""
+    gen = info["generation"]
+    if phase == "start":
+        if gen == 2:
+            _gc_now[1] = TraceAnnotation(_ANNOTATIONS[_COLLECT],
+                                         request=_tls.rid)
+        _gc_now[0] = time.monotonic_ns()
+        return
+    t1 = time.monotonic_ns()
+    t0 = _gc_now[0]
+    _gc_count[gen] += 1
+    _gc_ns[gen] += t1 - t0
+    if gen == 2:
+        ann, _gc_now[1] = _gc_now[1], None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        tls = _tls
+        ring = _ring
+        ring.write_nowait((next(ring.seq), tls.open_seq, tls.rid, _COLLECT,
+                           threading.get_ident(), t0, t1, -1))
+
+
+gc.callbacks.append(_on_gc)
+
+
+def collector_stats() -> dict:
+    """``_nodes/stats`` ``jvm.gc.collectors`` as the reference's
+    ``JvmStats`` shapes it: ``young`` (generations 0 and 1) and ``old``
+    (generation 2), cumulative since the process began."""
+    def doc(gens):
+        return {"collection_count": sum(_gc_count[g] for g in gens),
+                "collection_time_in_millis":
+                    sum(_gc_ns[g] for g in gens) // 1_000_000}
+    return {"young": doc((0, 1)), "old": doc((2,))}
 
 
 # ---------------------------------------------------------------------------

@@ -358,11 +358,6 @@ class ContinuousBatchScheduler:
         self._qw_good = 0
         self._qw_bad = 0
         self._qw_target_ms = 50.0       # refreshed from slo config
-        # enqueue → pick-up of THIS scheduler's waiters, and no other
-        # queue's (``stats()["queue_wait_ms"]``)
-        from elasticsearch_tpu.observability.histograms import \
-            LatencyHistogram
-        self._queue_wait = LatencyHistogram()
 
     # ---- admission ---------------------------------------------------------
 
@@ -437,18 +432,20 @@ class ContinuousBatchScheduler:
         return w
 
     def execute(self, lane: str, key, req, launch, drain=None):
-        """Blocking entry: queue, wait under a ``scheduler.queue`` span
-        (PR 8 — the span covers exactly the queue wait), then wait for
-        the batch's result. → result, or None when the scheduler
-        declined (caller runs its serial path). Raises
-        :class:`SchedulerRejectedError` when shed at admission."""
+        """Blocking entry: queue under a ``scheduler.queue`` span
+        (enqueue → pick-up), then wait for the batch's result under a
+        ``scheduler.result`` span (the batches ahead, then its own).
+        → result, or None when the scheduler declined (caller runs its
+        serial path). Raises :class:`SchedulerRejectedError` when shed
+        at admission."""
         from elasticsearch_tpu.observability import tracing as obs_trace
         w = self.submit(lane, key, req, launch, drain)
         with obs_trace.span("scheduler.queue", lane=lane) as sp:
             w.picked.wait(EXECUTE_BACKSTOP_S)
             sp.set(queue_ms=round(w.queue_ms, 3))
         try:
-            out = w.future.result(timeout=EXECUTE_BACKSTOP_S)
+            with obs_trace.span("scheduler.result", lane=lane):
+                out = w.future.result(timeout=EXECUTE_BACKSTOP_S)
         except FutTimeout:
             # the watchdog should have abandoned this batch long ago;
             # the backstop fails the CALLER over to its serial path
@@ -657,7 +654,6 @@ class ContinuousBatchScheduler:
         bad = 0
         for w in live:
             w.queue_ms = (t_pick - w.enq_t) * 1e3
-            self._queue_wait.observe(w.queue_ms)
             # the node's queue-time lane (and its SLO book) sees every
             # queue on the node, this one and the thread pools'
             obs_hist.observe_lane("queue_wait", w.queue_ms,
@@ -919,10 +915,6 @@ class ContinuousBatchScheduler:
                     "device_ms": round(pace[2], 3),
                     "staged_depth": self._staged_depth_locked(lane)}
                     for lane, pace in self._pace.items()},
-                "queue_wait_ms": {
-                    "count": self._queue_wait.count,
-                    "p50": round(self._queue_wait.percentile(0.50), 4),
-                    "p95": round(self._queue_wait.percentile(0.95), 4)},
                 "reconciled": (
                     self._submitted == self._queued + self._inflight_reqs
                     + self._delivered + self._declined + self._shed

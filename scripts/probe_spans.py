@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Three facts about the tracing of this program that only the machine
+"""Four facts about the tracing of this program that only the machine
 it runs on can give; prints one JSON object.
 
     python3 scripts/probe_spans.py          (on the chip: chiprun -- ...)
@@ -17,6 +17,10 @@ it runs on can give; prints one JSON object.
    scopes from the same program without (its key leaves metadata out):
    a program loaded from a cache that another build wrote carries that
    build's scopes, not this one's.
+4. ``collect_ns``: what the collector's callback costs — one call pair
+   (start and stop) alone for a young and for a full collection, and a
+   young collection (``gc.collect(0)`` on a small heap) with the
+   callback and without it.
 """
 
 from __future__ import annotations
@@ -78,6 +82,45 @@ def parts_ns(n: int = 200_000) -> dict:
                         / n * 1e9, 1) for name, stmt in stmts.items()}
 
 
+def collect_ns(n: int = 100_000, rounds: int = 5) -> dict:
+    """ns a call: the callback's start/stop pair alone, and a young
+    collection with and without it (alternating rounds, the median)."""
+    import gc
+    import statistics
+
+    from elasticsearch_tpu.observability import tracing
+
+    def per(fn) -> float:
+        t0 = time.perf_counter()
+        for _ in range(n):
+            fn()
+        return (time.perf_counter() - t0) / n * 1e9
+
+    def pair(gen):
+        info = {"generation": gen}
+
+        def call():
+            tracing._on_gc("start", info)
+            tracing._on_gc("stop", info)
+        return call
+
+    out = {"callback_pair_young": round(per(pair(0)), 1),
+           "callback_pair_full": round(per(pair(2)), 1)}
+    tracing.reset()
+    hooked, bare = [], []
+    for _ in range(rounds):
+        hooked.append(per(lambda: gc.collect(0)))
+        gc.callbacks.remove(tracing._on_gc)
+        try:
+            bare.append(per(lambda: gc.collect(0)))
+        finally:
+            gc.callbacks.append(tracing._on_gc)
+    out["young_collection_with_callback"] = round(
+        statistics.median(hooked), 1)
+    out["young_collection_without"] = round(statistics.median(bare), 1)
+    return out
+
+
 def scope_events(tdir: str, needle: str) -> dict:
     """Where ``needle`` shows: [plane, line, event name, stat], and what
     one op event of the device carries at all."""
@@ -123,7 +166,8 @@ def traced(fn, *args) -> list:
 
 
 def main() -> int:
-    out = {"span_ns": span_ns(), "parts_ns": parts_ns()}
+    out = {"span_ns": span_ns(), "parts_ns": parts_ns(),
+           "collect_ns": collect_ns()}
     with tempfile.TemporaryDirectory() as cache:
         import jax
         import jax.numpy as jnp

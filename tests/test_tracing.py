@@ -3,6 +3,7 @@ propagation over BOTH transports, trace reassembly under the
 coordinating task id, and the zero-leaked-open-spans contract on
 completion, cancellation, and timeout."""
 
+import gc
 import threading
 import time
 
@@ -367,13 +368,22 @@ def _self_ns(records):
     return out
 
 
+def _written(t0: int) -> int:
+    """The instant the request's ``rest.write`` has closed: the client
+    holds the reply a moment before the server's write span ends."""
+    assert wait_until(lambda: any(
+        r[3] == "rest.write"
+        for r in tracing.ring_records(t0, time.monotonic_ns())))
+    return time.monotonic_ns()
+
+
 def test_msearch_over_rest_one_request_every_layer(served, fresh_ring):
     node, call = served
     before = tracing.spans_allocated()
     jit0 = node.local_node_stats()["indices"]["jit"]
     t0 = time.monotonic_ns()
     status, reply = call("POST", "/_msearch", MSEARCH)
-    t1 = time.monotonic_ns()
+    t1 = _written(t0)
     assert status == 200 and len(reply["responses"]) == 8
     assert all(r["hits"]["total"] > 0 for r in reply["responses"])
     # the tracer is off: the ring took its records, no Span was built
@@ -440,7 +450,8 @@ def test_msearch_over_rest_one_request_every_layer(served, fresh_ring):
 
 def test_single_search_over_rest_passes_the_scheduler(served, fresh_ring):
     node, call = served
-    sched0 = node.search_actions.scheduler.stats()["queue_wait_ms"]
+    sched = node.search_actions.scheduler
+    sched0 = histograms.summaries(sched.node_id or "")["queue_wait"]
     t0 = time.monotonic_ns()
     status, reply = call("POST", "/ring/_search", SEARCH)
     t1 = time.monotonic_ns()
@@ -459,10 +470,38 @@ def test_single_search_over_rest_passes_the_scheduler(served, fresh_ring):
     everything = [n for v in by_rid.values() for n in v]
     assert "scheduler.launch" in everything
     assert "scheduler.drain" in everything
-    # the scheduler owns its queue-wait histogram
-    sched1 = node.search_actions.scheduler.stats()["queue_wait_ms"]
-    assert sched1["count"] == sched0["count"] + 1
-    assert 0 <= sched1["p50"] <= sched1["p95"]
+    # the pick-up fed the node's queue-time lane (and its SLO book)
+    sched1 = histograms.summaries(sched.node_id or "")["queue_wait"]
+    assert sched1["count"] >= sched0["count"] + 1
+    assert 0 <= sched1["p50_ms"] <= sched1["p95_ms"]
+
+
+def test_the_wait_for_a_batchs_result_is_a_span_of_its_own(served,
+                                                           fresh_ring):
+    node, call = served
+    t0 = time.monotonic_ns()
+    status, _ = call("POST", "/ring/_search", SEARCH)
+    t1 = time.monotonic_ns()
+    assert status == 200
+    recs = tracing.ring_records(t0, t1)
+    rid = next(r[2] for r in recs if r[3] == "rest.handle")
+    mine = {r[3]: r for r in recs if r[2] == rid}
+    shard = mine["action.shard"]
+    queue, result = mine["scheduler.queue"], mine["scheduler.result"]
+    # queue, then result, both under action.shard on its thread
+    assert queue[1] == result[1] == shard[0]
+    assert queue[4] == result[4] == shard[4]
+    assert queue[6] <= result[5] and result[7] == -1
+    # the batch ran inside the two waits: launched after the enqueue,
+    # its result delivered after its drain
+    launch = next(r for r in recs if r[3] == "scheduler.launch")
+    drain = next(r for r in recs if r[3] == "scheduler.drain")
+    assert queue[5] <= launch[5] and drain[6] <= result[6]
+    # so action.shard's self time holds neither wait
+    selfs = _self_ns(recs)
+    waits = (queue[6] - queue[5]) + (result[6] - result[5])
+    assert selfs[shard[0]] <= (shard[6] - shard[5]) - waits
+    assert selfs[result[0]] == result[6] - result[5]
 
 
 def test_tracer_off_contract_holds_with_the_ring_on(fresh_ring):
@@ -614,26 +653,167 @@ def test_fault_inside_a_span_closes_its_record_and_its_launch(fresh_ring):
         tracing.device_span("no-such-site")
 
 
+@pytest.fixture
+def no_automatic_collection():
+    """Only the test's own ``gc.collect()`` collects, on any thread."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
+
+
+def test_a_full_collection_is_a_record_under_the_span_it_stopped(
+        served, fresh_ring, no_automatic_collection):
+    node, _call = served
+
+    def old():
+        return node.local_node_stats()["jvm"]["gc"]["collectors"]["old"]
+
+    old0 = old()
+    t0 = time.monotonic_ns()
+    with tracing.request() as rid, tracing.span("fetch.hits"):
+        time.sleep(0.001)
+        gc.collect()
+        time.sleep(0.001)
+    t1 = time.monotonic_ns()
+    old1 = old()
+    me = threading.get_ident()
+    recs = [r for r in tracing.ring_records(t0, t1) if r[4] == me]
+    hits = next(r for r in recs if r[3] == "fetch.hits")
+    stops = [r for r in recs if r[3] == "interp.collect"]
+    assert len(stops) == 1
+    stop = stops[0]
+    # nested under the span it stopped, in its request, CPU not taken
+    assert stop[1] == hits[0] and stop[2] == rid and stop[7] == -1
+    assert hits[5] <= stop[5] < stop[6] <= hits[6]
+    assert tracing.SPAN_LAYERS["interp.collect"] == "interpreter"
+    # the span's self time no longer holds the stop
+    assert _self_ns(recs)[hits[0]] == (hits[6] - hits[5]) - (stop[6] - stop[5])
+    # ...and the operator's counters saw it
+    assert old1["collection_count"] == old0["collection_count"] + 1
+    assert old1["collection_time_in_millis"] >= old0[
+        "collection_time_in_millis"]
+
+
+def test_a_collection_under_the_rings_own_lock_is_parked_not_lost(
+        no_automatic_collection):
+    """The collector runs on whatever thread allocated — perhaps inside
+    a wrapping ring write that holds the ring's lock. Its record must
+    neither wait for that lock (a deadlock) nor be dropped."""
+    tracing.reset(ring_cap=8)
+    try:
+        for _ in range(8):
+            with tracing.span("jit.pack"):
+                pass
+        t_full = time.monotonic_ns()
+        parked = []
+
+        def collect_holding_the_lock():
+            with tracing._ring.lock:
+                gc.collect()
+                parked.extend(tracing._ring.parked)
+
+        worker = threading.Thread(target=collect_holding_the_lock)
+        worker.start()
+        worker.join(30)
+        assert not worker.is_alive()
+        assert [tracing.SPAN_NAMES[r[3]] for r in parked] == [
+            "interp.collect"]
+        with tracing.span("fetch.hits"):    # the next close writes it
+            pass
+        assert tracing._ring.parked == []
+        recs = tracing.ring_records(t_full, time.monotonic_ns())
+        assert [r[3] for r in recs] == ["interp.collect", "fetch.hits"]
+        assert recs[0][4] == worker.ident and recs[0][1] == 0
+    finally:
+        tracing.reset()
+
+
+def test_collections_are_counted_exactly_under_contention():
+    """The per-generation integers are written by the collector's
+    callback alone, one collection at a time: many threads allocating
+    at a short switch interval lose no count against the interpreter's
+    own."""
+    import sys
+
+    def counts():
+        # both books read with the collector held still between them
+        was = gc.isenabled()
+        gc.disable()
+        try:
+            return ([g["collections"] for g in gc.get_stats()],
+                    tracing.collector_stats())
+        finally:
+            if was:
+                gc.enable()
+
+    before, mine0 = counts()
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        def churn():
+            for i in range(300):
+                junk = [[j] for j in range(200)]
+                if i % 100 == 0:
+                    gc.collect(i // 100 % 3)
+                del junk
+
+        threads = [threading.Thread(target=churn) for _ in range(16)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(60)
+        assert not any(th.is_alive() for th in threads)
+    finally:
+        sys.setswitchinterval(prev)
+    after, mine1 = counts()
+    young = after[0] - before[0] + after[1] - before[1]
+    assert mine1["young"]["collection_count"] \
+        - mine0["young"]["collection_count"] == young > 0
+    assert mine1["old"]["collection_count"] \
+        - mine0["old"]["collection_count"] == after[2] - before[2] > 0
+
+
 @pytest.mark.parametrize("level", [0, 1])
 def test_annotations_reach_a_trace_only_at_host_tracer_level_1(
         level, tmp_path, fresh_ring):
-    """What ISSUE 26 rests on: ``benchmarks/harness.traced_slice``
-    records at ``host_tracer_level`` 0, where a ``TraceAnnotation``
-    leaves no event; at level 1 the spans are in the ``.xplane.pb``
-    under ``es.<name>`` with their ``request``."""
+    """``benchmarks/harness.traced_slice`` records at
+    ``host_tracer_level`` 0, where a ``TraceAnnotation`` leaves no
+    event; at level 1 every ring record of the session — a full
+    collection's too — is in the ``.xplane.pb`` as ``es.<name>`` with
+    its ``request``, at one constant offset from the ring's clock: the
+    join that names a device trace's idle gaps by span."""
     import glob
 
     import jax
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = level
+    was = gc.isenabled()
+    gc.disable()
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
+        t0 = time.monotonic_ns()
         with tracing.request() as rid, tracing.span("fetch.hits"):
             with tracing.device_span("dispatch"):
                 time.sleep(0.001)
+            time.sleep(0.002)
+            gc.collect()
+            time.sleep(0.002)
+            with tracing.span("jit.unpack"):
+                time.sleep(0.001)
+        t1 = time.monotonic_ns()
     finally:
         jax.profiler.stop_trace()
+        if was:
+            gc.enable()
+    me = threading.get_ident()
+    recs = [r for r in tracing.ring_records(t0, t1) if r[4] == me]
+    assert sorted(r[3] for r in recs) == [
+        "fetch.hits", "interp.collect", "jit.enqueue", "jit.unpack"]
     found = {}
     for path in glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
                           recursive=True):
@@ -642,12 +822,18 @@ def test_annotations_reach_a_trace_only_at_host_tracer_level_1(
             for line in plane.lines:
                 for ev in line.events:
                     if ev.name.startswith("es."):
-                        found[ev.name] = {k: v for k, v in ev.stats}
+                        found.setdefault(ev.name, []).append(
+                            (ev.start_ns, {k: v for k, v in ev.stats}))
     if level == 0:
         assert found == {}
-    else:
-        assert set(found) == {"es.fetch.hits", "es.jit.enqueue"}
-        assert all(int(st["request"]) == rid for st in found.values())
+        return
+    assert set(found) == {f"es.{r[3]}" for r in recs}
+    offsets = []
+    for r in recs:
+        (start, stats), = found[f"es.{r[3]}"]
+        assert int(stats["request"]) == rid
+        offsets.append(start - r[5])
+    assert max(offsets) - min(offsets) <= 100_000
 
 
 def test_every_span_name_in_the_package_is_in_the_table():
@@ -668,7 +854,8 @@ def test_every_span_name_in_the_package_is_in_the_table():
     for name in ("plan.knn", "plan.rescore", "plan.impact", "plan.exact",
                  "action.shard", "action.shard_query",
                  "action.shard_fetch", "scheduler.launch",
-                 "scheduler.drain"):
+                 "scheduler.drain", "interp.collect"):
         assert name in tracing.SPAN_LAYERS
     assert set(tracing.SPAN_LAYERS.values()) == {
-        "rest", "action", "scheduler", "planner", "jit_exec", "fetch"}
+        "rest", "action", "scheduler", "planner", "jit_exec", "fetch",
+        "interpreter"}
