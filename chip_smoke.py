@@ -207,9 +207,9 @@ class Corpus:
         rows = hi - lo
         np_rows = doc_count_bucket(rows)
 
-        def pad(a, fill):
+        def pad(a, fill, at=slice(lo, hi)):
             out = np.full((np_rows,) + a.shape[1:], fill, a.dtype)
-            out[:rows] = a[lo:hi]
+            out[:rows] = a[at]
             return out
 
         seg_df = np.zeros(self.vocab, np.int64)
@@ -228,10 +228,14 @@ class Corpus:
             doc_len=pad(self.lens, 0), df=seg_df, num_docs=rows,
             ids=[str(lo + i) for i in range(rows)]
             + [""] * (np_rows - rows), vectors=vectors)
+        # the segment stores its documents longest first: the columns
+        # added beside the text follow the rows its ids name
+        stored = np.array([int(i) for i in seg.ids[:rows]], np.int64)
         seg.numeric_fields["rank"] = NumericFieldColumn(
-            values=pad(self.rank, 0.0), exists=exists.copy())
+            values=pad(self.rank, 0.0, stored), exists=exists.copy())
         seg.keyword_fields["cat"] = KeywordFieldColumn(
-            vocab=list(self.cat_names), ords=pad(self.cat[:, None], -1))
+            vocab=list(self.cat_names),
+            ords=pad(self.cat[:, None], -1, stored))
         return seg
 
 

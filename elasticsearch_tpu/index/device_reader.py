@@ -33,6 +33,9 @@ class DeviceTextField:
     utf: Any         # [Np, U] f32
     doc_len: Any     # [Np] i32
     column: Any      # host TextFieldColumn (term dict, df)
+    # ops/lexical.SlotPlan of ``uterms`` (static: how far each row chunk
+    # is scored); None scores the whole width
+    slot_plan: Any = None
 
 
 @dataclass
@@ -237,7 +240,8 @@ class DeviceReader:
             text[name] = DeviceTextField(
                 tokens=np.ascontiguousarray(c.tokens),    # lazy (see above)
                 uterms=put(c.uterms),
-                utf=put(c.utf), doc_len=put(c.doc_len), column=c)
+                utf=put(c.utf), doc_len=put(c.doc_len), column=c,
+                slot_plan=c.slot_plan)
         keyword = {name: DeviceKeywordField(ords=put(c.ords), column=c)
                    for name, c in seg.keyword_fields.items()}
         numeric = {}

@@ -36,10 +36,12 @@ engine as per-segment live-bitmaps (Lucene's .liv files).
 
 from __future__ import annotations
 
+import functools
 import json
+import os
 from dataclasses import dataclass, field as dc_field
 from pathlib import Path
-from typing import Any, Iterable
+from typing import Any, Iterable, Sequence
 
 import numpy as np
 
@@ -267,6 +269,37 @@ def build_impact_column(col: TextFieldColumn, *, df: np.ndarray,
                         quant_gen=quant_gen)
 
 
+def _longest_first(doc_len: np.ndarray, num_docs: int) -> np.ndarray | None:
+    """The row order that puts the real rows longest first (a stable
+    sort) and the pad rows after them — None where the rows stand in that
+    order already."""
+    lens = doc_len[:num_docs]
+    if bool((lens[1:] <= lens[:-1]).all()):
+        return None
+    return np.concatenate([
+        np.argsort(-lens.astype(np.int64), kind="stable"),
+        np.arange(num_docs, doc_len.shape[0])])
+
+
+def _rows_in_order(a: np.ndarray, order: np.ndarray) -> np.ndarray:
+    """``a[order]`` along rows, gathered by a few threads where the column
+    is large (a column of a 2^20-row segment is about 1 GB; ``np.take``
+    releases the interpreter lock)."""
+    if a.nbytes < 1 << 26:
+        return np.take(a, order, axis=0)
+    from concurrent.futures import ThreadPoolExecutor
+    out = np.empty((order.shape[0],) + a.shape[1:], a.dtype)
+    k = min(8, os.cpu_count() or 1)
+    edges = [order.shape[0] * i // k for i in range(k + 1)]
+
+    def part(i: int) -> None:
+        lo, hi = edges[i], edges[i + 1]
+        np.take(a, order[lo:hi], axis=0, out=out[lo:hi], mode="clip")
+    with ThreadPoolExecutor(k) as pool:
+        list(pool.map(part, range(k)))
+    return out
+
+
 def doc_count_bucket(n: int) -> int:
     """Bucketized row padding: bounds the number of distinct compiled shapes
     as segments grow (SURVEY.md §7 'Incrementality'). Geometric buckets:
@@ -301,6 +334,13 @@ class TextFieldColumn:
     def tid(self, term: str) -> int:
         """Query-time term lookup; -1 = term absent from this segment."""
         return self.term_index.get(term, -1)
+
+    @functools.cached_property
+    def slot_plan(self):
+        """How far each row chunk of ``uterms`` holds terms
+        (``ops/lexical.plan_slots``), read from the column once."""
+        from elasticsearch_tpu.ops.lexical import plan_slots
+        return plan_slots(self.uterms)
 
     def ctf(self, tid: int) -> float:
         """Collection term frequency (Σ tf over docs) for one term id.
@@ -451,7 +491,7 @@ class Segment:
     seg_id: int
     num_docs: int                    # true doc count (rows beyond are pad)
     padded_docs: int
-    ids: list[str]                   # local doc → _id
+    ids: Sequence[str]               # local doc → _id
     sources: list[dict]              # stored fields (_source)
     text_fields: dict[str, TextFieldColumn]
     keyword_fields: dict[str, KeywordFieldColumn]
@@ -527,18 +567,52 @@ class Segment:
         queries rejected); rows at and beyond ``num_docs`` are padding.
         ``vectors``: dense_vector columns beside the text, field →
         ``(vecs [Np, D], exists [Np])`` (:meth:`packed_vector_column`).
+
+        The real rows are stored longest document first (a stable sort:
+        equal lengths keep the loader's order), every per-row column,
+        ``ids`` and ``sources`` moved alike; pad rows stay at the end. A
+        document keeps its ``_id`` (the default ``str(i)`` names the
+        loader's row i). Internal doc numbers carry no meaning, as in
+        Lucene, and Elasticsearch's index sorting orders a segment the
+        same way; rows of like length in one chunk are what lets
+        ``bm25_match`` stop at each chunk's last used slot
+        (``ops/lexical.SlotPlan``).
         """
         np_docs = int(uterms.shape[0])
         has_positions = tokens is not None
-        if tokens is None:
-            tokens = np.full((np_docs, 8), -1, np.int32)
-        if not (tokens.shape[0] == np_docs == doc_len.shape[0]
-                == utf.shape[0]):
+        if not (np_docs == doc_len.shape[0] == utf.shape[0] and (
+                tokens is None or tokens.shape[0] == np_docs)):
             raise ValueError("packed columns disagree on row count")
         if num_docs > np_docs:
             raise ValueError(f"num_docs {num_docs} > padded rows {np_docs}")
         if total_tokens is None:
             total_tokens = int(np.asarray(doc_len[:num_docs]).sum())
+        if ids is None:
+            ids = [str(i) for i in range(num_docs)] + \
+                [""] * (np_docs - num_docs)
+        order = _longest_first(np.asarray(doc_len), num_docs)
+        if order is not None:
+            uterms, utf, doc_len = (_rows_in_order(a, order)
+                                    for a in (uterms, utf, doc_len))
+            if has_positions:
+                tokens = _rows_in_order(tokens, order)
+            real = order[:num_docs].tolist()
+            ids = [ids[i] for i in real] + list(ids[num_docs:])
+            if sources is not None:
+                sources = [sources[i] for i in real] + \
+                    list(sources[num_docs:])
+            vectors = {name: (_rows_in_order(v, order),
+                              _rows_in_order(ex, order))
+                       for name, (v, ex) in (vectors or {}).items()}
+        if tokens is None:
+            tokens = np.full((np_docs, 8), -1, np.int32)
+        # a tuple of strings, which the interpreter's collector stops
+        # walking (CPython untracks it); a list is walked by every full
+        # collection, and in length order its strings lie scattered in
+        # memory (on a CPU host, 4 x 2^20 ids: a full collection took
+        # 47 ms in the loader's order, 97 ms in length order, 11 ms as
+        # tuples)
+        ids = tuple(ids)
         col = TextFieldColumn(
             terms=list(terms),
             tokens=np.ascontiguousarray(tokens, dtype=np.int32),

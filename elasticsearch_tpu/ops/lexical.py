@@ -30,16 +30,127 @@ B = 64 the pass is no longer bound by the column read but by the
 compares (64 x T a slot): what a pad costs there is in PERF.md section 6,
 PR 33. ``classic_match`` and ``lm_dirichlet_match`` still make one pass
 per term, so their lists are not padded.
+
+**The slot plan.** U is as wide as the column's longest row, and a slot
+past the last term a row holds is the -1 pad: it earns nothing, yet the
+pass compares it with every query term. A :class:`SlotPlan` bounds the
+pass: the column's rows in ``SLOT_CHUNKS`` equal chunks, each scored only
+up to the last slot any of its rows uses (rounded up to ``SLOT_ALIGN``).
+Where rows of every length are mixed every chunk needs the whole width;
+a segment whose rows are ordered by length (``Segment.from_packed_text``)
+needs a third of it on MS MARCO's lengths. The plan is read from the
+column (:func:`plan_slots`), so it is exact for any layout: a slot past a
+chunk's width is -1 in every row of the chunk and would add 0.0 and a
+count of 0. It is static — programs are keyed on it
+(``jit_exec.layout_key``).
 """
 
 from __future__ import annotations
 
+from dataclasses import dataclass
+
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+#: equal row chunks of a column's slot plan, and the slot multiple each
+#: chunk's width is rounded up to — fixed, so that one corpus shape gives
+#: one plan (and one compiled program) on every seed
+SLOT_CHUNKS = 16
+SLOT_ALIGN = 16
+#: a column of fewer rows has no plan: its pass is short, and the many
+#: small segments the per-document path refreshes into one row bucket
+#: keep sharing one compiled program
+SLOT_PLAN_MIN_ROWS = 1 << 16
+
+
+def chunk_bounds(rows: int,
+                 chunks: int = SLOT_CHUNKS) -> list[tuple[int, int]]:
+    """(first row, end row) of each of ``chunks`` equal chunks of ``rows``."""
+    edges = [rows * c // chunks for c in range(chunks + 1)]
+    return list(zip(edges[:-1], edges[1:]))
+
+
+@dataclass(frozen=True)
+class SlotPlan:
+    """The slots each row chunk of a ``rows``-row column is scored up to:
+    chunk c (``chunk_bounds(rows)[c]``) to ``widths[c]``; past it every
+    row of the chunk holds the -1 pad."""
+    rows: int
+    widths: tuple[int, ...]
+
+    @staticmethod
+    def of(rows: int, u: int, needed) -> "SlotPlan":
+        """The plan whose chunk c needs its first ``needed[c]`` slots."""
+        return SlotPlan(rows, tuple(
+            min(-(-int(n) // SLOT_ALIGN) * SLOT_ALIGN, u) for n in needed))
+
+    def merge(self, other: "SlotPlan") -> "SlotPlan":
+        """A plan exact for both columns (of one row count): the wider
+        width chunk by chunk."""
+        if other.rows != self.rows or len(other.widths) != len(self.widths):
+            raise ValueError("slot plans of different row chunks")
+        return SlotPlan(self.rows, tuple(
+            max(a, b) for a, b in zip(self.widths, other.widths)))
+
+    def runs(self) -> list[tuple[int, int, int]]:
+        """(first row, end row, width) of each run of neighbouring chunks
+        of one width — one pass each."""
+        out: list[list[int]] = []
+        for (a, e), w in zip(chunk_bounds(self.rows, len(self.widths)),
+                             self.widths):
+            if out and out[-1][2] == w:
+                out[-1][1] = e
+            else:
+                out.append([a, e, w])
+        return [tuple(r) for r in out]
+
+    def slots(self) -> int:
+        """Row × slot pairs the plan scores (one compare each a term)."""
+        return sum((e - a) * w for a, e, w in self.runs())
+
+
+def plan_slots(uterms) -> SlotPlan | None:
+    """The slot plan of a host ``[N, U]`` unique-term column: each chunk
+    up to 1 + the last slot that holds a term id (>= 0) in any of its
+    rows. None under ``SLOT_PLAN_MIN_ROWS`` rows."""
+    n, u = uterms.shape
+    if n < SLOT_PLAN_MIN_ROWS:
+        return None
+    needed = []
+    for a, e in chunk_bounds(n):
+        used = np.flatnonzero(uterms[a:e].max(axis=0) >= 0)
+        needed.append(int(used[-1]) + 1 if used.size else 0)
+    return SlotPlan.of(n, u, needed)
+
+
+def _score_rows(uterms, utf, doc_len, tids, tweight, k1, b, avgdl):
+    """The one pass over the rows and slots it is given."""
+    norm = k1 * (1.0 - b + b * doc_len.astype(jnp.float32) / avgdl)   # [N]
+    tf_norm = utf * (k1 + 1.0) / (utf + norm[:, None])                # [N, U]
+    w = jnp.zeros(uterms.shape, jnp.float32)     # weight a slot earns
+    cnt = jnp.zeros(uterms.shape, jnp.int32)     # query terms hitting it
+    for t in range(tids.shape[0]):  # T is static: T compares a slot
+        hit = uterms == tids[t]
+        w = w + jnp.where(hit, tweight[t], 0.0)
+        cnt = cnt + hit
+    # a select, not tf_norm * w alone: a slot no term hit has w == 0 and
+    # must add 0 even where tf_norm is 0/0 (a pad of a row whose norm is 0)
+    scores = jnp.where(w != 0.0, tf_norm * w, 0.0).sum(axis=1)
+    nmatch = cnt.sum(axis=1)
+    return scores, nmatch
+
+
+# a plan's passes, jitted: the segments of a reader share their runs'
+# shapes, so a program traces each shape once (traced inline, seven runs
+# a segment at 12 terms took a B = 64 program's tracing on a CPU host
+# from 0.3 s to 1.5 s)
+_score_run = jax.jit(_score_rows, static_argnames=("k1", "b"))
 
 
 @jax.named_scope("bm25_score")
-def bm25_match(uterms, utf, doc_len, qtids, qidf, qweight, k1, b, avgdl):
+def bm25_match(uterms, utf, doc_len, qtids, qidf, qweight, k1, b, avgdl,
+               slot_plan: SlotPlan | None = None):
     """Score a (multi-term, OR-semantics) match query against one segment.
 
     Args:
@@ -51,6 +162,11 @@ def bm25_match(uterms, utf, doc_len, qtids, qidf, qweight, k1, b, avgdl):
       qweight: [T]    f32    per-term boost (match queries use 1.0)
       k1, b:   BM25 params (python floats — static under jit)
       avgdl:   f32 scalar    average field length (aggregated host-side)
+      slot_plan: the column's :class:`SlotPlan` (static): every chunk of
+               rows is scored up to its width and the chunks' results
+               concatenated — the unbounded pass's up to the order of a
+               float sum along U. None, or a plan of another row count
+               (not this column's), scores the whole width.
 
     Returns:
       scores:  [N] f32  Σ_t idf_t · tfNorm(tf_t,d)
@@ -58,23 +174,25 @@ def bm25_match(uterms, utf, doc_len, qtids, qidf, qweight, k1, b, avgdl):
                the query repeats counts twice; drives
                minimum_should_match / operator=and)
     """
-    norm = k1 * (1.0 - b + b * doc_len.astype(jnp.float32) / avgdl)   # [N]
-    tf_norm = utf * (k1 + 1.0) / (utf + norm[:, None])                # [N, U]
     # an absent term (any negative id) becomes -2, which no slot holds:
     # a slot is a term id >= 0 or the -1 pad
     tids = jnp.where(qtids >= 0, qtids, -2)
     tweight = qidf * qweight
-    w = jnp.zeros(uterms.shape, jnp.float32)     # weight a slot earns
-    cnt = jnp.zeros(uterms.shape, jnp.int32)     # query terms hitting it
-    for t in range(qtids.shape[0]):  # T is static: T compares a slot
-        hit = uterms == tids[t]
-        w = w + jnp.where(hit, tweight[t], 0.0)
-        cnt = cnt + hit
-    # a select, not tf_norm * w alone: a slot no term hit has w == 0 and
-    # must add 0 even where tf_norm is 0/0 (a pad of a row whose norm is 0)
-    scores = jnp.where(w != 0.0, tf_norm * w, 0.0).sum(axis=1)
-    nmatch = cnt.sum(axis=1)
-    return scores, nmatch
+    n, u = uterms.shape
+    if slot_plan is None or slot_plan.rows != n \
+            or all(w == u for w in slot_plan.widths):
+        return _score_rows(uterms, utf, doc_len, tids, tweight, k1, b, avgdl)
+    scores, nmatch = [], []
+    for a, e, w in slot_plan.runs():
+        if w == 0:                    # every row of the run is all pad
+            scores.append(jnp.zeros(e - a, jnp.float32))
+            nmatch.append(jnp.zeros(e - a, jnp.int32))
+            continue
+        s, m = _score_run(uterms[a:e, :w], utf[a:e, :w], doc_len[a:e],
+                          tids, tweight, k1, b, avgdl)
+        scores.append(s)
+        nmatch.append(m)
+    return jnp.concatenate(scores), jnp.concatenate(nmatch)
 
 
 def term_filter(uterms, qtid):

@@ -98,6 +98,7 @@ plane.
 from __future__ import annotations
 
 import bisect
+import functools
 import math
 import threading
 from collections import OrderedDict
@@ -120,6 +121,7 @@ from elasticsearch_tpu.observability.tracing import (
 # an import executed at trace time caches foreign tracers into the
 # imported module's globals (trace-purity rule)
 from elasticsearch_tpu.ops import aggs_ops, topk as topk_ops
+from elasticsearch_tpu.ops.lexical import SlotPlan, plan_slots
 from elasticsearch_tpu.search import dfs as dfs_mod
 from elasticsearch_tpu.search.execute import ExecutionContext
 from elasticsearch_tpu.search.jit_exec import (
@@ -1166,13 +1168,15 @@ def _build_template(lay: _SlotLayout, seg, live, doc_base: int
                 df=np.zeros(1, np.int32), total_tokens=0)
             text[name] = DeviceTextField(
                 tokens=c.tokens, uterms=c.uterms, utf=c.utf,
-                doc_len=c.doc_len, column=c)
+                doc_len=c.doc_len, column=c, slot_plan=c.slot_plan)
         else:
+            uterms = _fit(c.uterms, (n, U), -1)
             text[name] = DeviceTextField(
-                tokens=_fit(c.tokens, (n, L), -1),
-                uterms=_fit(c.uterms, (n, U), -1),
+                tokens=_fit(c.tokens, (n, L), -1), uterms=uterms,
                 utf=_fit(c.utf, (n, U), 0.0),
-                doc_len=_fit(c.doc_len, (n,), 0), column=c)
+                doc_len=_fit(c.doc_len, (n,), 0), column=c,
+                slot_plan=c.slot_plan if uterms is c.uterms
+                else plan_slots(uterms))
     keyword = {}
     for name, kdim in lay.keyword.items():
         c = seg.keyword_fields.get(name) if seg is not None else None
@@ -1390,6 +1394,20 @@ class MeshEngineSearcher:
                 _block_cache.prune(
                     engines[si].engine_uuid,
                     {g.block_uid for g in views[si].segments})
+        # the program scores every shard of a slot through shard 0's
+        # template: each text column's slot plan is the widest of the
+        # shards' (none where a shard has none), exact for all of them
+        for j in range(self.n_slots):
+            tpls = [self._templates[si][j] for si in range(s)]
+            plans = {}
+            for name in tpls[0].text:
+                own = [t.text[name].slot_plan for t in tpls]
+                plans[name] = None if None in own else \
+                    functools.reduce(SlotPlan.merge, own)
+            for si, tpl in enumerate(tpls):
+                self._templates[si][j] = dc_replace(tpl, text={
+                    name: dc_replace(col, slot_plan=plans[name])
+                    for name, col in tpl.text.items()})
         # one reader facade a shard for the life of this point-in-time
         # pack: its text statistics are read once, not once a plan
         self._readers = [_TemplateReader(self._templates[si], views[si])
@@ -2105,6 +2123,7 @@ class MeshEngineSearcher:
         # dispatch
         sigs, layouts, emits, pfs, refss = [], [], [], [], []
         stacked_np = []
+        slots_scored = slots_full = 0
         from elasticsearch_tpu.search import jit_exec
         from elasticsearch_tpu.search.execute import match_term_floor
         with span("plane.resolve"):
@@ -2149,6 +2168,8 @@ class MeshEngineSearcher:
                                 "signature per batch (mixed query "
                                 "structures) — use the RPC fan-out path")
                         row.append(ct.values)
+                        slots_scored += ct.match_slots_scored
+                        slots_full += ct.match_slots_full
                     # the rows that pad the batch to its bucket repeat
                     # the last request's constants
                     rows.append(row + [row[-1]] * (b_pad - b_real))
@@ -2249,6 +2270,7 @@ class MeshEngineSearcher:
         jit_exec.note_plane_dispatch(
             self.n_shards * b_pad * (k * 4 * (2 + 2 * n_spec
                                               + bool(sort_specs)) + 4))
+        jit_exec.note_slot_compares(slots_scored, slots_full)
         if not sort_specs:
             # every shard takes each slot's top k for every batch row
             jit_exec.note_topk_rows(

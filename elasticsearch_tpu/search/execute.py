@@ -62,7 +62,8 @@ class ConstTable:
 
     __slots__ = ("values", "sig", "positions_needed", "vectors_needed",
                  "match_terms_real", "match_terms_padded",
-                 "phrase_terms_real", "phrase_terms_padded")
+                 "phrase_terms_real", "phrase_terms_padded",
+                 "match_slots_scored", "match_slots_full")
 
     def __init__(self):
         self.values: list[np.ndarray] = []
@@ -84,6 +85,10 @@ class ConstTable:
         # the same for exact phrases (pads match every position)
         self.phrase_terms_real = 0
         self.phrase_terms_padded = 0
+        # row x slot compares the plan's BM25 passes make under the
+        # columns' slot plans, and would make over the whole width
+        self.match_slots_scored = 0
+        self.match_slots_full = 0
 
     def add(self, v, dtype=None) -> int:
         arr = np.asarray(v, dtype=dtype)
@@ -451,6 +456,17 @@ class SegmentResolver:
             idfs.append(bm25_idf(df, doc_count) if df > 0 else 0.0)
         return tids, idfs
 
+    def _count_slots(self, field: str, n_terms: int) -> None:
+        """Book the row x slot compares of one BM25 pass of ``n_terms``
+        over ``field``: under the column's slot plan and at full width."""
+        col = self.seg.text[field]
+        rows, width = col.uterms.shape
+        full = n_terms * rows * width
+        plan = col.slot_plan
+        self.ct.match_slots_full += full
+        self.ct.match_slots_scored += full if plan is None or \
+            plan.rows != rows else n_terms * plan.slots()
+
     def _res_MatchQuery(self, query: q.MatchQuery) -> Emit:
         field = query.field
         if field in ("*", "_all"):
@@ -550,12 +566,15 @@ class SegmentResolver:
         r_boost = self.c(query.boost, np.float32)
         p = self.ctx.bm25
 
+        self._count_slots(field, n_terms)
+
         def emit(em):
             col = em.seg.text[field]
             scores, nmatch = lexical.bm25_match(
                 col.uterms, col.utf, col.doc_len,
                 jnp.asarray(em.get(r_tids)), jnp.asarray(em.get(r_idfs)),
-                jnp.ones(n_terms, jnp.float32), p.k1, p.b, em.get(r_avgdl))
+                jnp.ones(n_terms, jnp.float32), p.k1, p.b, em.get(r_avgdl),
+                col.slot_plan)
             if msm1:
                 # OR semantics: the bm25 sum is already 0 on non-matching
                 # docs, so the mask is just scores > 0 and no re-zeroing
@@ -1128,6 +1147,9 @@ class SegmentResolver:
             return (self.c([t for t, _ in pairs], np.int32),
                     self.c([i for _, i in pairs], np.float32), len(pairs))
         g_low, g_high = group(low), group(high)
+        for g in (g_low, g_high):
+            if g is not None:
+                self._count_slots(field, g[2])
         r_msm_low = self.c(msm_low, np.int32) if g_low else None
         r_msm_high = self.c(msm_high, np.int32) if g_high else None
 
@@ -1139,7 +1161,8 @@ class SegmentResolver:
                 return lexical.bm25_match(
                     col.uterms, col.utf, col.doc_len,
                     jnp.asarray(em.get(r_tids)), jnp.asarray(em.get(r_idfs)),
-                    jnp.ones(n, jnp.float32), p.k1, p.b, em.get(r_avgdl))
+                    jnp.ones(n, jnp.float32), p.k1, p.b, em.get(r_avgdl),
+                    col.slot_plan)
             if g_low is not None:
                 low_s, low_n = score_group(g_low)
                 mask = low_n >= em.get(r_msm_low)
@@ -1573,6 +1596,8 @@ class SegmentResolver:
         self.sig("mlt-excl", exclude is not None)
         r_avgdl = {f: self.c(self._avgdl(f), np.float32)
                    for f, *_ in groups}
+        for f, _, _, n in groups:
+            self._count_slots(f, n)
         p = self.ctx.bm25
 
         def emit(em):
@@ -1584,7 +1609,7 @@ class SegmentResolver:
                     col.uterms, col.utf, col.doc_len,
                     jnp.asarray(em.get(r_tids)), jnp.asarray(em.get(r_idfs)),
                     jnp.ones(n, jnp.float32), p.k1, p.b,
-                    em.get(r_avgdl[f]))
+                    em.get(r_avgdl[f]), col.slot_plan)
                 scores = scores + s
                 nmatch = nmatch + nm
             mask = nmatch >= em.get(r_msm)

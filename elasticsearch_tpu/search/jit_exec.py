@@ -744,7 +744,9 @@ def layout_key(seg: DeviceSegment) -> tuple:
             out.append((kind, name) + tuple(
                 (tuple(getattr(col, attr).shape),
                  str(getattr(col, attr).dtype))
-                for attr in _ARRAYS[kind]))
+                for attr in _ARRAYS[kind])
+                # a text column's slot plan shapes its BM25 passes
+                + ((col.slot_plan,) if kind == "text" else ()))
     for path in sorted(seg.nested):
         blk = seg.nested[path]
         out.append(("nested", path, tuple(blk.parent.shape),
@@ -890,6 +892,7 @@ def run_segment(seg: DeviceSegment, ctx: ExecutionContext, query,
         out = fn(flat, consts)
     if k is not None:
         note_topk_rows((seg.padded_docs,), k_static, 1)
+    note_slot_compares(ct.match_slots_scored, ct.match_slots_full)
     return out
 
 
@@ -922,6 +925,8 @@ def _plan_segment_batch(seg: DeviceSegment, ctx: ExecutionContext,
     consts_rows: list[list[np.ndarray]] = []
     # per query: (real, pads) of its match terms, then of its phrase terms
     match_terms: list[tuple[int, int, int, int]] = []
+    # per query: row x slot compares of its BM25 passes (scored, full)
+    slots: list[tuple[int, int]] = []
     for query in queries:
         ct, emit_q, _, refs = _plan(seg, ctx, query, None, flags,
                                     term_floor)
@@ -934,6 +939,7 @@ def _plan_segment_batch(seg: DeviceSegment, ctx: ExecutionContext,
         consts_rows.append(ct.values)
         match_terms.append((ct.match_terms_real, ct.match_terms_padded,
                             ct.phrase_terms_real, ct.phrase_terms_padded))
+        slots.append((ct.match_slots_scored, ct.match_slots_full))
 
     b = len(queries)
     # pad the batch axis to the next power of two (repeating the last
@@ -968,7 +974,7 @@ def _plan_segment_batch(seg: DeviceSegment, ctx: ExecutionContext,
         "key": (sig0, layout_key(seg), pos_for, vecs,
                 float(ctx.bm25.k1), float(ctx.bm25.b), k_static, b_pad,
                 tuple(specs)),
-        "k": k_static, "match_terms": match_terms,
+        "k": k_static, "match_terms": match_terms, "slots": slots,
     }
 
 
@@ -1061,8 +1067,10 @@ def run_reader_batch(segments: list, ctx: ExecutionContext, queries: list,
                            n_real if n_real is not None else b, b_pad)):
         device_fault_point("dispatch")
         out = fn(flats, packeds)
-    note_match_terms(plans[0]["match_terms"][:b if n_real is None
-                                             else n_real])
+    real = b if n_real is None else n_real
+    note_match_terms(plans[0]["match_terms"][:real])
+    rows = [row for p in plans for row in p["slots"][:real]]
+    note_slot_compares(sum(r[0] for r in rows), sum(r[1] for r in rows))
     note_phrase_items(queries[:n_real], batched=True)
     note_topk_rows([seg.padded_docs for seg in segments], k_static, b_pad)
     if b_pad != b:
@@ -1185,6 +1193,8 @@ def run_segments_streamed(segments: list, ctx: ExecutionContext,
                 outs = fn(cur, packed)      # async dispatch
             stats["dispatch_s"] += time.perf_counter() - t1
             note_topk_rows((seg.padded_docs,), k_static, plan["b_pad"])
+            note_slot_compares(sum(r[0] for r in plan["slots"]),
+                               sum(r[1] for r in plan["slots"]))
             outs_all.append(outs)
             del cur                         # free as soon as compute drains
             if i >= 1:
@@ -2235,6 +2245,16 @@ def note_match_terms(rows: list) -> None:
         _bump("match_terms_padded", sum(r[1] for r in rows))
         _bump("phrase_terms_real", sum(r[2] for r in rows))
         _bump("phrase_terms_padded", sum(r[3] for r in rows))
+
+
+def note_slot_compares(scored: int, full: int) -> None:
+    """One dispatch whose BM25 passes compare ``scored`` row x slot pairs
+    with each query term under the columns' slot plans, where the whole
+    width would be ``full``."""
+    if full:
+        with _cache_lock:
+            _bump("match_slot_compares_scored", int(scored))
+            _bump("match_slot_compares_full", int(full))
 
 
 def note_phrase_items(queries: list, batched: bool) -> None:

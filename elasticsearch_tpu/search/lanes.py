@@ -74,6 +74,12 @@ JIT_COUNTERS = {
     "match_terms_padded": "absent terms that padded those rows' term "
                           "lists to the batch's term bucket (compared "
                           "with every slot, never scoring)",
+    "match_slot_compares_scored": "row x slot pairs the BM25 passes of "
+                                  "dispatches compared with each query "
+                                  "term, each row chunk up to its slot "
+                                  "plan's width",
+    "match_slot_compares_full": "the row x slot pairs those passes would "
+                                "compare over the columns' whole width",
     "phrase_terms_real": "terms of consecutive-term match_phrase nodes "
                          "the real rows of reader-batch dispatches "
                          "compared",

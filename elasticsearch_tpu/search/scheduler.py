@@ -817,23 +817,25 @@ class ContinuousBatchScheduler:
         if results is None:
             self._deliver_declined(live)
             return
+        # the books settle before any waiter wakes: a client that reads
+        # the stats after its reply must find its request counted
+        with self._lock:
+            self._inflight_reqs -= len(live)
+            self._delivered += len(live)
         # slice to the REAL waiters: pad rows never deliver (and never
         # counted — note_scheduler_batch took n_real)
         for w, res in zip(live, results):
             if not w.future.done():
                 w.future.set_result(res)
-        with self._lock:
-            self._inflight_reqs -= len(live)
-            self._delivered += len(live)
 
     def _deliver_declined(self, live: list) -> None:
+        with self._lock:
+            self._inflight_reqs -= len(live)
+            self._declined += len(live)
         for w in live:
             w.picked.set()
             if not w.future.done():
                 w.future.set_result(DECLINED)
-        with self._lock:
-            self._inflight_reqs -= len(live)
-            self._declined += len(live)
 
     # ---- SLO-burn shed gate ------------------------------------------------
 

@@ -17,6 +17,17 @@ from elasticsearch_tpu.node import Node
 from elasticsearch_tpu.transport.local import DROP, LocalTransportHub
 
 
+def _settled_use(breaker, timeout_s: float = 5.0) -> int:
+    """A breaker's reserved bytes once work still in flight has had
+    ``timeout_s`` to return them: a hedged search's losing copy may still
+    run after its request answered, and releases when it stops. A leak
+    never returns, so it still reads above zero."""
+    deadline = time.monotonic() + timeout_s
+    while breaker.used and time.monotonic() < deadline:
+        time.sleep(0.02)
+    return breaker.used
+
+
 class InternalTestCluster:
     """N nodes sharing one LocalTransportHub. First node elects itself;
     the rest join. Fast fault-detection defaults so failover tests run in
@@ -180,7 +191,7 @@ class InternalTestCluster:
                     bs = getattr(n, "breaker_service", None)
                     if bs is not None:
                         for bname in ("fielddata", "request"):
-                            used = bs.breaker(bname).used
+                            used = _settled_use(bs.breaker(bname))
                             if used:
                                 leaks.append(
                                     f"node [{n.settings.get('node.name')}]"

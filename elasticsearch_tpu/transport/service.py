@@ -14,6 +14,7 @@ from __future__ import annotations
 import threading
 import time
 import uuid
+from collections import OrderedDict
 from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable
@@ -31,6 +32,11 @@ DROP = "drop"
 #: it, which is what reordering IS on an ordered transport).
 DUPLICATE = "duplicate"
 REORDER = "reorder"
+
+#: inbound request ids remembered per node for at-most-once delivery; a
+#: duplicate arrives within the fault seam's reorder jitter, far inside
+#: this many later requests
+DELIVERED_WINDOW = 16384
 
 
 class TransportException(Exception):
@@ -187,7 +193,12 @@ class TransportService:
         self.transport = transport
         self._handlers: dict[str, _RequestHandler] = {}
         self._responses: dict[int, _ResponseContext] = {}
-        self._request_id = 0
+        # random start: a node restarted under its old id must not reuse
+        # ids that a peer still remembers in its delivered window
+        self._request_id = uuid.uuid4().int >> 88
+        # (source node_id, request_id) of recent inbound requests, oldest
+        # first: a frame the network delivers twice runs its handler once
+        self._delivered: OrderedDict[tuple[str, int], None] = OrderedDict()
         self._lock = threading.Lock()
         self._executor = executor or ThreadPoolExecutor(
             max_workers=8, thread_name_prefix="transport")
@@ -317,7 +328,22 @@ class TransportService:
     # ---- inbound (called by the Transport impl) ----------------------------
 
     def on_request(self, source: DiscoveryNode, request_id: int, action: str,
-                   payload: bytes, wire_version: int) -> None:
+                   payload: bytes, wire_version: int,
+                   on_accept: Callable[[], None] | None = None) -> None:
+        """Dispatch one inbound request. A redelivery of a request id
+        already seen from ``source`` is dropped: the first copy's handler
+        answers it, and a second run would answer differently (a
+        duplicated primary delete finds the document gone). ``on_accept``
+        runs for a first delivery only, before the handler can reply."""
+        key = (source.node_id, request_id)
+        with self._lock:
+            if key in self._delivered:
+                return
+            self._delivered[key] = None
+            if len(self._delivered) > DELIVERED_WINDOW:
+                self._delivered.popitem(last=False)
+        if on_accept is not None:
+            on_accept()
         self._trace("recv_request", action, source.node_id)
         channel = TransportChannel(self, source, request_id, action)
         reg = self._handlers.get(action)

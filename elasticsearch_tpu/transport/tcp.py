@@ -380,10 +380,15 @@ class TcpTransport:
             source = DiscoveryNode.from_wire(inp)
             action = inp.read_string()
             payload = inp.read_bytes()
-            with self._lock:
-                self._inbound_channels[(source.node_id, request_id)] = sock
+
+            def register_channel():
+                # first delivery only: a dropped duplicate must not leave
+                # a reply channel that no response will ever pop
+                with self._lock:
+                    self._inbound_channels[(source.node_id, request_id)] = \
+                        sock
             self._service.on_request(source, request_id, action, payload,
-                                     version)
+                                     version, on_accept=register_channel)
         elif msg_type == _RESP:
             self._service.on_response(request_id, inp.read_bytes(), None,
                                       version)

@@ -213,7 +213,8 @@ def test_served_path_matches_the_reference(node, seed):
     # more than one, and no phrase fell to the query-by-query path
     delivered = sched1["delivered"] - sched0["delivered"]
     launched = sched1["batches_launched"] - sched0["batches_launched"]
-    assert delivered == len(pool) and sched1["reconciled"]
+    assert delivered == len(pool) and sched1["reconciled"], \
+        (sched1, jit_exec.plane_breaker.stats())
     assert launched < delivered, "no batch of more than one formed"
     assert after["phrase_items_batched"] - before["phrase_items_batched"] \
         == len(pool)

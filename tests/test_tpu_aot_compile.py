@@ -293,6 +293,31 @@ def test_reader_batch_reads_columns_once(topo, no_persistent_cache, big,
 
 BM25_CELL_SEGMENTS = 4    # msmarco-bm25.msearch64-top1000: 4 x [2^20, 224]
 BM25_CELL_UNIQUE = 224
+# the slot plan of the cells' length-ordered segments, on every seed at
+# 2^20 and at 1,105,920 rows (tests/test_slot_bounds.py)
+CELL_SLOT_WIDTHS = (224, 112, 96, 80, 80, 64, 64, 64, 64, 48, 48, 48, 48,
+                    48, 32, 32)
+
+
+def with_cell_slot_plan(seg: Segment) -> Segment:
+    """Give a segment of ``np.zeros`` columns the slot plan of the cells'
+    lengths: zeros read as a term in every slot, and columns that hold
+    the pads past each length would back 3.7 GB of pages a segment."""
+    from elasticsearch_tpu.ops import lexical
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(lexical, "plan_slots", lambda u: lexical.SlotPlan(
+            u.shape[0], CELL_SLOT_WIDTHS))
+        plan = seg.text_fields["body"].slot_plan    # read once, then kept
+    assert plan.widths == CELL_SLOT_WIDTHS
+    return seg
+
+
+def bounded_passes(text: str, rows: int) -> list:
+    """The (rows, width) of each run of the cell plan's chunks that the
+    compiled program holds no trace of."""
+    from elasticsearch_tpu.ops.lexical import SlotPlan
+    return [(e - a, w) for a, e, w in SlotPlan(rows, CELL_SLOT_WIDTHS).runs()
+            if f"{e - a},{w}]" not in text]
 
 
 @contextlib.contextmanager
@@ -325,12 +350,12 @@ def bm25_cell(tmp_path_factory):
     w = len(str(VOCAB - 1))
     terms = [f"t{i:0{w}d}" for i in range(VOCAB)]
     for _ in range(BM25_CELL_SEGMENTS):
-        eng.install_segment(Segment.from_packed_text(
+        eng.install_segment(with_cell_slot_plan(Segment.from_packed_text(
             0, "body", terms=terms, tokens=None,
             uterms=np.zeros((ROWS, BM25_CELL_UNIQUE), np.int32),
             utf=np.zeros((ROWS, BM25_CELL_UNIQUE), np.float32),
             doc_len=np.ones(ROWS, np.int32), df=np.ones(VOCAB, np.int64),
-            num_docs=ROWS, ids=[""] * ROWS), track_versions=False)
+            num_docs=ROWS, ids=[""] * ROWS)), track_versions=False)
     yield eng, ms
     eng.close()
     jit_exec.clear_cache()
@@ -366,6 +391,8 @@ def test_mixed_msearch_at_the_benchmark_cells_shapes(
         ma.temp_size_in_bytes + ma.output_size_in_bytes) < HBM_BYTES
     # each segment's top 1000 in two stages: no sort of a whole row
     assert whole_row_selections(compiled.as_text(), BATCH * ROWS) == []
+    # each run of the slot plan's chunks scored to its own width
+    assert bounded_passes(compiled.as_text(), ROWS) == []
     cost = compiled.cost_analysis()
     print(f"[aot] mixed msearch64: flops {cost['flops']:.4g}, bytes "
           f"accessed {cost['bytes accessed']:.4g}")
@@ -568,7 +595,7 @@ def plane_cell(tmp_path_factory):
     for s in range(PLANE_CELL_SHARDS):
         eng = Engine(tmp_path_factory.mktemp(f"aot_plane_{s}"), ms)
         for j in range(PLANE_CELL_SHARD_SEGMENTS):
-            eng.install_segment(Segment.from_packed_text(
+            eng.install_segment(with_cell_slot_plan(Segment.from_packed_text(
                 j, "body", terms=terms, tokens=None,
                 uterms=np.zeros((PLANE_CELL_ROWS, BM25_CELL_UNIQUE),
                                 np.int32),
@@ -576,7 +603,7 @@ def plane_cell(tmp_path_factory):
                              np.float32),
                 doc_len=np.ones(PLANE_CELL_ROWS, np.int32),
                 df=np.ones(VOCAB, np.int64), num_docs=PLANE_CELL_ROWS,
-                ids=[""] * PLANE_CELL_ROWS), track_versions=False)
+                ids=[""] * PLANE_CELL_ROWS)), track_versions=False)
         engines.append(eng)
     yield engines, ms
     for eng in engines:
@@ -682,6 +709,8 @@ def test_plane_program_at_the_4shard_cells_shapes(
     assert text.startswith("HloModule jit_step_local"), text[:80]
     assert whole_row_selections(text, BATCH * PLANE_CELL_ROWS) == []
     assert "all-gather" in text, "no cross-chip candidate merge"
+    # each shard's slots scored run by run under their merged slot plan
+    assert bounded_passes(text, PLANE_CELL_ROWS) == []
     for scope in ("plane_score", "plane_select", "plane_gather",
                   "plane_merge", "bm25_score"):
         assert scope in text, scope
@@ -702,13 +731,13 @@ def phrase_cell(tmp_path_factory):
     w = len(str(VOCAB - 1))
     terms = [f"t{i:0{w}d}" for i in range(VOCAB)]
     for _ in range(PHRASE_CELL_SEGMENTS):
-        eng.install_segment(Segment.from_packed_text(
+        eng.install_segment(with_cell_slot_plan(Segment.from_packed_text(
             0, "body", terms=terms,
             tokens=np.zeros((ROWS, BM25_CELL_UNIQUE), np.int32),
             uterms=np.zeros((ROWS, BM25_CELL_UNIQUE), np.int32),
             utf=np.zeros((ROWS, BM25_CELL_UNIQUE), np.float32),
             doc_len=np.ones(ROWS, np.int32), df=np.ones(VOCAB, np.int64),
-            num_docs=ROWS, ids=[""] * ROWS), track_versions=False)
+            num_docs=ROWS, ids=[""] * ROWS)), track_versions=False)
     yield eng, ms
     eng.close()
     jit_exec.clear_cache()
@@ -747,6 +776,7 @@ def test_phrase_program_at_the_benchmark_cells_shapes(
         ma.temp_size_in_bytes + ma.output_size_in_bytes) < HBM_BYTES
     assert "phrase_match" in compiled.as_text()
     assert whole_row_selections(compiled.as_text(), 16 * ROWS) == []
+    assert bounded_passes(compiled.as_text(), ROWS) == []
 
 
 @pytest.mark.parametrize("deltas", [tuple(range(12)), (0, 2, 3)])

@@ -16,7 +16,7 @@ from elasticsearch_tpu.transport import (
     TcpTransport, TransportService,
 )
 from elasticsearch_tpu.transport.local import DROP
-from elasticsearch_tpu.transport.service import random_node_id
+from elasticsearch_tpu.transport.service import DUPLICATE, random_node_id
 
 
 # ---- wire codec ------------------------------------------------------------
@@ -217,6 +217,47 @@ def test_tcp_error_and_many(tcp_pair):
                 f.result(10.0)
         else:
             assert f.result(10.0) == {"v": i + 1}
+
+
+@pytest.mark.parametrize("kind", ["local", "tcp"])
+def test_duplicated_request_runs_its_handler_once(kind):
+    """A frame the network delivers twice runs the handler once: the
+    caller sees the first run's answer, never a second run's (a
+    duplicated delete would find the document gone), and the TCP side
+    keeps no reply channel for the dropped copy."""
+    if kind == "local":
+        hub = LocalTransportHub()
+        a, b = (make_local_service(hub, n) for n in ("node_a", "node_b"))
+    else:
+        a, b = (TransportService(
+            TcpTransport(),
+            lambda addr, n=n: DiscoveryNode(random_node_id(), n, addr))
+            for n in ("tcp_a", "tcp_b"))
+    try:
+        docs, runs = {"d": 1}, []
+
+        def delete(req, src):
+            runs.append(req["id"])
+            if docs.pop(req["id"], None) is None:
+                raise KeyError(f"[{req['id']}] missing")
+            return {"deleted": req["id"]}
+        b.register_request_handler("test:delete", delete, sync=True)
+        a.outbound_rule = lambda addr, action: \
+            (DUPLICATE, 2) if action == "test:delete" else None
+        assert a.submit_request(b.local_node, "test:delete", {"id": "d"},
+                                timeout=5.0) == {"deleted": "d"}
+        a.outbound_rule = None
+        # a later request from the same sender still runs: the window
+        # holds ids, not senders
+        with pytest.raises(RemoteTransportError):
+            a.submit_request(b.local_node, "test:delete", {"id": "d"},
+                             timeout=5.0)
+        assert runs == ["d", "d"]
+        if kind == "tcp":
+            assert b.transport._inbound_channels == {}
+    finally:
+        a.close()
+        b.close()
 
 
 def test_tcp_connect_failure():
