@@ -2359,6 +2359,14 @@ class SearchActions:
                     payloads.append(payload)
                 else:
                     failures.append(payload)
+        if failures and not payloads and all(
+                f.get("reason", {}).get("type") == "script_exception"
+                for f in failures):
+            # a script no shard could evaluate is the request's error
+            # (Elasticsearch answers SearchPhaseExecutionException with
+            # the shard failures' status), never an empty answer
+            from elasticsearch_tpu.search.scripts import ScriptError
+            raise ScriptError(failures[0]["reason"].get("reason", ""))
         q_ms = (time.perf_counter() - q_t0) * 1e3
         r_t0 = time.perf_counter()
         with obs_trace.span("action.reduce"):

@@ -57,7 +57,9 @@ class ClusterInfoService:
         state = node.cluster_service.state()
         if state.master_node_id != node.node_id:
             return
-        stats = node.collect_nodes_stats()
+        # the file systems alone, as the reference's
+        # NodesStatsRequest().clear().fs(true)
+        stats = node.collect_nodes_stats(metrics=["fs"])
         usage: dict[str, float] = {}
         for nid, s in stats.get("nodes", {}).items():
             total = s.get("fs", {}).get("total", {})

@@ -24,6 +24,7 @@ import numpy as np
 
 from elasticsearch_tpu.index.engine import SearcherView
 from elasticsearch_tpu.index.segment import Segment
+from elasticsearch_tpu.observability.tracing import span
 
 
 @dataclass
@@ -246,9 +247,10 @@ class DeviceReader:
                    for name, c in seg.keyword_fields.items()}
         numeric = {}
         for name, c in seg.numeric_fields.items():
-            hi, lo = dd_split(c.values)
-            numeric[name] = DeviceNumericField(
-                hi=put(hi), lo=put(lo), exists=put(c.exists), column=c)
+            with span("jit.numeric_upload"):
+                hi, lo = dd_split(c.values)
+                numeric[name] = DeviceNumericField(
+                    hi=put(hi), lo=put(lo), exists=put(c.exists), column=c)
         # the normalized host columns are the knn lane's, computed once
         # per immutable Segment and shared (jit_exec._host_knn_column):
         # dense rows unit length, rank_vectors per TOKEN (padding rows
@@ -264,10 +266,13 @@ class DeviceReader:
             mvector[name] = DeviceMultiVectorField(
                 vecs=_host_knn_column(seg, name, "f32")[0]["vecs"],  # lazy
                 lens=put(c.lens), exists=put(c.exists), column=c)
-        geo = {name: DeviceGeoField(lat=put(c.lat.astype(np.float32)),
-                                    lon=put(c.lon.astype(np.float32)),
-                                    exists=put(c.exists), column=c)
-               for name, c in seg.geo_fields.items()}
+        geo = {}
+        for name, c in seg.geo_fields.items():
+            with span("jit.geo_upload"):
+                geo[name] = DeviceGeoField(
+                    lat=put(c.lat.astype(np.float32)),
+                    lon=put(c.lon.astype(np.float32)),
+                    exists=put(c.exists), column=c)
         shape = {name: DeviceShapeField(lats=put(c.lats), lons=put(c.lons),
                                         nv=put(c.nv), exists=put(c.exists),
                                         rid=put(c.rid), area=put(c.area),

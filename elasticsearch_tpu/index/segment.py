@@ -281,6 +281,10 @@ def _longest_first(doc_len: np.ndarray, num_docs: int) -> np.ndarray | None:
         np.arange(num_docs, doc_len.shape[0])])
 
 
+#: the per-row arrays of a packed text column (the rest are per term)
+_PACKED_TEXT_ROWS = ("tokens", "uterms", "utf", "doc_len")
+
+
 def _rows_in_order(a: np.ndarray, order: np.ndarray) -> np.ndarray:
     """``a[order]`` along rows, gathered by a few threads where the column
     is large (a column of a 2^20-row segment is about 1 GB; ``np.take``
@@ -298,6 +302,36 @@ def _rows_in_order(a: np.ndarray, order: np.ndarray) -> np.ndarray:
     with ThreadPoolExecutor(k) as pool:
         list(pool.map(part, range(k)))
     return out
+
+
+class PackedSources(Sequence):
+    """The stored ``_source`` of a packed segment: each document's JSON,
+    UTF-8, in one byte buffer (``data[starts[i]:ends[i]]``), decoded when
+    a hit is fetched — the analog of Lucene's stored-fields blocks. A
+    list of dicts would cost every document a tree of objects that the
+    interpreter's collector walks on each full collection; the buffer
+    costs it two offsets. A row whose slice is empty (a pad row) has the
+    empty source."""
+
+    def __init__(self, data: np.ndarray, starts: np.ndarray,
+                 ends: np.ndarray):
+        if starts.shape != ends.shape:
+            raise ValueError("packed sources disagree on row count")
+        self.data = np.ascontiguousarray(data, dtype=np.uint8)
+        self.starts = np.ascontiguousarray(starts, dtype=np.int64)
+        self.ends = np.ascontiguousarray(ends, dtype=np.int64)
+
+    def __len__(self) -> int:
+        return int(self.starts.shape[0])
+
+    def __getitem__(self, i: int) -> dict:
+        lo, hi = int(self.starts[i]), int(self.ends[i])
+        return json.loads(self.data[lo:hi].tobytes()) if hi > lo else {}
+
+    def take(self, rows: np.ndarray) -> "PackedSources":
+        """The same documents in the order ``rows`` names (the buffer is
+        shared, not copied)."""
+        return PackedSources(self.data, self.starts[rows], self.ends[rows])
 
 
 def doc_count_bucket(n: int) -> int:
@@ -552,9 +586,13 @@ class Segment:
                          total_tokens: int | None = None,
                          ids: list[str] | None = None,
                          sources: list[dict] | None = None,
-                         vectors: dict | None = None) -> "Segment":
-        """Construct an immutable single-text-field segment directly from
-        pre-tokenized packed columns — the high-throughput bulk-load path,
+                         vectors: dict | None = None,
+                         numeric: dict | None = None,
+                         geo: dict | None = None,
+                         keyword: dict | None = None,
+                         text: dict | None = None) -> "Segment":
+        """Construct an immutable segment directly from pre-tokenized
+        packed columns — the high-throughput bulk-load path,
         the analog of Lucene's ``IndexWriter.addIndexes(CodecReader...)``
         (segment-level ingest without re-analysis). Bulk loaders and the
         benchmark corpus builder use this; the per-document path is
@@ -566,11 +604,21 @@ class Segment:
         reference's ``index_options: freqs``: ~40% less memory, positional
         queries rejected); rows at and beyond ``num_docs`` are padding.
         ``vectors``: dense_vector columns beside the text, field →
-        ``(vecs [Np, D], exists [Np])`` (:meth:`packed_vector_column`).
+        ``(vecs [Np, D], exists [Np])`` (:meth:`packed_vector_column`);
+        ``numeric``: numeric doc values, field → ``(values [Np],
+        exists [Np])``; ``geo``: geo_point doc values, field → ``(lat
+        [Np], lon [Np], exists [Np])`` in degrees; ``keyword``: keyword
+        doc values, field → ``(vocab, ords [Np, K])`` (``vocab`` sorted,
+        ords ranks in it, -1 for no value); ``text``: more analyzed text
+        fields, field → a dict of ``terms``, ``tokens``, ``uterms``,
+        ``utf``, ``doc_len`` and ``df`` under the invariants above. A pad
+        row of these columns must not exist. ``sources`` is a list of
+        dicts or a :class:`PackedSources`.
 
-        The real rows are stored longest document first (a stable sort:
-        equal lengths keep the loader's order), every per-row column,
-        ``ids`` and ``sources`` moved alike; pad rows stay at the end. A
+        The real rows are stored longest document first in ``field`` (a
+        stable sort: equal lengths keep the loader's order), every per-row
+        column, ``ids`` and ``sources`` moved alike; pad rows stay at the
+        end (a loader whose rows stand in that order already moves none). A
         document keeps its ``_id`` (the default ``str(i)`` names the
         loader's row i). Internal doc numbers carry no meaning, as in
         Lucene, and Elasticsearch's index sorting orders a segment the
@@ -598,14 +646,26 @@ class Segment:
                 tokens = _rows_in_order(tokens, order)
             real = order[:num_docs].tolist()
             ids = [ids[i] for i in real] + list(ids[num_docs:])
-            if sources is not None:
+            if isinstance(sources, PackedSources):
+                sources = sources.take(order)
+            elif sources is not None:
                 sources = [sources[i] for i in real] + \
                     list(sources[num_docs:])
             vectors = {name: (_rows_in_order(v, order),
                               _rows_in_order(ex, order))
                        for name, (v, ex) in (vectors or {}).items()}
-        if tokens is None:
-            tokens = np.full((np_docs, 8), -1, np.int32)
+            numeric = {name: tuple(_rows_in_order(np.asarray(a), order)
+                                   for a in cols)
+                       for name, cols in (numeric or {}).items()}
+            geo = {name: tuple(_rows_in_order(np.asarray(a), order)
+                               for a in cols)
+                   for name, cols in (geo or {}).items()}
+            keyword = {name: (vocab, _rows_in_order(np.asarray(o), order))
+                       for name, (vocab, o) in (keyword or {}).items()}
+            text = {name: {k: (_rows_in_order(np.asarray(v), order)
+                               if k in _PACKED_TEXT_ROWS and v is not None
+                               else v) for k, v in cols.items()}
+                    for name, cols in (text or {}).items()}
         # a tuple of strings, which the interpreter's collector stops
         # walking (CPython untracks it); a list is walked by every full
         # collection, and in length order its strings lie scattered in
@@ -613,20 +673,73 @@ class Segment:
         # 47 ms in the loader's order, 97 ms in length order, 11 ms as
         # tuples)
         ids = tuple(ids)
-        col = TextFieldColumn(
-            terms=list(terms),
+        text_fields = {field: Segment.packed_text_column(
+            terms=terms, tokens=tokens, uterms=uterms, utf=utf,
+            doc_len=doc_len, df=df, num_docs=num_docs,
+            total_tokens=total_tokens)}
+        for name, cols in (text or {}).items():
+            text_fields[name] = Segment.packed_text_column(
+                num_docs=num_docs, **cols)
+        return Segment._from_packed(
+            seg_id, num_docs, np_docs, ids, sources,
+            text_fields=text_fields,
+            vector_fields={
+                name: Segment.packed_vector_column(v, ex, np_docs)
+                for name, (v, ex) in (vectors or {}).items()},
+            numeric_fields={
+                name: Segment.packed_numeric_column(v, ex, num_docs,
+                                                    np_docs)
+                for name, (v, ex) in (numeric or {}).items()},
+            geo_fields={
+                name: Segment.packed_geo_column(la, lo, ex, num_docs,
+                                                np_docs)
+                for name, (la, lo, ex) in (geo or {}).items()},
+            keyword_fields={
+                name: Segment.packed_keyword_column(vocab, o, num_docs,
+                                                    np_docs)
+                for name, (vocab, o) in (keyword or {}).items()})
+
+    @staticmethod
+    def packed_text_column(*, terms: list[str], tokens: np.ndarray | None,
+                           uterms: np.ndarray, utf: np.ndarray,
+                           doc_len: np.ndarray, df: np.ndarray,
+                           num_docs: int,
+                           total_tokens: int | None = None
+                           ) -> TextFieldColumn:
+        """THE way a packed text column enters a segment (the invariants
+        of :meth:`from_packed_text`)."""
+        np_docs = int(uterms.shape[0])
+        if not (np_docs == doc_len.shape[0] == utf.shape[0] and (
+                tokens is None or tokens.shape[0] == np_docs)):
+            raise ValueError("packed columns disagree on row count")
+        if total_tokens is None:
+            total_tokens = int(np.asarray(doc_len[:num_docs]).sum())
+        has_positions = tokens is not None
+        if tokens is None:
+            tokens = np.full((np_docs, 8), -1, np.int32)
+        # tuples, as ``ids`` are (see from_packed_text): the collector
+        # stops walking them, and columns of one term list share it
+        return TextFieldColumn(
+            terms=tuple(terms),
             tokens=np.ascontiguousarray(tokens, dtype=np.int32),
             uterms=np.ascontiguousarray(uterms, dtype=np.int32),
             utf=np.ascontiguousarray(utf, dtype=np.float32),
             doc_len=np.ascontiguousarray(doc_len, dtype=np.int32),
             df=np.ascontiguousarray(df, dtype=np.int32),
             total_tokens=total_tokens, has_positions=has_positions)
-        return Segment._from_packed(
-            seg_id, num_docs, np_docs, ids, sources,
-            text_fields={field: col},
-            vector_fields={
-                name: Segment.packed_vector_column(v, ex, np_docs)
-                for name, (v, ex) in (vectors or {}).items()})
+
+    @staticmethod
+    def packed_keyword_column(vocab, ords: np.ndarray, num_docs: int,
+                              np_docs: int) -> KeywordFieldColumn:
+        """THE way a packed keyword column enters a segment: ``vocab``
+        sorted (ordinal order is lexical order), ``ords [Np, K]`` ranks
+        in it with -1 for no value, no pad row existing."""
+        ords = np.ascontiguousarray(ords, dtype=np.int32)
+        if ords.ndim != 2 or ords.shape[0] != np_docs:
+            raise ValueError("packed columns disagree on row count")
+        if (ords[num_docs:] >= 0).any():
+            raise ValueError("a padding row is marked as existing")
+        return KeywordFieldColumn(vocab=tuple(vocab), ords=ords)
 
     @staticmethod
     def from_packed_vectors(seg_id: int, field: str, vecs: np.ndarray,
@@ -666,8 +779,41 @@ class Segment:
             dims=int(vecs.shape[1]))
 
     @staticmethod
+    def packed_numeric_column(values: np.ndarray, exists: np.ndarray,
+                              num_docs: int,
+                              np_docs: int) -> NumericFieldColumn:
+        """THE way a packed numeric column enters a segment: float64
+        values (a long is exact up to 2^53), no pad row existing."""
+        exists = np.ascontiguousarray(exists, dtype=bool)
+        if values.shape != (np_docs,) or exists.shape != (np_docs,):
+            raise ValueError("packed columns disagree on row count")
+        if exists[num_docs:].any():
+            raise ValueError("a padding row is marked as existing")
+        return NumericFieldColumn(
+            values=np.ascontiguousarray(values, dtype=np.float64),
+            exists=exists)
+
+    @staticmethod
+    def packed_geo_column(lat: np.ndarray, lon: np.ndarray,
+                          exists: np.ndarray, num_docs: int,
+                          np_docs: int) -> GeoFieldColumn:
+        """THE way a packed geo_point column enters a segment: float64
+        degrees, no pad row existing."""
+        exists = np.ascontiguousarray(exists, dtype=bool)
+        if not (lat.shape == lon.shape == exists.shape == (np_docs,)):
+            raise ValueError("packed columns disagree on row count")
+        if exists[num_docs:].any():
+            raise ValueError("a padding row is marked as existing")
+        return GeoFieldColumn(
+            lat=np.ascontiguousarray(lat, dtype=np.float64),
+            lon=np.ascontiguousarray(lon, dtype=np.float64), exists=exists)
+
+    @staticmethod
     def _from_packed(seg_id: int, num_docs: int, np_docs: int, ids, sources,
-                     *, text_fields: dict, vector_fields: dict) -> "Segment":
+                     *, text_fields: dict, vector_fields: dict,
+                     numeric_fields: dict | None = None,
+                     geo_fields: dict | None = None,
+                     keyword_fields: dict | None = None) -> "Segment":
         if ids is None:
             ids = [str(i) for i in range(num_docs)] + \
                 [""] * (np_docs - num_docs)
@@ -676,8 +822,10 @@ class Segment:
             sources = [{}] * np_docs       # shared empty dict: read-only
         return Segment(seg_id=seg_id, num_docs=num_docs, padded_docs=np_docs,
                        ids=ids, sources=sources, text_fields=text_fields,
-                       keyword_fields={}, numeric_fields={},
-                       vector_fields=vector_fields, geo_fields={},
+                       keyword_fields=keyword_fields or {},
+                       numeric_fields=numeric_fields or {},
+                       vector_fields=vector_fields,
+                       geo_fields=geo_fields or {},
                        source_complete=source_complete)
 
     # ---- persistence ------------------------------------------------------

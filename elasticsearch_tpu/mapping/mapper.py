@@ -361,7 +361,11 @@ class FieldMapper:
                         flat.extend(encoded)
                 pf.keywords = flat
             else:
-                pf.keywords = [str(v) for v in values if v is not None]
+                # ignore_above: a longer value is kept in _source but gets
+                # no term and no doc value (KeywordFieldMapper)
+                limit = self.params.get("ignore_above")
+                pf.keywords = [str(v) for v in values if v is not None and (
+                    limit is None or len(str(v)) <= int(limit))]
         elif self.kind == KIND_NUMERIC:
             for v in values:
                 if v is None:

@@ -1115,6 +1115,12 @@ class Node:
         return purged
 
     def _handle_node_stats(self, request: dict, source) -> dict:
+        if (request or {}).get("metrics") == ["fs"]:
+            # the disk sampler's request (NodesStatsRequest.clear().fs):
+            # the whole document is the interpreter's largest periodic
+            # allocation, and a full collection follows it
+            return {"name": self.node_name,
+                    "fs": self._fs_stats(int(time.time() * 1000))}
         return self.local_node_stats()
 
     def _handle_hot_threads(self, request: dict, source) -> dict:
@@ -1147,9 +1153,13 @@ class Node:
                 continue
         return out
 
-    def collect_nodes_stats(self) -> dict:
+    def collect_nodes_stats(self, metrics: list | None = None) -> dict:
+        """Every node's stats; ``metrics`` ``["fs"]`` asks for the file
+        system section alone."""
         return {"cluster_name": self.cluster_service.state().cluster_name,
-                "nodes": self._fan_out_nodes(self.NODE_STATS_ACTION, {})}
+                "nodes": self._fan_out_nodes(
+                    self.NODE_STATS_ACTION,
+                    {"metrics": metrics} if metrics else {})}
 
     # ---- span tracing (observability/tracing.py) ---------------------------
 

@@ -177,6 +177,10 @@ SPAN_LAYERS = {
     # the device seams (``jit.enqueue`` among them)
     "jit.pack": "jit_exec", "jit.drain": "jit_exec",
     "jit.unpack": "jit_exec",
+    # index/device_reader.py: a numeric column's double-double split and
+    # upload, a geo_point column's float32 cast and upload (each holds
+    # its ``jit.reader-upload`` transfers)
+    "jit.numeric_upload": "jit_exec", "jit.geo_upload": "jit_exec",
     **{name: "jit_exec" for name in _SITE_SPAN.values()},
     # fetch — search/phase.py fetch_phase
     "fetch.hits": "fetch",

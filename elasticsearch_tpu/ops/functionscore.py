@@ -17,12 +17,10 @@ from elasticsearch_tpu.utils.hashing import murmur3_hash32
 
 def field_value_factor(values, exists, factor: float = 1.0,
                        modifier: str = "none", missing: float | None = None):
-    """FieldValueFactorFunction.java: modifier(factor * value)."""
+    """FieldValueFactorFunction.java: modifier(factor * value). A
+    document without a value takes ``missing``; with no ``missing`` it
+    takes 0 (where the reference raises "Missing value for field")."""
     v = jnp.where(exists, values, missing if missing is not None else 0.0)
-    if missing is None:
-        # reference throws on missing w/o default; we score those docs 1.0
-        # only if exists handled upstream — keep 0-safe here
-        pass
     v = v.astype(jnp.float32) * factor
     if modifier == "none":
         out = v

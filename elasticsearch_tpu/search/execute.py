@@ -37,7 +37,8 @@ from typing import Any, Callable
 import jax.numpy as jnp
 import numpy as np
 
-from elasticsearch_tpu.common.errors import QueryParsingError
+from elasticsearch_tpu.common.errors import (
+    ElasticsearchTpuError, QueryParsingError)
 from elasticsearch_tpu.index.device_reader import (
     DeviceReader, DeviceSegment, dd_split)
 from elasticsearch_tpu.mapping.mapper import parse_date, KIND_NUMERIC
@@ -47,7 +48,8 @@ from elasticsearch_tpu.ops import (
 from elasticsearch_tpu.ops.similarity import BM25Params, idf as bm25_idf
 from elasticsearch_tpu.search import query_dsl as q
 from elasticsearch_tpu.search.batching import term_bucket
-from elasticsearch_tpu.search.scripts import ScriptContext, compile_script
+from elasticsearch_tpu.search.scripts import (
+    ScriptContext, ScriptError, compile_script)
 
 
 class ConstTable:
@@ -295,6 +297,14 @@ def bears_phrase(query) -> bool:
     """Whether a parsed query holds a ``match_phrase`` node."""
     return any(isinstance(node, q.MatchPhraseQuery)
                for node in _query_nodes([query]))
+
+
+def score_function_count(query) -> int | None:
+    """The score functions of a parsed query's ``function_score`` nodes,
+    None where it holds none."""
+    counts = [len(node.functions) for node in _query_nodes([query])
+              if isinstance(node, q.FunctionScoreQuery)]
+    return sum(counts) if counts else None
 
 
 class SegmentResolver:
@@ -1619,11 +1629,7 @@ class SegmentResolver:
         return emit
 
     def _res_FunctionScoreQuery(self, query: q.FunctionScoreQuery) -> Emit:
-        self.sig("function_score", query.score_mode, query.boost_mode,
-                 query.max_boost is not None, query.min_score is not None,
-                 tuple((fn.kind, fn.weight is not None,
-                        fn.filter_query is not None)
-                       for fn in query.functions))
+        self.sig("function_score", query.functions_shape())
         base_emit = self.resolve(query.query or q.MatchAllQuery())
         fn_emits = []
         for fn in query.functions:
@@ -1703,13 +1709,7 @@ class SegmentResolver:
         if fn.kind in ("gauss", "exp", "linear"):
             return self._decay_factor(fn, params)
         if fn.kind == "script_score":
-            script = params.get("script", params)
-            if isinstance(script, dict):
-                src = script.get("source", script.get("inline", ""))
-                sparams = script.get("params", {})
-            else:
-                src, sparams = str(script), {}
-            return self._script_factor(src, sparams)
+            return self._script_factor(*fn.script())
         raise QueryParsingError(f"unknown score function [{fn.kind}]")
 
     def _decay_factor(self, fn: q.ScoreFunction, params: dict):
@@ -1743,7 +1743,9 @@ class SegmentResolver:
                 dlmb = jnp.radians(col.lon - olon_t)
                 a = jnp.sin(dphi / 2) ** 2 + jnp.cos(p1) * jnp.cos(p2) * \
                     jnp.sin(dlmb / 2) ** 2
-                dist = 2 * r * jnp.arcsin(jnp.sqrt(a))
+                # rounding takes ``a`` past 1 within a few km of the
+                # origin's antipode, where arcsin would give NaN
+                dist = 2 * r * jnp.arcsin(jnp.sqrt(jnp.minimum(a, 1.0)))
                 return fs_ops.decay(dist, col.exists, em.get(r_zero),
                                     em.get(r_scale), em.get(r_offset),
                                     em.get(r_decay), kind)
@@ -1762,14 +1764,21 @@ class SegmentResolver:
             origin_v = float(origin if origin is not None else 0.0)
             scale = float(spec["scale"])
             offset = float(spec.get("offset", 0))
-        r_origin = self.c(origin_v, np.float32)
+        # the distance from the double-double column: at epoch
+        # milliseconds a float32 alone steps by 131,072 ms, and so would
+        # the distance read from ``hi`` only
+        o_hi, o_lo = dd_split(float(origin_v))
+        r_origin_hi = self.c(o_hi, np.float32)
+        r_origin_lo = self.c(o_lo, np.float32)
         r_scale = self.c(scale, np.float32)
         r_offset = self.c(offset, np.float32)
         r_decay = self.c(float(spec.get("decay", 0.5)), np.float32)
 
         def factor_emit(em, s):
             col = em.seg.numeric[fname]
-            return fs_ops.decay(col.hi, col.exists, em.get(r_origin),
+            delta = (col.hi - em.get(r_origin_hi)) + \
+                (col.lo - em.get(r_origin_lo))
+            return fs_ops.decay(delta, col.exists, 0.0,
                                 em.get(r_scale), em.get(r_offset),
                                 em.get(r_decay), kind)
         return factor_emit
@@ -1781,17 +1790,13 @@ class SegmentResolver:
         out = {}
         for key in sorted(params):
             v = params[key]
-            if isinstance(v, bool) or isinstance(v, str):
-                self.sig("sparam", key, v)
-                out[key] = ("static", v)
-            elif isinstance(v, (int, float)):
-                self.sig("sparam", key, "num")
+            shape = q.script_param_shape(v)
+            self.sig("sparam", key, shape)
+            if shape[0] == "num":
                 out[key] = ("ref", self.c(float(v), np.float32))
-            elif isinstance(v, (list, tuple)):
-                self.sig("sparam", key, "vec", len(v))
+            elif shape[0] == "vec":
                 out[key] = ("ref", self.c(np.asarray(v, np.float32)))
             else:
-                self.sig("sparam", key, repr(v))
                 out[key] = ("static", v)
         return out
 
@@ -1823,8 +1828,22 @@ class SegmentResolver:
                     raise QueryParsingError(f"no vector field [{field}]")
                 return vcol.vecs, vcol.exists
 
-            ctx = ScriptContext(get_numeric, get_vector, scores, sparams)
-            out = compiled.evaluate(ctx)
+            def get_geo(field):
+                gcol = em.seg.geo.get(field)
+                return None if gcol is None else (gcol.lat, gcol.lon,
+                                                  gcol.exists)
+
+            ctx = ScriptContext(get_numeric, get_vector, scores, sparams,
+                                get_geo_column=get_geo)
+            try:
+                out = compiled.evaluate(ctx)
+            except ElasticsearchTpuError:
+                raise
+            except Exception as e:       # noqa: BLE001 — the script's own
+                # a script that cannot be evaluated is the request's
+                # error, never a degraded empty answer
+                raise ScriptError(f"failed to run script [{source}]: "
+                                  f"{type(e).__name__}: {e}") from e
             return jnp.broadcast_to(jnp.asarray(out, jnp.float32), (em.n,))
         return factor_emit
 

@@ -47,7 +47,7 @@ from elasticsearch_tpu.ops import topk as topk_ops
 from elasticsearch_tpu.search import lanes
 from elasticsearch_tpu.search.execute import (
     ConstTable, EmitCtx, ExecutionContext, SegmentResolver,
-    bears_phrase, match_term_floor)
+    bears_phrase, match_term_floor, score_function_count)
 
 _CACHE_CAP = 512
 _cache: OrderedDict[tuple, "jax.stages.Wrapped"] = OrderedDict()
@@ -1017,6 +1017,7 @@ def run_reader_batch(segments: list, ctx: ExecutionContext, queries: list,
             plan = _plan_segment_batch(seg, ctx, queries, k_static)
             if plan is None:
                 note_phrase_items(queries[:n_real], batched=False)
+                note_fscore_items(queries[:n_real], batched=False)
                 return None
             plans.append(plan)
         b = len(queries)
@@ -1072,6 +1073,8 @@ def run_reader_batch(segments: list, ctx: ExecutionContext, queries: list,
     rows = [row for p in plans for row in p["slots"][:real]]
     note_slot_compares(sum(r[0] for r in rows), sum(r[1] for r in rows))
     note_phrase_items(queries[:n_real], batched=True)
+    note_fscore_items(queries[:n_real], batched=True,
+                      docs=sum(seg.padded_docs for seg in segments))
     note_topk_rows([seg.padded_docs for seg in segments], k_static, b_pad)
     if b_pad != b:
         out = out[:b] if pack else {name: v[:b] for name, v in out.items()}
@@ -2267,6 +2270,22 @@ def note_phrase_items(queries: list, batched: bool) -> None:
         with _cache_lock:
             _bump("phrase_items_batched" if batched
                   else "phrase_items_serial", n)
+
+
+def note_fscore_items(queries: list, batched: bool, docs: int = 0) -> None:
+    """The real rows of one reader batch, as :func:`note_phrase_items`
+    counts them, for rows that hold a ``function_score``; a batched
+    dispatch also books each such row's score functions over the
+    ``docs`` rows of the reader."""
+    fns = [score_function_count(query) for query in queries]
+    n = sum(1 for f in fns if f is not None)
+    if n:
+        with _cache_lock:
+            _bump("fscore_items_batched" if batched
+                  else "fscore_items_serial", n)
+            if batched:
+                _bump("fscore_doc_evals", int(docs) * sum(
+                    f for f in fns if f is not None))
 
 
 def note_msearch_items(n_items: int, batched: bool) -> None:

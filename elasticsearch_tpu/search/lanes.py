@@ -91,6 +91,13 @@ JIT_COUNTERS = {
     "phrase_items_serial": "search items holding a match_phrase that fell "
                            "to the query-by-query path because the "
                            "batch's plans did not share one signature",
+    "fscore_items_batched": "search items holding a function_score that "
+                            "one reader-batch dispatch answered",
+    "fscore_items_serial": "search items holding a function_score that "
+                           "fell to the query-by-query path because the "
+                           "batch's plans did not share one signature",
+    "fscore_doc_evals": "real rows x documents x score functions the "
+                        "reader-batch dispatches evaluated",
     "msearch_items_batched": "shard-side _msearch items answered by one "
                              "batched dispatch (query_phase_batch)",
     "msearch_items_serial": "shard-side _msearch items that fell to the "

@@ -1672,9 +1672,13 @@ class ShardSearcher:
                     compile_groovylite)
                 run_interpreted(compile_groovylite)
                 continue
+            def get_geo(fld):
+                col = seg.geo.get(fld)
+                return None if col is None else (col.lat, col.lon,
+                                                 col.exists)
             ctx = ScriptContext(get_numeric, get_vector,
                                 jnp.zeros(seg.padded_docs, jnp.float32),
-                                params)
+                                params, get_geo_column=get_geo)
             vals = compiled.evaluate(ctx)
             arr = np.asarray(jnp.broadcast_to(jnp.asarray(vals),
                                               (seg.padded_docs,)))

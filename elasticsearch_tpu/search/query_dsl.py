@@ -310,6 +310,19 @@ class MoreLikeThisQuery(Query):
     exclude_ids: list[str] = dc_field(default_factory=list)
 
 
+def script_param_shape(value) -> tuple:
+    """What one script param puts into a compiled plan's signature: a
+    number or a vector is a constant the program takes (its kind, and a
+    vector's length), a boolean or a string is part of the program."""
+    if isinstance(value, (bool, str)):
+        return ("static", value)
+    if isinstance(value, (int, float)):
+        return ("num",)
+    if isinstance(value, (list, tuple)):
+        return ("vec", len(value))
+    return ("static", repr(value))
+
+
 @dataclass
 class ScoreFunction:
     kind: str                          # field_value_factor | weight | random_score
@@ -317,6 +330,38 @@ class ScoreFunction:
     params: dict = dc_field(default_factory=dict)
     filter_query: Query | None = None
     weight: float | None = None
+
+    def script(self) -> tuple[str, dict]:
+        """A ``script_score``'s source and params."""
+        script = self.params.get("script", self.params)
+        if isinstance(script, dict):
+            return (script.get("source", script.get("inline", "")),
+                    script.get("params") or {})
+        return str(script), {}
+
+    def shape(self) -> tuple:
+        """What of this function decides its compiled program: the kind,
+        whether a weight or a filter applies, and per kind the field,
+        modifier, seed or script source and its params' kinds. Never a
+        per-request constant (an origin, a factor, a scale, a numeric
+        param's value): those are inputs of one program. The plan
+        signature (``execute``) and the scheduler's queue key both read
+        it, so requests that share it share a batch."""
+        p = self.params
+        parts: list = [self.kind, self.weight is not None,
+                       self.filter_query is not None]
+        if self.kind == "field_value_factor":
+            parts += [p.get("field"), p.get("modifier", "none"),
+                      p.get("missing") is None]
+        elif self.kind in ("gauss", "exp", "linear"):
+            parts += [k for k in p if k != "multi_value_mode"]
+        elif self.kind == "random_score":
+            parts.append(int(p.get("seed", 0)))
+        elif self.kind == "script_score":
+            source, sparams = self.script()
+            parts += [source, tuple((k, script_param_shape(sparams[k]))
+                                    for k in sorted(sparams))]
+        return tuple(parts)
 
 
 @dataclass
@@ -327,6 +372,14 @@ class FunctionScoreQuery(Query):
     boost_mode: str = "multiply"
     max_boost: float | None = None
     min_score: float | None = None
+
+    def functions_shape(self) -> tuple:
+        """The structural part of the scoring (:meth:`ScoreFunction.shape`
+        of each function, the modes, whether a max_boost or a min_score
+        applies)."""
+        return (self.score_mode, self.boost_mode,
+                self.max_boost is not None, self.min_score is not None,
+                tuple(fn.shape() for fn in self.functions))
 
 
 @dataclass

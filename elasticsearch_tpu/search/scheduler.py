@@ -72,6 +72,7 @@ from contextlib import nullcontext
 
 from elasticsearch_tpu.common.threadpool import EsRejectedExecutionError
 from elasticsearch_tpu.search.batching import pow2_bucket, term_bucket
+from elasticsearch_tpu.search.query_dsl import FunctionScoreQuery
 
 
 class SchedulerRejectedError(EsRejectedExecutionError):
@@ -154,6 +155,11 @@ def query_shape(q_node) -> tuple:
         sub = getattr(q_node, attr, None)
         if sub is not None and hasattr(sub, "__dataclass_fields__"):
             parts.append((attr, query_shape(sub)))
+    if isinstance(q_node, FunctionScoreQuery):
+        parts.append(("functions", q_node.functions_shape(),
+                      tuple(query_shape(fn.filter_query)
+                            for fn in q_node.functions
+                            if fn.filter_query is not None)))
     return tuple(parts)
 
 

@@ -8,7 +8,10 @@ evaluate **vectorized over all docs** as jnp ops — no per-doc interpreter.
 Grammar: Python expression syntax restricted to arithmetic/comparison ops,
 math functions, and the ES script bindings:
 
-    doc['field'].value        → the field's doc-values column
+    doc['field'] / doc['field'].value → the field's doc-values column
+                              (0 where a document has no value)
+    doc['field'].empty        → whether a document has no value
+    doc['geo'].lat / .lon     → a geo_point field's latitude / longitude
     _score                    → the query score vector
     params.x / params['x']    → request-supplied constants
     cosineSimilarity(params.qv, 'field')   → vector similarity (+ dotProduct)
@@ -60,13 +63,25 @@ _FUNCS: dict[str, Callable] = {
 }
 
 
+class ScriptError(QueryParsingError):
+    """A script the engine cannot evaluate: the request's own error, which
+    Elasticsearch answers as a ``script_exception`` (400) — never a device
+    fault, so the compiled paths re-raise it rather than fall back, and
+    it does not count against the plane breaker."""
+    error_type = "script_exception"
+
+
 class ScriptContext:
     """Per-segment evaluation context handed to compiled scripts."""
 
     def __init__(self, get_numeric_column, get_vector_column, scores,
-                 params: dict, variables: dict | None = None):
+                 params: dict, variables: dict | None = None,
+                 get_geo_column=None):
         self.get_numeric_column = get_numeric_column   # field → ([N] f32, exists)
         self.get_vector_column = get_vector_column     # field → ([N, D] f32, exists)
+        # field → ([N] lat, [N] lon, exists), None for a field that is
+        # not a geo_point
+        self.get_geo_column = get_geo_column
         self.scores = scores                           # [N] f32
         self.params = params
         # bare-name bindings (bucket_script/bucket_selector buckets_path
@@ -165,22 +180,17 @@ def _eval(node: _pyast.AST, ctx: ScriptContext) -> Any:  # noqa: C901
         else:
             raise QueryParsingError("subscript must be a literal")
         if isinstance(base, _pyast.Name) and base.id == "doc":
-            return _DocField(str(key))
+            # a bare doc['f'] is its value, as Lucene expressions bind it
+            return _doc_attribute(ctx, str(key), "value")
         if isinstance(base, _pyast.Name) and base.id == "params":
             return _param(ctx, str(key))
         raise QueryParsingError("only doc[...] / params[...] subscripts allowed")
     if isinstance(node, _pyast.Attribute):
-        base = _eval(node.value, ctx) if not (
-            isinstance(node.value, _pyast.Name) and node.value.id == "params") \
-            else None
         if isinstance(node.value, _pyast.Name) and node.value.id == "params":
             return _param(ctx, node.attr)
-        if isinstance(base, _DocField) and node.attr == "value":
-            col, exists = ctx.get_numeric_column(base.field)
-            return jnp.where(exists, col, 0.0)
-        if isinstance(base, _DocField) and node.attr == "empty":
-            _, exists = ctx.get_numeric_column(base.field)
-            return ~exists
+        field = _doc_field(node.value)
+        if field is not None:
+            return _doc_attribute(ctx, field, node.attr)
         raise QueryParsingError(f"unknown attribute [{node.attr}]")
     if isinstance(node, _pyast.Call):
         if not isinstance(node.func, _pyast.Name):
@@ -209,9 +219,32 @@ def _eval(node: _pyast.AST, ctx: ScriptContext) -> Any:  # noqa: C901
         f"script syntax not allowed: {type(node).__name__}")
 
 
-class _DocField:
-    def __init__(self, field: str):
-        self.field = field
+def _doc_field(node: _pyast.AST) -> str | None:
+    """The field name of a ``doc['f']`` node, None for any other node."""
+    if isinstance(node, _pyast.Subscript) and \
+            isinstance(node.value, _pyast.Name) and node.value.id == "doc" \
+            and isinstance(node.slice, _pyast.Constant):
+        return str(node.slice.value)
+    return None
+
+
+def _doc_attribute(ctx: ScriptContext, field: str, attr: str):
+    """``doc[field].<attr>``: the numeric value (0 where a document has
+    none) and ``empty``, or a geo_point's ``lat`` / ``lon``."""
+    if attr in ("lat", "lon"):
+        geo = ctx.get_geo_column(field) if ctx.get_geo_column else None
+        if geo is None:
+            raise ScriptError(
+                f"field [{field}] is not a geo_point: no [{attr}]")
+        lat, lon, exists = geo
+        return jnp.where(exists, lat if attr == "lat" else lon, 0.0)
+    if attr == "value":
+        col, exists = ctx.get_numeric_column(field)
+        return jnp.where(exists, col, 0.0)
+    if attr == "empty":
+        _, exists = ctx.get_numeric_column(field)
+        return ~exists
+    raise QueryParsingError(f"unknown attribute [{attr}]")
 
 
 def _param(ctx: ScriptContext, key: str):

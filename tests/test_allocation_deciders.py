@@ -158,3 +158,19 @@ def test_disk_threshold_fed_by_cluster_info(tmp_path):
         usage = n.allocation.disk_usage
         assert n.node_id in usage and 0.0 <= usage[n.node_id] <= 1.0
         assert ("d", 0) in n.cluster_info_service.shard_sizes
+
+
+def test_cluster_info_samples_the_file_systems_alone(tmp_path, monkeypatch):
+    """The periodic sample asks each node for its fs section only (the
+    reference's NodesStatsRequest().clear().fs(true)): the whole stats
+    document is never built for it."""
+    from elasticsearch_tpu.node import Node
+    with Node({"node.name": "cis-fs"}, data_path=tmp_path) as n:
+        def whole(*a, **k):
+            raise AssertionError("the whole stats document was built")
+        monkeypatch.setattr(n, "local_node_stats", whole)
+        n.cluster_info_service.refresh_once()
+        usage = n.allocation.disk_usage
+        assert n.node_id in usage and 0.0 <= usage[n.node_id] <= 1.0
+        monkeypatch.undo()
+        assert "fs" in n.collect_nodes_stats()["nodes"][n.node_id]

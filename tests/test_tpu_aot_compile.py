@@ -803,3 +803,97 @@ def test_phrase_kernel_at_the_benchmark_cells_shapes(topo,
     assert "tpu_custom_call" in text
     assert not [ln for ln in text.splitlines()
                 if str(ROWS) in ln and (" copy(" in ln or " transpose(" in ln)]
+
+
+GEONAMES_SEGMENTS = 11      # geonames-fscore.fscore-mix-top10-c16
+GEONAMES_DOCS = 11_396_503
+GEONAMES_NAME_SLOTS = 16
+
+
+#: the keyword and coded text fields of Rally geonames' mapping (their
+#: columns are resident beside the ones the functions read)
+GEONAMES_RAW = ("name", "asciiname", "alternatenames", "feature_class",
+                "feature_code", "country_code", "cc2", "admin1_code",
+                "admin2_code", "admin3_code", "admin4_code", "dem",
+                "timezone")
+
+
+def _packed_text(terms, width: int) -> dict:
+    return {"terms": terms, "tokens": np.zeros((ROWS, width), np.int32),
+            "uterms": np.zeros((ROWS, width), np.int32),
+            "utf": np.zeros((ROWS, width), np.float32),
+            "doc_len": np.ones(ROWS, np.int32),
+            "df": np.ones(len(terms), np.int64)}
+
+
+@pytest.fixture(scope="module")
+def geonames_cell(tmp_path_factory):
+    """The benchmark configuration ``geonames-function-score`` at its own
+    shapes: eleven packed segments of 2^20 rows (the last one partly
+    real) with every column of Rally's mapping — ``name`` and
+    ``asciiname`` [2^20, 16], the coded text fields one or two slots, a
+    ``.raw`` keyword each, ``population`` / ``geonameid`` / ``elevation``
+    and ``location``."""
+    import json
+    config = json.loads(open(os.path.join(
+        os.path.dirname(__file__), "..", "benchmarks", "configs",
+        "geonames-function-score.json")).read())
+    ms = MapperService()
+    ms.merge("_doc", config["index"]["mappings"]["_doc"])
+    eng = Engine(tmp_path_factory.mktemp("aot_geonames_cell"), ms)
+    terms = tuple(f"t{i:05d}" for i in range(VOCAB))
+    width = GEONAMES_NAME_SLOTS
+    for si in range(GEONAMES_SEGMENTS):
+        n = min(ROWS, GEONAMES_DOCS - si * ROWS)
+        exists = np.arange(ROWS) < n
+        values = np.zeros(ROWS, np.float64)
+        name = _packed_text(terms, width)
+        eng.install_segment(Segment.from_packed_text(
+            0, "name", **name, num_docs=n, ids=[""] * ROWS,
+            numeric={f: (values, exists)
+                     for f in ("population", "geonameid", "elevation")},
+            geo={"location": (values, values, exists)},
+            keyword={f"{f}.raw": (("a",), np.full((ROWS, 1), -1, np.int32))
+                     for f in GEONAMES_RAW},
+            text={"asciiname": name,
+                  **{f: _packed_text(("a", "b"), 2 if f == "timezone"
+                                     else 1)
+                     for f in GEONAMES_RAW[3:]}}),
+            track_versions=False)
+    yield eng, ms
+    eng.close()
+    jit_exec.clear_cache()
+
+
+@pytest.mark.parametrize("op", ["gauss", "script_score"])
+def test_function_score_program_at_the_benchmark_cells_shapes(
+        topo, no_persistent_cache, geonames_cell, op):
+    """A batch of 16 of Rally geonames' geo ``gauss`` decays (an origin
+    each) and of its lang-expression ``script_score`` over 11 x 2^20
+    places plans to ONE `reader-batch` program, which compiles for the
+    chip and fits HBM beside the resident columns, and ranks each
+    segment's scores in two stages (no top-k of a whole [16, 2^20])."""
+    from jax.sharding import SingleDeviceSharding
+    eng, ms = geonames_cell
+    chip = SingleDeviceSharding(topo.devices[0])
+    script = ("abs(ln(abs(doc['population']) + 1) + doc['location'].lon "
+              "+ doc['location'].lat) * _score")
+    reqs = []
+    for i in range(16):
+        fn = {"gauss": {"location": {
+            "origin": f"{-40 + 5 * i:.5f}, {7.5 * i:.5f}",
+            "scale": "500km", "offset": "0km", "decay": 0.1}}} \
+            if op == "gauss" else {"functions": [{"script_score": {
+                "script": {"lang": "expression", "inline": script}}}]}
+        reqs.append(parse_search_request({"query": {"function_score": {
+            "query": {"match_all": {}}, **fn}}, "size": 10}))
+    before = jit_exec.cache_stats()["misses"]
+    with reader_uploads_as_shapes():
+        searcher = ShardSearcher(0, device_reader_for(eng), ms)
+        with steered_to(lambda a: chip):
+            compiled = captured(searcher.query_phase_batch, reqs)
+    assert jit_exec.cache_stats()["misses"] - before == 1
+    ma = fits_hbm(compiled, f"geonames {op} B=16 k=10, 11 x 2^20 places")
+    assert ma.argument_size_in_bytes + 2 * (
+        ma.temp_size_in_bytes + ma.output_size_in_bytes) < HBM_BYTES
+    assert whole_row_selections(compiled.as_text(), 16 * ROWS) == []
