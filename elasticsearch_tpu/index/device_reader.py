@@ -146,6 +146,15 @@ class DeviceSegment:
     # over-capacity analog of the reference's FS-cache paging
     # (core/index/store/FsDirectoryService.java mmap).
     resident: bool = True
+    # the query-independent part of a batch's pack, built on first use
+    # and read by every later batch (jit_exec.layout_key and
+    # seg_flatten): a column's shape and dtype never change once packed
+    # (lazy materialization keeps both), and a refresh packs new
+    # DeviceSegments, so the memo dies with its reader generation.
+    # init=False: a dataclasses.replace copy (seg_rebuild's traced view,
+    # a mesh template with a new live mask) starts with an empty memo.
+    pack_memo: dict = dc_field(default_factory=dict, init=False,
+                               repr=False, compare=False)
 
     @property
     def padded_docs(self) -> int:

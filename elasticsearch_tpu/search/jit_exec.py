@@ -635,6 +635,9 @@ _ARRAYS = {
 
 
 _materialize_lock = threading.Lock()
+#: one thread alone walks a segment's columns for its layout key (the
+#: first callers of a new reader generation race for it)
+_memo_lock = threading.Lock()
 
 
 def _fetch(seg: DeviceSegment, col, attr: str):
@@ -677,7 +680,26 @@ def seg_flatten(seg: DeviceSegment, positions_for: frozenset | None = None,
     matrices flatten ONLY for fields in `positions_for`, and vector/geo
     columns only when the plan declared that kind — tracing the [N, L]
     tokens array (or a [N, 768] vector column) no op reads multiplies
-    XLA compile time for nothing (measured ~14x at 1M docs)."""
+    XLA compile time for nothing (measured ~14x at 1M docs).
+
+    A resident segment's list is built once per ``(positions_for,
+    vectors_for)`` and kept in its ``pack_memo``: the walk has put every
+    lazy array it returns on the device (``_fetch``), and callers read
+    the list and never change it. Segments without ``lazy_put`` (mesh
+    templates, streamed host pools) hold host arrays by design and are
+    walked on every call."""
+    if seg.lazy_put is None:
+        return _flatten_walk(seg, positions_for, vectors_for)
+    memo = ("flat", positions_for, vectors_for)
+    flat = seg.pack_memo.get(memo)
+    if flat is None:
+        flat = seg.pack_memo[memo] = _flatten_walk(seg, positions_for,
+                                                   vectors_for)
+    return flat
+
+
+def _flatten_walk(seg: DeviceSegment, positions_for: frozenset | None,
+                  vectors_for: frozenset | None) -> list:
     flat = [seg.live]
     for kind in _KINDS:
         fields = getattr(seg, kind)
@@ -690,7 +712,7 @@ def seg_flatten(seg: DeviceSegment, positions_for: frozenset | None = None,
     for path in sorted(seg.nested):
         blk = seg.nested[path]
         flat.append(blk.parent)
-        flat.extend(seg_flatten(blk.child, positions_for, vectors_for))
+        flat.extend(_flatten_walk(blk.child, positions_for, vectors_for))
     return flat
 
 
@@ -736,6 +758,26 @@ def seg_rebuild(seg: DeviceSegment, flat: list,
 
 
 def layout_key(seg: DeviceSegment) -> tuple:
+    """The segment's part of a program key: its padded rows and each
+    column's array shapes and dtypes (a text column's slot plan too),
+    nested blocks included. Built by one walk over the columns on a
+    segment's first call, kept in its ``pack_memo``, and returned as
+    that same tuple to every later call of the reader generation."""
+    key = seg.pack_memo.get("layout")
+    hit = key is not None
+    if not hit:
+        with _memo_lock:
+            key = seg.pack_memo.get("layout")
+            hit = key is not None
+            if not hit:
+                key = seg.pack_memo["layout"] = _layout_walk(seg)
+    with _cache_lock:
+        _bump("pack_layout_memo_hits" if hit
+              else "pack_layout_memo_misses")
+    return key
+
+
+def _layout_walk(seg: DeviceSegment) -> tuple:
     out = [seg.padded_docs]
     for kind in _KINDS:
         fields = getattr(seg, kind)
@@ -750,7 +792,7 @@ def layout_key(seg: DeviceSegment) -> tuple:
     for path in sorted(seg.nested):
         blk = seg.nested[path]
         out.append(("nested", path, tuple(blk.parent.shape),
-                    layout_key(blk.child)))
+                    _layout_walk(blk.child)))
     return tuple(out)
 
 

@@ -98,6 +98,12 @@ JIT_COUNTERS = {
                            "batch's plans did not share one signature",
     "fscore_doc_evals": "real rows x documents x score functions the "
                         "reader-batch dispatches evaluated",
+    "pack_layout_memo_hits": "segments whose program layout key a "
+                             "dispatch's pack reused from the segment "
+                             "(built once a reader generation)",
+    "pack_layout_memo_misses": "segments whose program layout key was "
+                               "built by a walk over their columns "
+                               "(once a segment of a reader generation)",
     "msearch_items_batched": "shard-side _msearch items answered by one "
                              "batched dispatch (query_phase_batch)",
     "msearch_items_serial": "shard-side _msearch items that fell to the "
